@@ -100,10 +100,9 @@ def superstep_records(ns=(2000,), m_attach: int = 4,
 
     One record per pair: HLO flops / bytes from the compiled program's cost
     analysis, best-of-``reps`` wall, achieved rates, and the fraction of the
-    platform peaks those rates reach. Pallas rows are skipped on jax builds
-    without Pallas; on CPU/GPU they run in interpret mode — expect achieved
-    fractions far below the XLA rows there (the columns exist exactly so
-    that gap is measurable, per-backend, over time).
+    device's peaks (``repro.platform.peaks``, keyed by ``device_kind``)
+    those rates reach. A device without published peaks raises: there is
+    no roofline to report for it.
     """
     import jax
     import jax.numpy as jnp
@@ -124,8 +123,6 @@ def superstep_records(ns=(2000,), m_attach: int = 4,
         amask = jnp.ones(g.num_arcs, bool)
         act = jnp.ones(g.n, bool)
         for mode in dispatches:
-            if mode == "pallas" and not dmod.pallas_supported():
-                continue
             plan = dmod.DispatchPlan(kind=mode,
                                      interpret=platform.interpret_kernels())
             ell = build_ell(g) if mode == "pallas" else None

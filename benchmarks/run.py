@@ -53,6 +53,9 @@ def main() -> None:
         ap.error(f"unknown benchmark(s) {unknown}; pick from {list(BENCHES)}")
     names = args.names or list(BENCHES)
     repeat = max(args.repeat, 1)
+    from repro import platform
+
+    platform.enable_compile_cache()
 
     for name in names:
         mod = importlib.import_module(BENCHES[name])

@@ -3,7 +3,7 @@ composable JAX module, with exact message accounting, termination-detection
 models, and a simulated-network cost model."""
 
 from repro.core.bz import bz_core_numbers, max_core
-from repro.core.dispatch import DispatchPlan, pallas_supported, resolve_plan
+from repro.core.dispatch import DispatchPlan, resolve_plan
 from repro.core.jit_telemetry import compile_count, compile_seconds
 from repro.core.kcore import (
     KCoreConfig,
@@ -38,7 +38,6 @@ __all__ = [
     "bz_core_numbers",
     "max_core",
     "DispatchPlan",
-    "pallas_supported",
     "resolve_plan",
     "compile_count",
     "compile_seconds",

@@ -1,22 +1,19 @@
 """Backend-aware superstep dispatch: XLA segment ops vs Pallas kernels.
 
-The seed shipped two Pallas kernels aimed exactly at the h-index superstep —
-``kernels/kcore_hindex`` (rowwise clipped h-index over the degree-bucketed
-ELL layout) and ``kernels/segment_sum`` (blocked one-hot-matmul segment sum
-over sorted COO) — that the convergence path never called: the masked
-superstep and the fused ``lax.while_loop`` body always lowered to generic
-``jax.ops.segment_sum`` programs, which PR 5 measured as the 10k-vertex
-CPU bottleneck. This module is the routing layer between them:
+Two Pallas kernels target the h-index superstep — ``kernels/kcore_hindex``
+(rowwise clipped h-index over the degree-bucketed ELL layout) and
+``kernels/segment_sum`` (blocked one-hot-matmul segment sum over sorted
+COO). This module is the routing layer between them and the generic
+``jax.ops.segment_sum`` programs of ``core.kcore``:
 
 * ``resolve_plan()`` turns the platform dispatch switch
   (``repro.platform.dispatch_mode()`` — ``REPRO_PALLAS`` env var or a CLI
   flag) into a concrete ``DispatchPlan``: ``auto`` picks the Pallas kernels
-  only where they compile natively (TPU), ``on`` forces them everywhere
-  (interpret mode off-TPU — bit-exact, slow; the parity/CI path), ``off``
-  keeps the XLA segment ops. Unavailable kernels (a jax build without
-  Pallas) always fall back to XLA.
-* ``masked_round_program`` / ``fused_convergence_program`` build (and
-  cache) jitted superstep programs with the SAME contract as
+  on TPU, where they compile natively, and the XLA segment ops on CPU;
+  ``pallas`` forces the kernels (interpret mode on the CPU backend — exact,
+  slow; the parity/CI path), ``xla`` keeps the XLA segment ops.
+* ``masked_round_program`` / ``fused_convergence_program`` build jitted
+  superstep programs with the SAME contract as
   ``core.kcore.masked_round_segment`` / ``core.kcore.fused_convergence``,
   but with the per-round reductions routed through the kernels: the
   binary-search hit counts and the receiver computation go through the
@@ -27,26 +24,22 @@ CPU bottleneck. This module is the routing layer between them:
 
 Dispatch is an execution-placement choice, never an accounting one: cores
 and per-round MessageStats are bit-equal across every (plan, mode) pair —
-the kernels do exact int32 arithmetic, ``ref.py`` stays the independent
+the kernels count 0/1 indicators exactly, ``ref.py`` stays the independent
 oracle, and tests/test_dispatch.py asserts the equality across host, fused,
-and sharded modes. The sharded (shard_map) paths intentionally keep the XLA
-segment ops — per-shard Pallas dispatch is a later step once a real
-accelerator lane exists.
+and sharded modes. The sharded (shard_map) paths keep the XLA segment ops.
 
-Arc arrays enter the programs as jit CONSTANTS here (the blocked layout and
-ELL tables are host-precomputed from them), so programs are cached by an
-arc-content key: static graphs and the streaming engine's high-water padded
-slots reuse one compiled program; forcing Pallas dispatch on a stream whose
-slot contents churn re-stages per batch — that cost is the documented price
-of ``REPRO_PALLAS=on`` off-TPU today.
+The graph enters every program as jit ARGUMENTS (``GraphOperands``: arcs,
+the blocked segment-sum layout, the ELL tables), never as closed-over
+constants, so jax compiles one program per operand SHAPE: a graph of 10^8
+arcs does not become an HLO literal, and a stream whose slot contents churn
+at a stable padded size reuses one compiled program.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-import hashlib
-import warnings
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -55,35 +48,22 @@ from jax import lax
 
 from repro import platform as _platform
 from repro.graph.structs import EllGraph
+from repro.kernels.kcore_hindex.ops import hindex_rows
+from repro.kernels.segment_sum.ops import blocked_layout, segment_sum_arrays
 
 
 @dataclasses.dataclass(frozen=True)
 class DispatchPlan:
     """Resolved kernel-dispatch decision for superstep programs.
 
-    ``kind`` is ``"xla"`` (generic segment ops — the default everywhere
-    until a native accelerator is present) or ``"pallas"`` (route through
-    the kernels package); ``interpret`` records whether Pallas kernels run
-    interpreted (any backend but real TPU) — informational for reports,
-    the kernels' ops wrappers decide it themselves.
+    ``kind`` is ``"xla"`` (generic segment ops) or ``"pallas"`` (route
+    through the kernels package); ``interpret`` records whether Pallas
+    kernels run interpreted (the CPU backend only) — informational for
+    reports, the kernels' ops wrappers decide it themselves.
     """
 
     kind: str = "xla"
     interpret: bool = True
-
-
-@functools.lru_cache(maxsize=1)
-def pallas_supported() -> bool:
-    """Can this jax build stage Pallas kernels at all? (cached probe)"""
-    try:
-        # the kernel modules are exactly the surface the ops wrappers defer
-        # (jax.experimental.pallas + pallas.tpu); probing them probes what
-        # trace time will actually import
-        from repro.kernels.kcore_hindex import kernel as _hk  # noqa: F401
-        from repro.kernels.segment_sum import kernel as _sk  # noqa: F401
-    except Exception:
-        return False
-    return True
 
 
 def resolve_plan(mode: str | None = None) -> DispatchPlan:
@@ -96,75 +76,56 @@ def resolve_plan(mode: str | None = None) -> DispatchPlan:
         mode = _platform.dispatch_mode()
     interpret = _platform.interpret_kernels()
     if mode == "auto":
-        mode = "pallas" if (not interpret and pallas_supported()) else "xla"
-    if mode == "pallas" and not pallas_supported():
-        warnings.warn(
-            "Pallas dispatch requested but jax.experimental.pallas is "
-            "unavailable; falling back to XLA segment ops",
-            RuntimeWarning,
-            stacklevel=2,
-        )
-        mode = "xla"
+        mode = "xla" if interpret else "pallas"
     return DispatchPlan(kind=mode, interpret=interpret)
 
 
 # ---------------------------------------------------------------------- #
-# Program cache — arc arrays are jit constants in dispatched programs
+# Graph operands — staged once per graph, passed to the jitted programs
 # ---------------------------------------------------------------------- #
 
-_PROGRAMS: dict[tuple, object] = {}
-_LAYOUTS: dict[tuple, object] = {}
-_CACHE_CAP = 64
+
+class GraphOperands(NamedTuple):
+    """Device arrays a dispatched program reads (a pytree of jit args)."""
+
+    src: jax.Array  # (A,) int32 arc sources
+    dst: jax.Array  # (A,) int32 arc destinations
+    seg: tuple  # (slot_edge, rows_local, block_row) blocked layout; () on xla
+    ell: tuple  # ((ids, nbrs), ...) per ELL bucket; () for the bsearch h-index
 
 
-def _arc_key(src: np.ndarray, dst: np.ndarray, n: int) -> str:
-    h = hashlib.blake2b(digest_size=16)
-    h.update(np.int64(n).tobytes())
-    h.update(np.ascontiguousarray(src, np.int32).tobytes())
-    h.update(np.ascontiguousarray(dst, np.int32).tobytes())
-    return h.hexdigest()
+@dataclasses.dataclass(frozen=True)
+class Program:
+    """A jitted superstep program with its graph operands bound.
+
+    ``fn`` is a module-level ``jax.jit`` function, so its compiled programs
+    are cached by jax on the operand shapes and the ``static`` values.
+    """
+
+    fn: Callable
+    operands: GraphOperands
+    static: tuple  # (name, value) pairs of static arguments
+
+    def __call__(self, *args):
+        return self.fn(self.operands, *args, **dict(self.static))
+
+    def lower(self, *args):
+        return self.fn.lower(self.operands, *args, **dict(self.static))
 
 
-def _evict(cache: dict) -> None:
-    while len(cache) > _CACHE_CAP:
-        cache.pop(next(iter(cache)))
-
-
-def _blocked_segment_layout(src: np.ndarray, n: int, key: str):
-    """Blocked Pallas segment-sum layout over the (static) arc sources."""
-    from repro.kernels.segment_sum.ops import blocked_layout
-
-    cache_key = (key, n)
-    if cache_key not in _LAYOUTS:
-        _LAYOUTS[cache_key] = blocked_layout(np.asarray(src, np.int64), n)
-        _evict(_LAYOUTS)
-    return _LAYOUTS[cache_key]
-
-
-def _make_segment_sum(plan: DispatchPlan, src: np.ndarray, n: int, key: str):
-    """Traceable ``seg(vals_i32) -> (n,) i32`` for per-source reductions."""
+def _stage(plan: DispatchPlan, src, dst, n: int, ell: EllGraph | None):
+    """Host graph arrays -> (GraphOperands, static layout args)."""
+    src_np = np.asarray(src, np.int32)
+    seg, R, n_rows_pad = (), 0, 0
     if plan.kind == "pallas":
-        from repro.kernels.segment_sum.ops import segment_sum_blocked
-
-        layout = _blocked_segment_layout(src, n, key)
-
-        def seg(vals):
-            return segment_sum_blocked(vals, layout, n)[:, 0]
-
-        return seg
-
-    src_j = jnp.asarray(src, jnp.int32)
-
-    def seg(vals):
-        return jax.ops.segment_sum(vals, src_j, num_segments=n)
-
-    return seg
-
-
-def _ell_sig(ell: EllGraph | None) -> tuple:
-    if ell is None:
-        return ()
-    return tuple((b.width, b.ids.shape[0], b.rows_real) for b in ell.buckets)
+        layout = blocked_layout(src_np, n)
+        seg = tuple(jnp.asarray(a) for a in (layout.slot_edge, layout.rows_local, layout.block_row))
+        R, n_rows_pad = layout.R, layout.n_rows_pad
+    buckets = ()
+    if ell is not None and plan.kind == "pallas":
+        buckets = tuple((jnp.asarray(b.ids), jnp.asarray(b.nbrs)) for b in ell.buckets)
+    ops = GraphOperands(jnp.asarray(src_np), jnp.asarray(dst, jnp.int32), seg, buckets)
+    return ops, (("R", R), ("n_rows_pad", n_rows_pad))
 
 
 # ---------------------------------------------------------------------- #
@@ -172,72 +133,82 @@ def _ell_sig(ell: EllGraph | None) -> tuple:
 # ---------------------------------------------------------------------- #
 
 
-def _make_round_body(
-    n: int,
-    n_iters: int,
-    plan: DispatchPlan,
-    src: np.ndarray,
-    dst: np.ndarray,
-    ell: EllGraph | None,
-    key: str,
-):
-    """Build the traceable masked-superstep body with dispatched reductions.
+def _round(ops: GraphOperands, est, arc_mask, active, *, n, n_iters, R, n_rows_pad):
+    """Traceable masked superstep with dispatched reductions.
 
-    Same math as ``core.kcore._masked_round``; ``src``/``dst`` are closed
-    over as constants. With ``ell`` (static fully-live adjacency only — the
-    from-scratch decomposition) the h-index runs through the Pallas
-    ``hindex_rows`` kernel per degree bucket; otherwise it is the binary
-    search with the hit counts routed through the dispatched segment sum.
+    Same math as ``core.kcore._masked_round``. With ELL tables (static
+    fully-live adjacency only — the from-scratch decomposition) the h-index
+    runs through the Pallas ``hindex_rows`` kernel per degree bucket;
+    otherwise it is the binary search with the hit counts routed through
+    the dispatched segment sum.
     """
-    src_j = jnp.asarray(src, jnp.int32)
-    dst_j = jnp.asarray(dst, jnp.int32)
-    seg = _make_segment_sum(plan, src, n, key)
+    src, dst = ops.src, ops.dst
 
-    if ell is not None and plan.kind == "pallas":
-        from repro.kernels.kcore_hindex.ops import hindex_rows
+    if ops.seg:
 
-        bucket_ids = [jnp.asarray(b.ids) for b in ell.buckets]
-        bucket_nbrs = [jnp.asarray(b.nbrs) for b in ell.buckets]
-
-        def hindex(est, est_dst_masked):
-            # est_ext[n] = 0: padded neighbor slots never count for k >= 1.
-            # Requires est == 0 on degree-0 vertices (true from the degree
-            # seed: they are in no bucket, so their estimate passes through)
-            est_ext = jnp.concatenate([est, jnp.zeros(1, jnp.int32)])
-            new_ext = est_ext
-            for ids, nbrs in zip(bucket_ids, bucket_nbrs):
-                h = hindex_rows(est_ext[nbrs], est_ext[ids], n_iters=n_iters)
-                new_ext = new_ext.at[ids].set(h)
-            return new_ext[:n]
+        def count(mask):
+            return segment_sum_arrays(mask, *ops.seg, R=R, n_rows_pad=n_rows_pad, n_rows=n)[:, 0]
 
     else:
 
-        def hindex(est, est_dst_masked):
-            lo = jnp.zeros_like(est)
-            hi = est
+        def count(mask):
+            return jax.ops.segment_sum(mask.astype(jnp.int32), src, num_segments=n)
 
-            def body(lohi, _):
-                lo, hi = lohi
-                mid = (lo + hi + 1) // 2
-                hit = (est_dst_masked >= mid[src_j]) & (mid[src_j] > 0)
-                cnt = seg(hit.astype(jnp.int32))
-                ok = cnt >= mid
-                return (jnp.where(ok, mid, lo), jnp.where(ok, hi, mid - 1)), None
+    if ops.ell:
+        # est_ext[n] = 0: padded neighbor slots never count for k >= 1.
+        # Requires est == 0 on degree-0 vertices (true from the degree
+        # seed: they are in no bucket, so their estimate passes through)
+        est_ext = jnp.concatenate([est, jnp.zeros(1, jnp.int32)])
+        new_ext = est_ext
+        for ids, nbrs in ops.ell:
+            h = hindex_rows(est_ext[nbrs], est_ext[ids], n_iters=n_iters)
+            new_ext = new_ext.at[ids].set(h)
+        h = new_ext[:n]
+    else:
+        est_dst = jnp.where(arc_mask, est[dst], 0)
 
-            # lax.scan (not fori_loop) like core.kcore._hindex_by_bsearch:
-            # the trip count stays visible to jaxpr-walk cost analyses
-            (lo, hi), _ = lax.scan(body, (lo, hi), None, length=n_iters)
-            return lo
+        def body(lohi, _):
+            lo, hi = lohi
+            mid = (lo + hi + 1) // 2
+            cnt = count((est_dst >= mid[src]) & (mid[src] > 0))
+            ok = cnt >= mid
+            return (jnp.where(ok, mid, lo), jnp.where(ok, hi, mid - 1)), None
 
-    def round_body(est, arc_mask, active):
-        est_dst = jnp.where(arc_mask, est[dst_j], 0)
-        h = hindex(est, est_dst)
-        new_est = jnp.where(active, h, est)
-        changed = new_est < est
-        recv = seg(jnp.where(arc_mask, changed[dst_j], False).astype(jnp.int32)) > 0
-        return new_est, changed, recv
+        # lax.scan (not fori_loop) like core.kcore._hindex_by_bsearch:
+        # the trip count stays visible to jaxpr-walk cost analyses
+        (h, _), _ = lax.scan(body, (jnp.zeros_like(est), est), None, length=n_iters)
+    new_est = jnp.where(active, h, est)
+    changed = new_est < est
+    recv = count(jnp.where(arc_mask, changed[dst], False)) > 0
+    return new_est, changed, recv
 
-    return round_body
+
+@functools.partial(jax.jit, static_argnames=("n", "n_iters", "R", "n_rows_pad"))
+def _masked_round_jit(ops, est, arc_mask, active, *, n, n_iters, R, n_rows_pad):
+    return _round(ops, est, arc_mask, active, n=n, n_iters=n_iters, R=R, n_rows_pad=n_rows_pad)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "n_iters", "max_rounds", "R", "n_rows_pad"))
+def _fused_jit(ops, est, arc_mask, active, deg, *, n, n_iters, max_rounds, R, n_rows_pad):
+    def cond(carry):
+        _est, act, r, stop = carry[:4]
+        return (~stop) & (r < max_rounds) & act.any()
+
+    def body(carry):
+        est, act, r, _stop, mb, cb, rb = carry
+        new_est, changed, recv = _round(
+            ops, est, arc_mask, act, n=n, n_iters=n_iters, R=R, n_rows_pad=n_rows_pad
+        )
+        any_ch = changed.any()
+        mb = mb.at[r].set(jnp.sum(jnp.where(changed, deg, 0), dtype=jnp.int32))
+        cb = cb.at[r].set(jnp.sum(changed, dtype=jnp.int32))
+        rb = rb.at[r].set(jnp.sum(recv, dtype=jnp.int32))
+        return new_est, recv, r + 1, ~any_ch, mb, cb, rb
+
+    zeros = jnp.zeros(max_rounds, jnp.int32)
+    carry = (est, active, jnp.int32(0), jnp.bool_(False), zeros, zeros, zeros)
+    est, act, r, stop, mb, cb, rb = lax.while_loop(cond, body, carry)
+    return est, r, stop, jnp.sum(act, dtype=jnp.int32), mb, cb, rb
 
 
 def masked_round_program(
@@ -247,18 +218,13 @@ def masked_round_program(
     src: np.ndarray,
     dst: np.ndarray,
     ell: EllGraph | None = None,
-):
-    """Cached jitted dispatched superstep: ``(est, arc_mask, active) ->
-    (new_est, changed, recv)`` — ``core.kcore.masked_round_segment`` with
-    the reductions routed per ``plan`` (arc arrays are baked-in constants).
+) -> Program:
+    """Dispatched superstep: ``(est, arc_mask, active) -> (new_est,
+    changed, recv)`` — ``core.kcore.masked_round_segment`` with the
+    reductions routed per ``plan``.
     """
-    key = _arc_key(src, dst, n)
-    cache_key = ("round", n, n_iters, plan, key, _ell_sig(ell))
-    if cache_key not in _PROGRAMS:
-        body = _make_round_body(n, n_iters, plan, src, dst, ell, key)
-        _PROGRAMS[cache_key] = jax.jit(body)
-        _evict(_PROGRAMS)
-    return _PROGRAMS[cache_key]
+    ops, layout = _stage(plan, src, dst, n, ell)
+    return Program(_masked_round_jit, ops, (("n", n), ("n_iters", n_iters)) + layout)
 
 
 def fused_convergence_program(
@@ -269,8 +235,8 @@ def fused_convergence_program(
     src: np.ndarray,
     dst: np.ndarray,
     ell: EllGraph | None = None,
-):
-    """Cached jitted dispatched fused convergence loop.
+) -> Program:
+    """Dispatched fused convergence loop.
 
     Same carry, cond, stat buffers, and output contract as
     ``core.kcore.fused_convergence`` — ``prog(est, arc_mask, active, deg)
@@ -279,38 +245,6 @@ def fused_convergence_program(
     kernels per ``plan``. Accounting is reconstructed by the shared
     ``fused_round_stats``, so the bill is bit-equal to every other mode.
     """
-    key = _arc_key(src, dst, n)
-    cache_key = ("fused", n, n_iters, max_rounds, plan, key, _ell_sig(ell))
-    if cache_key in _PROGRAMS:
-        return _PROGRAMS[cache_key]
-
-    round_body = _make_round_body(n, n_iters, plan, src, dst, ell, key)
-
-    def prog(est, arc_mask, active, deg):
-        def cond(carry):
-            _est, act, r, stop = carry[:4]
-            return (~stop) & (r < max_rounds) & act.any()
-
-        def body(carry):
-            est, act, r, _stop, mb, cb, rb = carry
-            new_est, changed, recv = round_body(est, arc_mask, act)
-            any_ch = changed.any()
-            mb = mb.at[r].set(jnp.sum(jnp.where(changed, deg, 0), dtype=jnp.int32))
-            cb = cb.at[r].set(jnp.sum(changed, dtype=jnp.int32))
-            rb = rb.at[r].set(jnp.sum(recv, dtype=jnp.int32))
-            return new_est, recv, r + 1, ~any_ch, mb, cb, rb
-
-        zeros = jnp.zeros(max_rounds, jnp.int32)
-        carry = (est, active, jnp.int32(0), jnp.bool_(False), zeros, zeros, zeros)
-        est, act, r, stop, mb, cb, rb = lax.while_loop(cond, body, carry)
-        return est, r, stop, jnp.sum(act, dtype=jnp.int32), mb, cb, rb
-
-    _PROGRAMS[cache_key] = jax.jit(prog)
-    _evict(_PROGRAMS)
-    return _PROGRAMS[cache_key]
-
-
-def clear_caches() -> None:
-    """Drop cached layouts/programs (tests; after massive graph churn)."""
-    _PROGRAMS.clear()
-    _LAYOUTS.clear()
+    ops, layout = _stage(plan, src, dst, n, ell)
+    static = (("n", n), ("n_iters", n_iters), ("max_rounds", max_rounds)) + layout
+    return Program(_fused_jit, ops, static)

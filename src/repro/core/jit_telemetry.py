@@ -18,9 +18,9 @@ attributes "this batch was slow because it minted a fresh program" to the
 exact batch/phase that paid for it.
 
 The listener registers lazily on first use and is a no-op counter bump,
-so leaving it installed costs nothing. On a jax that stops emitting the
-event (none known across 0.4.x..current), counts degrade to 0 rather
-than erroring — telemetry must never take down the engine.
+so leaving it installed costs nothing. Should jax stop emitting the
+event, counts degrade to 0 rather than erroring — telemetry must never
+take down the engine.
 """
 
 from __future__ import annotations

@@ -97,6 +97,9 @@ class KCoreResult:
     # resolved superstep dispatch this run executed with ("xla" | "pallas");
     # see repro.core.dispatch — bills are bit-equal across choices
     dispatch: str = "xla"
+    # fused runs: ids of the devices that held the final estimate's shards
+    # (FusedOutcome.devices); empty for the host round loops
+    devices: tuple = ()
 
 
 def _bs_iters(max_deg: int) -> int:
@@ -421,6 +424,7 @@ def _decompose_body(g: Graph, config: KCoreConfig,
     compiles0, csecs0 = compile_count(), compile_seconds()
     phase_s: dict = {}
     dispatch_kind = "xla"
+    devices: tuple = ()
     n = g.n
     if n == 0:
         return KCoreResult(core=np.zeros(0, np.int32), rounds=0,
@@ -472,6 +476,7 @@ def _decompose_body(g: Graph, config: KCoreConfig,
             dispatch=plan.kind, ell=ell, frontier1=active[1])
         rounds, converged = outcome.rounds, outcome.converged
         dispatch_kind = outcome.dispatch
+        devices = outcome.devices
         msgs.extend(outcome.msgs.tolist())
         changed_counts.extend(outcome.changed.tolist())
         active.extend(outcome.recv.tolist())
@@ -606,7 +611,8 @@ def _decompose_body(g: Graph, config: KCoreConfig,
                        stats=stats,
                        recompiles=compile_count() - compiles0,
                        compile_s=compile_seconds() - csecs0,
-                       phase_s=phase_s, dispatch=dispatch_kind)
+                       phase_s=phase_s, dispatch=dispatch_kind,
+                       devices=devices)
 
 
 def _receivers_arrays(n: int, src: np.ndarray, dst: np.ndarray,
@@ -753,6 +759,7 @@ def kcore_decompose_sharded(g: Graph, mesh: jax.sharding.Mesh,
 
     compiles0, csecs0 = compile_count(), compile_seconds()
     phase_s: dict = {}
+    devices: tuple = ()
     n_dev = int(np.prod([mesh.shape[a] for a in axis_names]))
     sg = shard_graph(g, n_dev)
     # straggler visibility: a round's wall is the slowest shard's, so skew
@@ -785,6 +792,7 @@ def kcore_decompose_sharded(g: Graph, mesh: jax.sharding.Mesh,
                 n=g.n, n_iters=n_iters, max_rounds=cap,
                 frontier1=active[1])
             rounds, converged = outcome.rounds, outcome.converged
+            devices = outcome.devices
             msgs.extend(outcome.msgs.tolist())
             changed_counts.extend(outcome.changed.tolist())
             active.extend(outcome.recv.tolist())
@@ -836,4 +844,4 @@ def kcore_decompose_sharded(g: Graph, mesh: jax.sharding.Mesh,
                        stats=stats,
                        recompiles=compile_count() - compiles0,
                        compile_s=compile_seconds() - csecs0,
-                       phase_s=phase_s)
+                       phase_s=phase_s, devices=devices)

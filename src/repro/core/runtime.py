@@ -76,10 +76,27 @@ class FusedOutcome:
     # generic segment ops, "pallas" = the kernels package. Execution
     # placement only — the accounting above is bit-equal either way.
     dispatch: str = "xla"
+    # ids of the (process-local) devices holding shards of the final
+    # estimate: one id on a single device, every mesh device when sharded
+    devices: tuple = ()
+
+
+def _shard_devices(x) -> tuple:
+    return tuple(sorted({s.device.id for s in x.addressable_shards}))
 
 
 def _finish(
-    span, raw, rounds_raw, t_dev, compiles0, csecs0, est_of, dispatch="xla", frontier1=None, seed=None
+    span,
+    raw,
+    rounds_raw,
+    t_dev,
+    compiles0,
+    csecs0,
+    est_of,
+    dispatch="xla",
+    frontier1=None,
+    seed=None,
+    devices=(),
 ):
     """Shared tail of both fused paths: block, time phases, reconstruct."""
     t0 = time.perf_counter()
@@ -99,6 +116,7 @@ def _finish(
         compile_delta=compile_count() - compiles0,
         compile_s=compile_seconds() - csecs0,
         dispatch=dispatch,
+        devices=devices,
     )
     span.set(
         rounds=outcome.rounds,
@@ -207,6 +225,7 @@ def fused_converge_dense(
                 dispatch=plan.kind,
                 frontier1=frontier1,
                 seed=seed_np,
+                devices=_shard_devices(est_j),
             )
 
 
@@ -280,4 +299,5 @@ def fused_converge_sharded(seed, active, sg, mesh, axis_names, *, n, n_iters, ma
                 .reshape(-1)[:n].astype(np.int32),
                 frontier1=frontier1,
                 seed=seed_np,
+                devices=_shard_devices(est_j),
             )

@@ -1,24 +1,15 @@
-"""jax version + topology compatibility shims.
+"""Mesh and multi-process helpers — every mesh, shard_map and
+``jax.distributed`` call in the repo goes through here.
 
-The repo pins jax 0.4.37 (the container's baked-in jax_pallas toolchain) but
-several distribution APIs moved across jax releases:
-
-  * ``jax.sharding.AxisType`` (and ``make_mesh(..., axis_types=...)``) only
-    exist on jax >= 0.5; on 0.4.x every mesh axis is implicitly Auto.
-  * ``jax.shard_map`` was promoted out of ``jax.experimental.shard_map``
-    and its replication-check kwarg renamed ``check_rep`` -> ``check_vma``.
-
-Everything in the repo that builds meshes or shard_maps goes through these
-wrappers so the same code runs on the pinned 0.4.x and on newer jax.
-
-This module is ALSO the only place that touches ``jax.distributed``: the
-multi-process (multi-host) helpers below let the fused sharded runtime span
-processes — ``init_multiprocess`` brings a rank into the coordination
-service (with the CPU-collectives hint 0.4.x needs), ``global_mesh`` builds
-a mesh over every global device, and ``stage_to_mesh`` /
-``fetch_replicated`` move host arrays across the single-vs-multi-process
-boundary (``jnp.asarray`` and ``np.asarray`` are process-local and fail on
-cross-process global arrays).
+* ``make_mesh`` builds meshes with Auto axis types (the k-core shard_map
+  programs place their own collectives), ``shard_map`` turns replication
+  checking off.
+* The multi-process (multi-host) helpers let the fused sharded runtime span
+  processes — ``init_multiprocess`` brings a rank into the coordination
+  service, ``global_mesh`` builds a mesh over every global device, and
+  ``stage_to_mesh`` / ``fetch_replicated`` move host arrays across the
+  single-vs-multi-process boundary (``jnp.asarray`` and ``np.asarray`` are
+  process-local and fail on cross-process global arrays).
 """
 
 from __future__ import annotations
@@ -27,56 +18,26 @@ from typing import Sequence
 
 import jax
 import numpy as np
+from jax._src.distributed import global_state as _distributed_state
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]
               ) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with Auto axis types."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any jax version."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ------------------------------------------------------------------ #
 # Multi-process (jax.distributed) topology
 # ------------------------------------------------------------------ #
-
-def _distributed_client():
-    """The live jax.distributed client, or None (API is private pre-0.5)."""
-    state = getattr(jax.distributed, "global_state", None)
-    if state is None:
-        try:
-            from jax._src.distributed import global_state as state
-        except ImportError:
-            return None
-    return getattr(state, "client", None)
-
-
-def cpu_collectives_hint() -> None:
-    """Select a CPU cross-process collectives backend where one is needed.
-
-    On the pinned 0.4.x the CPU backend refuses multi-process computations
-    unless ``jax_cpu_collectives_implementation`` is set (gloo ships in the
-    container's jaxlib); newer jax picks a default itself. Must run BEFORE
-    the backend initializes — ``init_multiprocess`` calls this first.
-    """
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass  # option gone (newer jax defaults correctly) — nothing to do
-
 
 def init_multiprocess(coordinator_address: str, num_processes: int,
                       process_id: int) -> None:
@@ -89,9 +50,8 @@ def init_multiprocess(coordinator_address: str, num_processes: int,
     ``jax.process_count()`` here — merely asking would initialize the
     backend, after which jax refuses to join a coordination service.
     """
-    if _distributed_client() is not None:
+    if _distributed_state.client is not None:
         return
-    cpu_collectives_hint()
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)

@@ -1,11 +1,5 @@
-"""embedding_bag kernel package — attribute access defers the Pallas import."""
+"""embedding_bag kernel package: fused gather + reduce."""
+
+from repro.kernels.embedding_bag.ops import embedding_bag_fused
 
 __all__ = ["embedding_bag_fused"]
-
-
-def __getattr__(name):
-    if name in __all__:
-        from repro.kernels.embedding_bag import ops
-
-        return getattr(ops, name)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -26,7 +26,7 @@ def _bag_kernel(idx_ref, table_ref, out_ref, *, L: int, bb: int):
         def row(b, acc):
             ix = idx_ref[i * bb + b, j]
             valid = ix >= 0
-            r = pl.load(table_ref, (pl.dslice(jnp.maximum(ix, 0), 1), slice(None)))  # (1, D)
+            r = table_ref[pl.ds(jnp.maximum(ix, 0), 1), :]  # (1, D)
             return acc.at[b].add(jnp.where(valid, r[0], 0.0).astype(jnp.float32))
 
         return jax.lax.fori_loop(0, bb, row, acc)

@@ -8,13 +8,12 @@ import jax
 import jax.numpy as jnp
 
 from repro import platform as _platform
+from repro.kernels.embedding_bag.kernel import embedding_bag_pallas
 
 
 @functools.partial(jax.jit, static_argnames=())
 def embedding_bag_fused(table, indices):
     """table (V, D), indices (B, L) int32 (−1 pad) -> (B, D) sum-bags."""
-    from repro.kernels.embedding_bag.kernel import embedding_bag_pallas
-
     B, L = indices.shape
     bb = 8
     pad = (-B) % bb

@@ -1,11 +1,5 @@
-"""flash_attention kernel package — attribute access defers the Pallas import."""
+"""flash_attention kernel package."""
+
+from repro.kernels.flash_attention.ops import flash_attention
 
 __all__ = ["flash_attention"]
-
-
-def __getattr__(name):
-    if name in __all__:
-        from repro.kernels.flash_attention import ops
-
-        return getattr(ops, name)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

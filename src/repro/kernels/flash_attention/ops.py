@@ -1,5 +1,5 @@
 """Jit'd wrapper: shape plumbing (B,H grouping, GQA), block-size selection,
-padding, interpret fallback off-TPU."""
+padding, interpret mode on the CPU backend."""
 
 from __future__ import annotations
 
@@ -8,6 +8,7 @@ import functools
 import jax
 
 from repro import platform as _platform
+from repro.kernels.flash_attention.kernel import flash_attention_pallas
 
 
 def _pick_blocks(Sq: int, Sk: int, d: int) -> tuple[int, int]:
@@ -27,8 +28,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None):
     Drop-in for the XLA chunked path in models/transformer (same masking
     semantics: causal + optional sliding window over absolute positions).
     """
-    from repro.kernels.flash_attention.kernel import flash_attention_pallas
-
     B, Sq, Hq, d = q.shape
     _, Sk, Hkv, _ = k.shape
     qf = q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, d)

@@ -1,12 +1,5 @@
-"""kcore_hindex kernel package — attribute access defers the Pallas import
-(repro.core must stay importable on jax builds without Pallas)."""
+"""kcore_hindex kernel package: rowwise clipped h-index over ELL tiles."""
+
+from repro.kernels.kcore_hindex.ops import hindex_rows
 
 __all__ = ["hindex_rows"]
-
-
-def __getattr__(name):
-    if name in __all__:
-        from repro.kernels.kcore_hindex import ops
-
-        return getattr(ops, name)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

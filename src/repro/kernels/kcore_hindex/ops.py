@@ -1,7 +1,7 @@
 """Jit'd wrapper for the kcore_hindex Pallas kernel.
 
 Handles row padding to the tile multiple, 2-D reshape of the estimate
-column, VMEM-aware row-tile selection, and interpret-mode fallback off-TPU.
+column, VMEM-aware row-tile selection, and interpret mode on the CPU backend.
 """
 
 from __future__ import annotations
@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import platform as _platform
+from repro.kernels.kcore_hindex.kernel import hindex_rows_pallas
 
 _VMEM_BUDGET_BYTES = 4 * 1024 * 1024  # per-block neighbor tile budget
 
@@ -26,12 +27,8 @@ def _pick_row_tile(width: int) -> int:
 def hindex_rows(nbr_est, est_u, n_iters: int):
     """Rowwise clipped h-index. nbr_est (R, W) int32, est_u (R,) int32 → (R,).
 
-    Drop-in replacement for core.kcore.hindex_rows_ref. The Pallas kernel
-    import is deferred to trace time so importing this module stays safe on
-    jax builds without Pallas.
+    Drop-in replacement for core.kcore.hindex_rows_ref.
     """
-    from repro.kernels.kcore_hindex.kernel import hindex_rows_pallas
-
     rows, width = nbr_est.shape
     tile = _pick_row_tile(width)
     pad = (-rows) % tile

@@ -1,12 +1,5 @@
-"""segment_sum kernel package — attribute access defers the Pallas import
-(repro.core must stay importable on jax builds without Pallas)."""
+"""segment_sum kernel package: blocked one-hot-matmul segment sum."""
 
-__all__ = ["blocked_layout", "segment_sum_blocked"]
+from repro.kernels.segment_sum.ops import blocked_layout, segment_sum_arrays, segment_sum_blocked
 
-
-def __getattr__(name):
-    if name in __all__:
-        from repro.kernels.segment_sum import ops
-
-        return getattr(ops, name)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+__all__ = ["blocked_layout", "segment_sum_arrays", "segment_sum_blocked"]

@@ -16,21 +16,24 @@ the simulated-network runtime — plus validation vs the BZ oracle.
 ``--fused`` runs the whole round loop as ONE device-resident
 ``lax.while_loop`` (the shared fused runtime, repro/core/runtime.py) with
 bit-equal message accounting; ``--mesh N`` runs the sharded engine on an
-N-device ("data",) mesh (forced host devices when the platform has fewer —
-the flag must precede the first jax import, so mesh runs defer all jax
-imports like kcore_serve does). The two compose: ``--mesh N --fused`` nests
-the masked shard_map superstep inside the while_loop.
+N-device ("data",) mesh: N chips on TPU (fewer is an error), N forced host
+devices on the CPU platform (the flag must precede the first jax backend
+init, so this module defers its jax imports). The two compose: ``--mesh N
+--fused`` nests the masked shard_map superstep inside the while_loop.
+
+``build_graph``, ``decompose`` and ``report`` are the steps ``main`` runs
+after ``repro.platform.configure_run``, importable so other drivers
+(chip_smoke.py) take the same path.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import time
 
 
-def parse_args() -> argparse.Namespace:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--graph", default="FC", help="SNAP abbrev (Table I) or chain/ba/er")
     ap.add_argument("--scale", type=float, default=0.2)
@@ -72,14 +75,15 @@ def parse_args() -> argparse.Namespace:
         type=int,
         default=0,
         metavar="N",
-        help="run the sharded engine on an N-device ('data',) mesh "
-        "(forces N host devices when the platform has fewer)",
+        help="run the sharded engine on an N-device ('data',) mesh: N "
+        "chips on TPU, N forced host devices on the CPU platform",
     )
     ap.add_argument(
         "--platform",
         default=None,
-        choices=["cpu", "gpu", "tpu"],
-        help="select the jax platform (repro.platform.set_platform)",
+        choices=["cpu", "tpu"],
+        help="select the jax platform (repro.platform.set_platform); the "
+        "run fails if jax's first device is elsewhere",
     )
     ap.add_argument(
         "--devices",
@@ -94,9 +98,9 @@ def parse_args() -> argparse.Namespace:
         default=None,
         choices=["auto", "pallas", "xla", "on", "off"],
         help="superstep kernel dispatch (repro.core.dispatch): auto routes "
-        "to the Pallas kernels only where they compile natively; on/pallas "
-        "forces them (interpret mode off-TPU), off/xla keeps the XLA "
-        "segment ops. Default: the REPRO_PALLAS env var, else auto",
+        "to the Pallas kernels on TPU and the XLA segment ops on CPU; "
+        "on/pallas forces the kernels (interpreted on CPU), off/xla keeps "
+        "the XLA segment ops. Default: the REPRO_PALLAS env var, else auto",
     )
     ap.add_argument("--json", action="store_true")
     ap.add_argument(
@@ -134,7 +138,7 @@ def parse_args() -> argparse.Namespace:
         help="enable the convergence flight recorder + invariant monitor "
         "and dump the per-round ring and health verdict as JSON",
     )
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.metrics_out:
         args.metrics = True
     if args.mesh and (args.mode != "jacobi" or args.backend != "segment"):
@@ -160,53 +164,15 @@ def build_graph(args, generators):
     return generators.snap_analogue(args.graph, scale=args.scale, seed=args.seed)
 
 
-def main() -> None:
-    args = parse_args()
-    # platform layer first: env-driven config plus the CLI flags, all of
-    # which must precede the first jax backend init in the process
-    from repro import platform
+def decompose(args, g):
+    """Run the engine ``args`` selects on ``g``; returns (result, wall_s)."""
+    from repro.core import KCoreConfig, kcore_decompose, kcore_decompose_sharded
 
-    platform.configure_from_env()
-    if args.platform:
-        platform.set_platform(args.platform)
-    if args.devices:
-        platform.force_host_device_count(args.devices)
-    if args.dispatch:
-        platform.set_dispatch_mode(args.dispatch)
-    if args.mesh:
-        # must precede the first jax import anywhere in the process
-        flags = os.environ.get("XLA_FLAGS", "")
-        os.environ["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count={args.mesh}"
-        ).strip()
-
-    from repro.core import (
-        KCoreConfig,
-        bz_core_numbers,
-        kcore_decompose,
-        kcore_decompose_sharded,
-        work_bound,
-    )
-    from repro.core.cost_model import DATACENTER, INTERNET, TPU_POD, simulate_runtime
-    from repro.core.messages import heartbeat_overhead
-    from repro.graph import generators
-    from repro.obs import metrics, trace
-
-    if args.trace:
-        trace.enable()
-    if args.flight:
-        from repro.obs import flight, health
-
-        flight.enable()
-        health.install()
-
-    g = build_graph(args, generators)
     t0 = time.perf_counter()
     if args.out_of_core:
         from repro.core.outofcore import outofcore_decompose
 
-        res = outofcore_decompose(g, mem_budget=args.mem_budget,
-                                  n_blocks=args.blocks)
+        res = outofcore_decompose(g, mem_budget=args.mem_budget, n_blocks=args.blocks)
     elif args.mesh:
         from repro.distribution.compat import make_mesh
 
@@ -215,13 +181,20 @@ def main() -> None:
     else:
         config = KCoreConfig(mode=args.mode, backend=args.backend)
         res = kcore_decompose(g, config, fused=args.fused)
-    wall = time.perf_counter() - t0
+    return res, time.perf_counter() - t0
 
-    ref = bz_core_numbers(g)
-    ok = bool((res.core == ref).all())
+
+def report(args, g, res, wall: float, oracle) -> dict:
+    """The paper's measurement set for one run, checked against the BZ
+    core numbers ``oracle``."""
+    from repro.core import work_bound
+    from repro.core.cost_model import DATACENTER, INTERNET, TPU_POD, simulate_runtime
+    from repro.core.messages import heartbeat_overhead
+
+    ok = bool((res.core == oracle).all())
     wb = work_bound(g, res.core)
     hb = heartbeat_overhead(res.stats)
-    report = {
+    out = {
         "graph": args.graph,
         "n": g.n,
         "m": g.m,
@@ -252,11 +225,37 @@ def main() -> None:
         },
     }
     if args.out_of_core and res.block_stats is not None:
-        report["out_of_core"] = res.block_stats.to_json()
+        out["out_of_core"] = res.block_stats.to_json()
+    return out
+
+
+def main() -> None:
+    args = parse_args()
+    from repro import platform
+
+    platform.configure_run(
+        platform=args.platform, devices=args.devices, dispatch=args.dispatch, mesh=args.mesh
+    )
+
+    from repro.core import bz_core_numbers
+    from repro.graph import generators
+    from repro.obs import metrics, trace
+
+    if args.trace:
+        trace.enable()
+    if args.flight:
+        from repro.obs import flight, health
+
+        flight.enable()
+        health.install()
+
+    g = build_graph(args, generators)
+    res, wall = decompose(args, g)
+    rep = report(args, g, res, wall, bz_core_numbers(g))
     if args.json:
-        print(json.dumps(report, indent=1))
+        print(json.dumps(rep, indent=1))
     else:
-        for k, v in report.items():
+        for k, v in rep.items():
             print(f"{k}: {v}")
     if args.trace:
         trace.export(args.trace)
@@ -294,7 +293,8 @@ def main() -> None:
             f"flight: {args.flight} (runs={payload['runs']} "
             f"rounds={payload['rounds_recorded']} health={payload['health']['status']})"
         )
-    assert ok, "core numbers disagree with BZ oracle!"
+    if not rep["correct_vs_BZ"]:
+        raise SystemExit("core numbers disagree with BZ oracle!")
 
 
 if __name__ == "__main__":

@@ -45,21 +45,24 @@ loop).
 
 --mesh N runs the maintenance engine mesh-native on an N-device ("data",)
 mesh: the initial decomposition and the per-batch masked supersteps execute
-as shard_map programs. If fewer than N real devices exist, N host (CPU)
-devices are forced via XLA_FLAGS — which only works because this module
-defers every jax import until after the flag is set, so keep --mesh runs to
-fresh processes. Cores and message counts are identical to the
-single-device engine on any mesh (that equality is CI-tested).
+as shard_map programs. On TPU the mesh is N chips, and a host with fewer
+fails the run; on the CPU platform N host devices are forced via XLA_FLAGS
+— which only works because this module defers every jax import until after
+the flag is set, so keep --mesh runs to fresh processes. Cores and message
+counts are identical to the single-device engine on any mesh (that equality
+is CI-tested).
+
+``serve_churn`` is the synthetic-churn serving loop ``main`` runs,
+importable so other drivers (chip_smoke.py) take the same path.
 """
 
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 
-def parse_args():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--graph", default="EEN",
                     help="SNAP abbrev (Table I) or chain/ba/er")
@@ -76,15 +79,16 @@ def parse_args():
                     help="engine execution mode; fused = one device-"
                          "resident while_loop per batch (mesh-aware)")
     ap.add_argument("--mesh", type=int, default=0, metavar="N",
-                    help="run mesh-native on an N-device ('data',) mesh; "
-                         "forces N host devices when fewer exist (must be "
-                         "set before jax initializes — fresh process only). "
-                         "0 = single device (default)")
+                    help="run mesh-native on an N-device ('data',) mesh: "
+                         "N chips on TPU, N forced host devices on the CPU "
+                         "platform (fresh process only). 0 = single device "
+                         "(default)")
     ap.add_argument("--verify", action="store_true",
                     help="check vs the BZ oracle every tick (slow)")
     ap.add_argument("--platform", default=None,
-                    choices=["cpu", "gpu", "tpu"],
-                    help="select the jax platform (repro.platform)")
+                    choices=["cpu", "tpu"],
+                    help="select the jax platform (repro.platform); the run "
+                         "fails if jax's first device is elsewhere")
     ap.add_argument("--devices", type=int, default=0, metavar="N",
                     help="force N host (CPU) devices before backend init "
                          "(repro.platform; like REPRO_HOST_DEVICES)")
@@ -137,7 +141,7 @@ def parse_args():
                          "invariant monitor and dump the round ring, "
                          "watch timelines, and health verdict as JSON "
                          "after the run")
-    return ap.parse_args()
+    return ap.parse_args(argv)
 
 
 def _fmt_stats(stats: dict) -> dict:
@@ -353,62 +357,17 @@ def _finish_obs(args, server) -> None:
               f"health={payload['health']['status']})")
 
 
-def main() -> None:
-    args = parse_args()
-    # platform layer first: env-driven config plus the CLI flags, all of
-    # which must precede the first jax backend init in the process
-    from repro import platform
-    platform.configure_from_env()
-    if args.platform:
-        platform.set_platform(args.platform)
-    if args.devices:
-        platform.force_host_device_count(args.devices)
-    if args.dispatch:
-        platform.set_dispatch_mode(args.dispatch)
-    if args.mesh:
-        # must precede the first jax import anywhere in the process
-        flags = os.environ.get("XLA_FLAGS", "")
-        os.environ["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count={args.mesh}"
-        ).strip()
-
-    # live observability starts BEFORE the heavy jax init below, so
-    # external pollers can already reach /healthz while the backend and
-    # the initial decomposition warm up (repro.obs is stdlib+numpy only)
-    httpd = None
-    if args.listen is not None or args.flight:
-        from repro.obs import flight, health
-        flight.enable()
-        health.install()
-        if args.listen is not None:
-            from repro.obs.http import start_server
-            httpd = start_server(port=args.listen)
-            print(f"# obs: listening on {httpd.url} "
-                  "(/metrics /healthz /debug/flight)", flush=True)
-
-    import numpy as np
-
+def serve_churn(args, g, mesh=None, httpd=None, oracle=None) -> list:
+    """Synthetic-churn serving loop over ``g``: per tick one churn batch
+    through the incremental engine, a query load, the from-scratch bill for
+    the ratio, and (``--verify``) a check against ``oracle`` — BZ core
+    numbers of a graph, ``bz_core_numbers`` unless the caller shares one.
+    Prints the CSV and returns one dict per tick."""
     from repro.core import bz_core_numbers, kcore_decompose
-    from repro.graph import generators
     from repro.streaming import (KCoreServer, Request, StreamingConfig,
                                  random_churn_batch)
 
-    mesh = None
-    if args.mesh:
-        from repro.distribution.compat import make_mesh
-        mesh = make_mesh((args.mesh,), ("data",))
-        if args.frontier == "dense":
-            args.frontier = "sharded"
-
-    if args.trace:
-        from repro.obs import trace
-        trace.enable()
-
-    if args.events:
-        replay_serve(args, mesh, httpd=httpd)
-        return
-
-    g = build_graph(args, generators)
+    oracle = oracle or bz_core_numbers
     t0 = time.perf_counter()
     server = KCoreServer(g, StreamingConfig(frontier=args.frontier),
                          mesh=mesh)
@@ -426,6 +385,7 @@ def main() -> None:
             "rounds,region,seed_changed,mode,patch_s,queries,query_s,max_k,"
             "verified")
     print(cols, flush=True)
+    ticks = []
     tick = start_tick
     while tick < args.batches and not stop.is_set():
         rng = _tick_rng(args.seed, tick)
@@ -453,23 +413,74 @@ def main() -> None:
         scratch = kcore_decompose(server.engine.graph)
         verified = ""
         if args.verify:
-            ok = bool((res.core == bz_core_numbers(server.engine.graph)).all())
+            ok = bool((res.core == oracle(server.engine.graph)).all())
             verified = str(ok)
-            assert ok, "incremental cores diverged from the BZ oracle!"
+            if not ok:
+                raise SystemExit("incremental cores diverged from the BZ "
+                                 "oracle!")
         ratio = res.total_messages / max(scratch.stats.total_messages, 1)
-        print(",".join(str(c) for c in (
-            tick, server.engine.graph.m, res.delta.inserted.shape[0],
-            res.delta.deleted.shape[0], res.total_messages,
-            scratch.stats.total_messages, round(ratio, 4), res.rounds,
-            res.region_size, res.seed_changed, res.mode,
-            round(res.patch_s, 5), args.queries,
-            round(query_s, 4), server.max_k(), verified)), flush=True)
+        row = dict(tick=tick, m=server.engine.graph.m,
+                   inserted=res.delta.inserted.shape[0],
+                   deleted=res.delta.deleted.shape[0],
+                   inc_messages=res.total_messages,
+                   scratch_messages=scratch.stats.total_messages,
+                   ratio=round(ratio, 4), rounds=res.rounds,
+                   region=res.region_size, seed_changed=res.seed_changed,
+                   mode=res.mode, patch_s=round(res.patch_s, 5),
+                   queries=args.queries, query_s=round(query_s, 4),
+                   max_k=server.max_k(), verified=verified)
+        print(",".join(str(v) for v in row.values()), flush=True)
+        ticks.append(row)
         tick += 1
 
     stats = front.stats() if front is not None else server.stats()
     print(f"# final_stats={_fmt_stats(stats)}")
     _save_on_exit(args, front, server, tick)
     _finish_obs(args, server)
+    return ticks
+
+
+def main() -> None:
+    args = parse_args()
+    from repro import platform
+
+    # live observability starts BEFORE the heavy jax init below, so
+    # external pollers can already reach /healthz while the backend and
+    # the initial decomposition warm up (repro.obs is stdlib+numpy only)
+    httpd = None
+    if args.listen is not None or args.flight:
+        from repro.obs import flight, health
+        flight.enable()
+        health.install()
+        if args.listen is not None:
+            from repro.obs.http import start_server
+            httpd = start_server(port=args.listen)
+            print(f"# obs: listening on {httpd.url} "
+                  "(/metrics /healthz /debug/flight)", flush=True)
+
+    # platform layer: env-driven config plus the CLI flags (all before the
+    # first jax backend init), then the device checks and compile cache
+    platform.configure_run(platform=args.platform, devices=args.devices,
+                           dispatch=args.dispatch, mesh=args.mesh)
+
+    from repro.graph import generators
+
+    mesh = None
+    if args.mesh:
+        from repro.distribution.compat import make_mesh
+        mesh = make_mesh((args.mesh,), ("data",))
+        if args.frontier == "dense":
+            args.frontier = "sharded"
+
+    if args.trace:
+        from repro.obs import trace
+        trace.enable()
+
+    if args.events:
+        replay_serve(args, mesh, httpd=httpd)
+        return
+
+    serve_churn(args, build_graph(args, generators), mesh, httpd=httpd)
 
 
 if __name__ == "__main__":

@@ -1,37 +1,47 @@
 """Computation-platform configuration layer.
 
-One place (in the spirit of bayespec's ``elisa.util.config``) that decides
-WHERE the supersteps run and HOW the Pallas kernels are dispatched, driven
-by a flag or an environment variable — so the same entry points cover a
-laptop CPU, a forced-multi-device CI lane, and a real TPU/GPU runner:
+One place that decides WHERE the supersteps run and HOW the Pallas kernels
+are dispatched, driven by a flag or an environment variable — so the same
+entry points cover a laptop CPU, a forced-multi-device CI lane, and a TPU:
 
-* ``set_platform("cpu"|"gpu"|"tpu")`` — pick the jax platform (and set the
-  recommended XLA perf flags on GPU).
+* ``set_platform("cpu"|"tpu")`` — pick the jax platform.
 * ``force_host_device_count(n)`` — expose ``n`` host (CPU) devices via
   ``--xla_force_host_platform_device_count``, turning a single machine into
   an in-process mesh for the sharded/fused-sharded paths. Must run before
-  jax initializes its backends.
+  jax initializes its backends. ``prepare_mesh(n)`` applies it only when
+  the selected platform is the CPU: a TPU mesh is made of chips.
 * ``configure_from_env()`` — apply both from ``REPRO_PLATFORM`` /
   ``REPRO_HOST_DEVICES`` (+ ``REPRO_X64``); idempotent and cheap, called by
   the CLIs and ``tests/conftest.py`` so one exported variable reconfigures
   every entry point.
+* ``require_platform(name)`` / ``require_devices(n)`` — after backend init,
+  fail loudly when the run is not on the platform or device count it asked
+  for, instead of running somewhere else. ``configure_run`` strings the
+  steps together for the CLIs.
 * ``dispatch_mode()`` — the Pallas kernel-dispatch switch (``REPRO_PALLAS``
   = ``auto`` | ``on``/``pallas`` | ``off``/``xla``) consumed by
   ``repro.core.dispatch``: ``auto`` routes the superstep h-index /
-  segment-sum to the Pallas kernels only where they compile natively (TPU),
-  ``on`` forces them everywhere (interpret mode off-TPU — exact, slow;
-  the parity/CI path), ``off`` keeps the plain XLA segment ops.
-* ``peaks()`` — per-backend peak FLOP/s and bytes/s for roofline reporting
-  (``REPRO_PEAK_GFLOPS`` / ``REPRO_PEAK_GBS`` override).
+  segment-sum to the Pallas kernels on TPU and to the XLA segment ops on
+  CPU, ``on`` forces the kernels (interpret mode on CPU — exact, slow; the
+  parity/CI path), ``off`` keeps the plain XLA segment ops.
+* ``interpret_kernels()`` — True on the CPU backend (tests), False on TPU;
+  any other backend is an error.
+* ``peaks()`` — published peak FLOP/s and bytes/s per ``device_kind`` for
+  roofline reporting (``REPRO_PEAK_GFLOPS`` / ``REPRO_PEAK_GBS`` override);
+  a device without a row raises.
+* ``enable_compile_cache()`` — JAX's persistent compilation cache at one
+  fixed directory, or wherever ``JAX_COMPILATION_CACHE_DIR`` says.
 
-Everything here touches only ``os.environ`` and ``jax.config`` — importing
-this module never initializes a jax backend, so it is always safe to import
-first and configure before the rest of the process touches a device.
+Everything here touches only ``os.environ`` and ``jax.config`` until a
+function documents otherwise — importing this module never initializes a
+jax backend, so it is always safe to import first and configure before the
+rest of the process touches a device.
 """
 
 from __future__ import annotations
 
 import os
+import pathlib
 import warnings
 
 ENV_PLATFORM = "REPRO_PLATFORM"
@@ -40,28 +50,24 @@ ENV_DISPATCH = "REPRO_PALLAS"
 ENV_X64 = "REPRO_X64"
 ENV_PEAK_GFLOPS = "REPRO_PEAK_GFLOPS"
 ENV_PEAK_GBS = "REPRO_PEAK_GBS"
+ENV_COMPILE_CACHE = "JAX_COMPILATION_CACHE_DIR"
 
-_PLATFORMS = ("cpu", "gpu", "tpu")
-
-# jax GPU performance-tips flags (safe no-ops elsewhere; only set when the
-# gpu platform is selected, mirroring SNIPPETS.md snippet 1)
-_GPU_XLA_FLAGS = (
-    "--xla_gpu_triton_gemm_any=True "
-    "--xla_gpu_enable_latency_hiding_scheduler=true "
-    "--xla_gpu_enable_highest_priority_async_stream=true"
-)
+_PLATFORMS = ("cpu", "tpu")
 
 _FORCE_DEVICES_FLAG = "--xla_force_host_platform_device_count"
 
-# per-backend (peak FLOP/s, peak bytes/s): TPU numbers match
-# repro.launch.hlo_analysis (v5e-class); GPU ~A100-class; CPU a deliberately
-# round server-core estimate — override via REPRO_PEAK_GFLOPS/REPRO_PEAK_GBS
-# when calibrating a specific machine. Roofline REPORTING only, never used
-# for correctness or dispatch decisions.
+# the checkout root (src/repro/platform.py -> ../..): the compile cache
+# lives at one fixed path inside it, so every run of this checkout hits it
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[2]
+COMPILE_CACHE_DIR = _CHECKOUT / ".jax_cache"
+
+# (peak FLOP/s, peak HBM bytes/s) per jax ``device_kind``, for roofline
+# REPORTING only — never used for correctness or dispatch decisions.
+# "TPU v5 lite" is TPU v5e: Google Cloud documentation, "TPU v5e"
+# (cloud.google.com/tpu/docs/v5e): 197 TFLOP/s bf16, 393 TOP/s int8,
+# 16 GB HBM at 819 GB/s.
 _PEAKS = {
-    "tpu": (197e12, 819e9),
-    "gpu": (312e12, 2.0e12),
-    "cpu": (200e9, 50e9),
+    "TPU v5 lite": (197e12, 819e9),
 }
 
 _DISPATCH_MODES = ("auto", "pallas", "xla")
@@ -74,16 +80,21 @@ _dispatch_override: str | None = None
 
 
 def set_platform(platform: str) -> None:
-    """Select the jax platform (cpu/gpu/tpu). Call before backend init."""
+    """Select the jax platform (cpu/tpu). Call before backend init."""
     if platform not in _PLATFORMS:
         raise ValueError(f"platform must be one of {_PLATFORMS}, got {platform!r}")
     import jax
 
-    jax.config.update("jax_platform_name", platform)
-    if platform == "gpu":
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "--xla_gpu" not in flags:
-            os.environ["XLA_FLAGS"] = f"{flags} {_GPU_XLA_FLAGS}".strip()
+    jax.config.update("jax_platforms", platform)
+
+
+def selected_platform() -> str | None:
+    """The platform this process asked for before backend init, if any:
+    ``REPRO_PLATFORM``, else the first entry of ``JAX_PLATFORMS``."""
+    import jax
+
+    chosen = os.environ.get(ENV_PLATFORM, "").strip().lower() or (jax.config.jax_platforms or "")
+    return chosen.split(",")[0].strip() or None
 
 
 def force_host_device_count(n: int) -> None:
@@ -112,19 +123,23 @@ def force_host_device_count(n: int) -> None:
         )
 
 
+def prepare_mesh(n: int) -> None:
+    """Before backend init, for an ``n``-device mesh: on the CPU platform
+    expose ``n`` host devices; anywhere else the mesh is built from the
+    real devices, and ``require_devices`` refuses a host with too few."""
+    if selected_platform() == "cpu":
+        force_host_device_count(n)
+
+
 def _backends_initialized() -> bool:
-    """Best-effort: has this process already materialized jax devices?"""
+    """Has this process already materialized jax devices?"""
     import sys
 
-    jax = sys.modules.get("jax")
-    if jax is None:
+    if "jax" not in sys.modules:
         return False
-    try:
-        from jax._src import xla_bridge
+    from jax._src import xla_bridge
 
-        return bool(xla_bridge._backends)
-    except Exception:  # jax version drift — assume not initialized
-        return False
+    return bool(xla_bridge._backends)
 
 
 def configure_from_env() -> dict:
@@ -150,6 +165,55 @@ def configure_from_env() -> dict:
         jax.config.update("jax_enable_x64", x64 in ("1", "true", "yes", "on"))
         applied["x64"] = x64 in ("1", "true", "yes", "on")
     return applied
+
+
+def configure_run(
+    *, platform: str | None = None, devices: int = 0, dispatch: str | None = None, mesh: int = 0
+) -> None:
+    """What a CLI run does before its first compile: the env-driven config
+    and its flags (all before backend init), then the device checks
+    (``--platform`` and ``--mesh`` must be met, or the run fails), then the
+    compile cache."""
+    configure_from_env()
+    if platform:
+        set_platform(platform)
+    if devices:
+        force_host_device_count(devices)
+    if dispatch:
+        set_dispatch_mode(dispatch)
+    if mesh:
+        prepare_mesh(mesh)
+    if platform:
+        require_platform(platform)
+    if mesh:
+        require_devices(mesh)
+    enable_compile_cache()
+
+
+def require_platform(platform: str) -> None:
+    """Fail unless jax's first device is on ``platform`` (initializes the
+    backend). A run that asked for a TPU never continues on the CPU."""
+    import jax
+
+    found = jax.devices()[0].platform
+    if found != platform:
+        raise RuntimeError(
+            f"asked for platform {platform!r}, but jax's first device is on {found!r}"
+        )
+
+
+def require_devices(n: int) -> list:
+    """The first ``n`` devices of the default backend, or an error naming
+    what the host has (initializes the backend)."""
+    import jax
+
+    devs = jax.devices()
+    if len(devs) < n:
+        raise RuntimeError(
+            f"a {n}-device mesh needs {n} devices; "
+            f"this host has {len(devs)} {devs[0].platform} device(s)"
+        )
+    return devs[:n]
 
 
 # ---------------------------------------------------------------------- #
@@ -197,10 +261,22 @@ def set_dispatch_mode(mode: str | None) -> None:
 
 
 def interpret_kernels() -> bool:
-    """Should Pallas kernels run in interpret mode? (anywhere but real TPU)"""
+    """Should Pallas kernels run in interpret mode?
+
+    Only on the CPU backend, where the tests run them; on TPU they compile
+    natively. Any other backend has no route for the kernels and raises
+    rather than quietly interpreting them.
+    """
     import jax
 
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels run on TPU (native) or CPU (interpreted), not on {backend!r}"
+    )
 
 
 # ---------------------------------------------------------------------- #
@@ -208,14 +284,19 @@ def interpret_kernels() -> bool:
 # ---------------------------------------------------------------------- #
 
 
-def peaks(backend: str | None = None) -> tuple[float, float]:
-    """(peak FLOP/s, peak bytes/s) for ``backend`` (default: the active one),
-    with ``REPRO_PEAK_GFLOPS`` / ``REPRO_PEAK_GBS`` overrides."""
-    if backend is None:
+def peaks(device_kind: str | None = None) -> tuple[float, float]:
+    """(peak FLOP/s, peak bytes/s) of ``device_kind`` (default: jax's first
+    device), with ``REPRO_PEAK_GFLOPS`` / ``REPRO_PEAK_GBS`` overrides.
+    Raises KeyError for a device without published peaks."""
+    if device_kind is None:
         import jax
 
-        backend = jax.default_backend()
-    flops, membw = _PEAKS.get(backend, _PEAKS["cpu"])
+        device_kind = jax.devices()[0].device_kind
+    if device_kind not in _PEAKS:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; known: {sorted(_PEAKS)}"
+        )
+    flops, membw = _PEAKS[device_kind]
     gflops = os.environ.get(ENV_PEAK_GFLOPS, "").strip()
     gbs = os.environ.get(ENV_PEAK_GBS, "").strip()
     if gflops:
@@ -225,16 +306,43 @@ def peaks(backend: str | None = None) -> tuple[float, float]:
     return flops, membw
 
 
-def summary() -> dict:
-    """The resolved platform state (for CLI reports; initializes backends)."""
+def device_summary() -> dict:
+    """The device as jax reports it: platform, kind and count (initializes
+    backends). Every chip result names its device with these keys."""
     import jax
 
-    flops, membw = peaks()
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind, "count": len(devs)}
+
+
+def summary() -> dict:
+    """The resolved platform state (for CLI reports; initializes backends)."""
     return {
-        "backend": jax.default_backend(),
-        "device_count": jax.device_count(),
+        **device_summary(),
         "dispatch_mode": dispatch_mode(),
         "interpret_kernels": interpret_kernels(),
-        "peak_gflops": round(flops / 1e9, 1),
-        "peak_gbs": round(membw / 1e9, 1),
     }
+
+
+# ---------------------------------------------------------------------- #
+# Persistent compilation cache
+# ---------------------------------------------------------------------- #
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    this sets no other directory. Otherwise the cache lives at the fixed
+    ``.jax_cache/`` of this checkout: the path is part of what a later run
+    must find again. Call before the first compile; tests never call it,
+    so their compiles stay out of the cache.
+    """
+    import jax
+
+    path = os.environ.get(ENV_COMPILE_CACHE, "").strip()
+    if not path:
+        path = str(COMPILE_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_enable_compilation_cache", True)
+    return path

@@ -124,16 +124,33 @@ def test_ell_hindex_pow2_boundary_and_empty_rows():
 @settings(max_examples=20, deadline=None)
 @given(st.integers(1, 400), st.integers(1, 80), st.integers(0, 100))
 def test_segment_sum_int32_bit_exact(E, n, seed):
-    """int32 blocked segment sum is BIT-equal to jax.ops.segment_sum — the
-    exactness the dispatched superstep's message accounting rests on."""
+    """Counting bool indicators gives int32 counts BIT-equal to
+    jax.ops.segment_sum — the exactness the dispatched superstep's message
+    accounting rests on (every k-core operand is a 0/1 indicator)."""
     r = np.random.default_rng(seed)
     seg = np.sort(r.integers(0, n, E))    # sorted-COO like arc sources
-    vals = r.integers(0, 2**20, E).astype(np.int32)
-    lo = blocked_layout(seg, n, R=16, be=32)
-    out = np.asarray(segment_sum_blocked(jnp.asarray(vals), lo, n)[:, 0])
-    ref = np.asarray(jax.ops.segment_sum(jnp.asarray(vals),
+    vals = r.integers(0, 2, E).astype(bool)
+    lo = blocked_layout(seg, n, R=128, be=128)
+    out = segment_sum_blocked(jnp.asarray(vals), lo, n)[:, 0]
+    assert out.dtype == jnp.int32
+    ref = np.asarray(jax.ops.segment_sum(jnp.asarray(vals, jnp.int32),
                                          jnp.asarray(seg), num_segments=n))
-    np.testing.assert_array_equal(out, ref)
+    np.testing.assert_array_equal(np.asarray(out), ref)
+
+
+def test_segment_sum_refuses_inexact_inputs(monkeypatch):
+    """Non-indicator integers have no exact path, and a segment that could
+    count past the f32 accumulator's exact range is refused on the host."""
+    from repro.kernels.segment_sum import ops
+
+    seg = np.array([0, 0, 0, 1])
+    lo = blocked_layout(seg, 2, R=128, be=128)
+    assert lo.max_count == 3
+    with pytest.raises(TypeError, match="bool indicators or floats"):
+        segment_sum_blocked(jnp.ones(4, jnp.int32), lo, 2)
+    monkeypatch.setattr(ops, "EXACT_COUNT_LIMIT", 3)
+    with pytest.raises(ValueError, match="counts exactly only below 3"):
+        blocked_layout(seg, 2, R=128, be=128)
 
 
 # ------------------------- flash attention --------------------------- #
@@ -170,7 +187,7 @@ def test_flash_attention_sweep(B, Sq, Sk, Hq, Hkv, D, causal, window, dtype):
 def test_segment_sum_sweep(E, n, F, dtype, rng):
     seg = rng.integers(0, n, E)
     vals = rng.normal(size=(E, F)).astype(dtype)
-    lo = blocked_layout(seg, n, R=32, be=64)
+    lo = blocked_layout(seg, n, R=128, be=256)
     out = segment_sum_blocked(jnp.asarray(vals), lo, n)
     ref = segment_sum_ref(jnp.asarray(vals), jnp.asarray(seg), n)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5,
@@ -183,7 +200,7 @@ def test_segment_sum_property(E, n, seed):
     r = np.random.default_rng(seed)
     seg = r.integers(0, n, E)
     vals = r.normal(size=(E, 4)).astype(np.float32)
-    lo = blocked_layout(seg, n, R=16, be=32)
+    lo = blocked_layout(seg, n, R=128, be=128)
     out = segment_sum_blocked(jnp.asarray(vals), lo, n)
     ref = segment_sum_ref(jnp.asarray(vals), jnp.asarray(seg), n)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-4,

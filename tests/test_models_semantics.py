@@ -56,15 +56,6 @@ def test_swa_masks_far_context():
     assert diff[:16].max() > 1e-3           # but it does change nearby
 
 
-@pytest.mark.xfail(
-    jax.__version__.startswith("0.4."),
-    reason="known pre-seed numeric drift in the MoE virtual-split path on "
-           "jax 0.4.37 (ROADMAP.md); exact on jax >= 0.5. Observed on "
-           "0.4.37: max |h1-h2| = 3.125e-2 (vs atol 3e-2) in the bf16 "
-           "forward, max ~2.95e4 bf16 ulp at near-zero activations, mean "
-           "7.7 ulp. strict: an accidental fix or a worsening regression "
-           "must surface, not pass silently",
-    strict=True)
 def test_moe_virtual_split_is_exact():
     """split-2 virtual experts must equal the unsplit computation when the
     params are tied accordingly."""

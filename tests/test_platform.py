@@ -3,6 +3,8 @@ plumbing, roofline peaks, and the forced-host-device-count lane (the env
 mutation is backend-init-order sensitive, so the device-count assertions
 run in subprocesses)."""
 
+import os
+import pathlib
 import subprocess
 import sys
 
@@ -10,7 +12,10 @@ import pytest
 
 from repro import platform
 
-_ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
+# the checkout the subprocesses run from (they import src/ from here)
+_REPO = pathlib.Path(__file__).resolve().parents[1]
+
+_ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": os.path.expanduser("~"),
         "JAX_PLATFORMS": "cpu"}
 
 
@@ -56,20 +61,92 @@ def test_set_platform_rejects_unknown():
 def test_peaks_defaults_and_env_override(monkeypatch):
     monkeypatch.delenv(platform.ENV_PEAK_GFLOPS, raising=False)
     monkeypatch.delenv(platform.ENV_PEAK_GBS, raising=False)
-    flops, bw = platform.peaks("tpu")
-    assert flops == 197e12 and bw == 819e9   # matches launch.hlo_analysis
+    flops, bw = platform.peaks("TPU v5 lite")   # TPU v5e, Google Cloud docs
+    assert flops == 197e12 and bw == 819e9
     monkeypatch.setenv(platform.ENV_PEAK_GFLOPS, "123")
     monkeypatch.setenv(platform.ENV_PEAK_GBS, "45")
-    flops, bw = platform.peaks("cpu")
+    flops, bw = platform.peaks("TPU v5 lite")
     assert flops == 123e9 and bw == 45e9
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v99", None])
+def test_peaks_unknown_device_kind_raises(kind):
+    """No default row: a device without published peaks (the CPU the tests
+    run on, for one) is an error, not the nearest guess."""
+    with pytest.raises(KeyError, match="no published peaks"):
+        platform.peaks(kind)
 
 
 def test_summary_reports_resolved_state():
     s = platform.summary()
-    assert s["backend"] in ("cpu", "gpu", "tpu")
-    assert s["device_count"] >= 1
+    assert s["platform"] == "cpu" and s["kind"] == "cpu"
+    assert s["count"] >= 1
     assert s["dispatch_mode"] in ("auto", "pallas", "xla")
-    assert s["peak_gflops"] > 0 and s["peak_gbs"] > 0
+    assert s["interpret_kernels"] is True
+
+
+# ------------------------ device checks -------------------------------- #
+
+def test_interpret_kernels_by_backend(monkeypatch):
+    """Interpret mode on CPU only; TPU compiles natively; any other
+    backend raises instead of quietly interpreting the kernels."""
+    import jax
+
+    assert platform.interpret_kernels() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert platform.interpret_kernels() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="not on 'gpu'"):
+        platform.interpret_kernels()
+
+
+def test_require_platform_and_devices():
+    platform.require_platform("cpu")
+    with pytest.raises(RuntimeError, match="asked for platform 'tpu'"):
+        platform.require_platform("tpu")
+    assert len(platform.require_devices(1)) == 1
+    with pytest.raises(RuntimeError, match="this host has"):
+        platform.require_devices(10_000)
+
+
+# ------------------------ compile cache -------------------------------- #
+
+@pytest.fixture
+def restore_cache_config():
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    saved = (jax.config.jax_compilation_cache_dir,
+             jax.config.jax_enable_compilation_cache)
+    yield
+    jax.config.update("jax_compilation_cache_dir", saved[0])
+    jax.config.update("jax_enable_compilation_cache", saved[1])
+    compilation_cache.reset_cache()
+
+
+def test_compile_cache_default_is_fixed_checkout_path(monkeypatch,
+                                                      restore_cache_config):
+    import jax
+
+    monkeypatch.delenv(platform.ENV_COMPILE_CACHE, raising=False)
+    path = platform.enable_compile_cache()
+    assert path == str(platform.COMPILE_CACHE_DIR)
+    assert platform.COMPILE_CACHE_DIR.name == ".jax_cache"
+    assert (platform.COMPILE_CACHE_DIR.parent / "src" / "repro").is_dir()
+    assert jax.config.jax_compilation_cache_dir == path
+    assert platform.enable_compile_cache() == path   # same path every call
+
+
+def test_compile_cache_env_dir_wins(monkeypatch, restore_cache_config,
+                                    tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX reads it and the helper sets
+    no directory of its own."""
+    import jax
+
+    monkeypatch.setenv(platform.ENV_COMPILE_CACHE, str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert platform.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
 
 
 # ----------------------- forced host device count ---------------------- #
@@ -111,7 +188,7 @@ def test_configure_from_env_forces_devices_subprocess():
     )
     proc = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
-        env={**_ENV, "REPRO_HOST_DEVICES": "4"}, cwd="/root/repo",
+        env={**_ENV, "REPRO_HOST_DEVICES": "4"}, cwd=_REPO,
         timeout=300)
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert proc.stdout.strip().endswith("OK")
