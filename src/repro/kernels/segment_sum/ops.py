@@ -111,10 +111,10 @@ def segment_sum_arrays(
         raise TypeError(f"segment sum takes bool indicators or floats, got {vals.dtype}")
     if vals.ndim == 1:
         vals = vals[:, None]
+    dt = jnp.bfloat16 if counting else jnp.float32
     cols = []
     for f in range(vals.shape[1]):
         # slot_edge == E on padding slots: read the appended zero
-        dt = jnp.bfloat16 if counting else jnp.float32
         x = jnp.concatenate([vals[:, f].astype(dt), jnp.zeros(1, dt)])[slot_edge]
         out = segment_sum_pallas(
             x, rows_local, block_row, n_rows_pad // R, R=R, interpret=_platform.interpret_kernels()

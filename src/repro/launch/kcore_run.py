@@ -22,7 +22,7 @@ init, so this module defers its jax imports). The two compose: ``--mesh N
 --fused`` nests the masked shard_map superstep inside the while_loop.
 
 ``build_graph``, ``decompose`` and ``report`` are the steps ``main`` runs
-after ``repro.platform.configure_run``, importable so other drivers
+after ``repro.platform.configure_run``, importable so other callers
 (chip_smoke.py) take the same path.
 """
 

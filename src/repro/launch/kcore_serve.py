@@ -53,7 +53,7 @@ counts are identical to the single-device engine on any mesh (that equality
 is CI-tested).
 
 ``serve_churn`` is the synthetic-churn serving loop ``main`` runs,
-importable so other drivers (chip_smoke.py) take the same path.
+importable so other callers (chip_smoke.py) take the same path.
 """
 
 from __future__ import annotations
