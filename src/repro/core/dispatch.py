@@ -28,6 +28,14 @@ the kernels count 0/1 indicators exactly, ``ref.py`` stays the independent
 oracle, and tests/test_dispatch.py asserts the equality across host, fused,
 and sharded modes. The sharded (shard_map) paths keep the XLA segment ops.
 
+Inside the programs the parts of the round carry ``jax.named_scope``s —
+``kcore.gather``, ``kcore.hindex``, ``kcore.changed``, ``kcore.recv`` and
+(in the fused loop) ``kcore.stats`` — and the kernels their
+``pallas_call`` names (``kcore_hindex``, ``segment_sum``), so a device
+trace ties each op to the part of the round it computes. Staging the
+graph operands is a ``stage`` layer span (repro.obs.trace.layer) that
+counts the bytes it copies to the device (``h2d_bytes``).
+
 The graph enters every program as jit ARGUMENTS (``GraphOperands``: arcs,
 the blocked segment-sum layout, the ELL tables), never as closed-over
 constants, so jax compiles one program per operand SHAPE: a graph of 10^8
@@ -50,6 +58,7 @@ from repro import platform as _platform
 from repro.graph.structs import EllGraph
 from repro.kernels.kcore_hindex.ops import hindex_rows
 from repro.kernels.segment_sum.ops import blocked_layout, segment_sum_arrays
+from repro.obs import trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,18 +122,29 @@ class Program:
         return self.fn.lower(self.operands, *args, **dict(self.static))
 
 
+def to_device(stage: trace.LayerSpan, x, dtype=None) -> jax.Array:
+    """``jnp.asarray(x, dtype)``, adding to ``stage``'s ``h2d_bytes`` the
+    bytes copied when ``x`` is a host array (0 when it is on the device)."""
+    out = jnp.asarray(x, dtype)
+    if not isinstance(x, jax.Array):
+        stage.add("h2d_bytes", out.nbytes)
+    return out
+
+
 def _stage(plan: DispatchPlan, src, dst, n: int, ell: EllGraph | None):
-    """Host graph arrays -> (GraphOperands, static layout args)."""
-    src_np = np.asarray(src, np.int32)
-    seg, R, n_rows_pad = (), 0, 0
-    if plan.kind == "pallas":
-        layout = blocked_layout(src_np, n)
-        seg = tuple(jnp.asarray(a) for a in (layout.slot_edge, layout.rows_local, layout.block_row))
-        R, n_rows_pad = layout.R, layout.n_rows_pad
-    buckets = ()
-    if ell is not None and plan.kind == "pallas":
-        buckets = tuple((jnp.asarray(b.ids), jnp.asarray(b.nbrs)) for b in ell.buckets)
-    ops = GraphOperands(jnp.asarray(src_np), jnp.asarray(dst, jnp.int32), seg, buckets)
+    """Host graph arrays -> (GraphOperands, static layout args), inside one
+    ``stage`` layer span."""
+    with trace.layer("stage", h2d_bytes=0) as st:
+        src_np = np.asarray(src, np.int32)
+        seg, R, n_rows_pad = (), 0, 0
+        if plan.kind == "pallas":
+            layout = blocked_layout(src_np, n)
+            seg = tuple(to_device(st, a) for a in (layout.slot_edge, layout.rows_local, layout.block_row))
+            R, n_rows_pad = layout.R, layout.n_rows_pad
+        buckets = ()
+        if ell is not None and plan.kind == "pallas":
+            buckets = tuple((to_device(st, b.ids), to_device(st, b.nbrs)) for b in ell.buckets)
+        ops = GraphOperands(to_device(st, src_np), to_device(st, dst, jnp.int32), seg, buckets)
     return ops, (("R", R), ("n_rows_pad", n_rows_pad))
 
 
@@ -161,11 +181,15 @@ def _round(ops: GraphOperands, est, arc_mask, active, *, n, n_iters, R, n_rows_p
         est_ext = jnp.concatenate([est, jnp.zeros(1, jnp.int32)])
         new_ext = est_ext
         for ids, nbrs in ops.ell:
-            h = hindex_rows(est_ext[nbrs], est_ext[ids], n_iters=n_iters)
-            new_ext = new_ext.at[ids].set(h)
+            with jax.named_scope("kcore.gather"):
+                nbr_est, own_est = est_ext[nbrs], est_ext[ids]
+            with jax.named_scope("kcore.hindex"):
+                h = hindex_rows(nbr_est, own_est, n_iters=n_iters)
+                new_ext = new_ext.at[ids].set(h)
         h = new_ext[:n]
     else:
-        est_dst = jnp.where(arc_mask, est[dst], 0)
+        with jax.named_scope("kcore.gather"):
+            est_dst = jnp.where(arc_mask, est[dst], 0)
 
         def body(lohi, _):
             lo, hi = lohi
@@ -176,10 +200,13 @@ def _round(ops: GraphOperands, est, arc_mask, active, *, n, n_iters, R, n_rows_p
 
         # lax.scan (not fori_loop) like core.kcore._hindex_by_bsearch:
         # the trip count stays visible to jaxpr-walk cost analyses
-        (h, _), _ = lax.scan(body, (jnp.zeros_like(est), est), None, length=n_iters)
-    new_est = jnp.where(active, h, est)
-    changed = new_est < est
-    recv = count(jnp.where(arc_mask, changed[dst], False)) > 0
+        with jax.named_scope("kcore.hindex"):
+            (h, _), _ = lax.scan(body, (jnp.zeros_like(est), est), None, length=n_iters)
+    with jax.named_scope("kcore.changed"):
+        new_est = jnp.where(active, h, est)
+        changed = new_est < est
+    with jax.named_scope("kcore.recv"):
+        recv = count(jnp.where(arc_mask, changed[dst], False)) > 0
     return new_est, changed, recv
 
 
@@ -199,10 +226,11 @@ def _fused_jit(ops, est, arc_mask, active, deg, *, n, n_iters, max_rounds, R, n_
         new_est, changed, recv = _round(
             ops, est, arc_mask, act, n=n, n_iters=n_iters, R=R, n_rows_pad=n_rows_pad
         )
-        any_ch = changed.any()
-        mb = mb.at[r].set(jnp.sum(jnp.where(changed, deg, 0), dtype=jnp.int32))
-        cb = cb.at[r].set(jnp.sum(changed, dtype=jnp.int32))
-        rb = rb.at[r].set(jnp.sum(recv, dtype=jnp.int32))
+        with jax.named_scope("kcore.stats"):
+            any_ch = changed.any()
+            mb = mb.at[r].set(jnp.sum(jnp.where(changed, deg, 0), dtype=jnp.int32))
+            cb = cb.at[r].set(jnp.sum(changed, dtype=jnp.int32))
+            rb = rb.at[r].set(jnp.sum(recv, dtype=jnp.int32))
         return new_est, recv, r + 1, ~any_ch, mb, cb, rb
 
     zeros = jnp.zeros(max_rounds, jnp.int32)

@@ -15,7 +15,9 @@ and, when span tracing is live (repro.obs.trace), records each compile as
 an ``xla.compile`` span ending at the current clock, which lands it inside
 whatever engine span was open while the compile ran. That is how a trace
 attributes "this batch was slow because it minted a fresh program" to the
-exact batch/phase that paid for it.
+exact batch/phase that paid for it. Every layer span (repro.obs.trace.layer)
+also records the count's change across it as its ``compiles``, traced or
+not.
 
 The listener registers lazily on first use and is a no-op counter bump,
 so leaving it installed costs nothing. Should jax stop emitting the

@@ -88,11 +88,13 @@ class KCoreResult:
     # ... and the wall-clock XLA spent on those compiles (the duration-
     # valued twin: jit_telemetry.compile_seconds delta)
     compile_s: float = 0.0
-    # per-phase wall breakdown (seconds). Fused runs report the runtime's
-    # split: "device-converge" (the while_loop, blocked to completion) and
-    # "host-reconstruct" (stats recovery); host-loop runs report "converge"
-    # (the whole round loop). Always measured — two perf_counter pairs per
-    # DECOMPOSITION, not per round.
+    # per-phase wall breakdown (seconds), the durations of the layer spans
+    # (repro.obs.trace.layer). Fused runs report the runtime's split:
+    # "device-converge" (staging and the while_loop, blocked to
+    # completion), "host-reconstruct" (stats recovery), and "stage" (every
+    # host staging span: ELL layout, blocked layout, H2D copies) and
+    # "device-loop" (the while_loop alone); host-loop runs report
+    # "converge" (the whole round loop).
     phase_s: dict = dataclasses.field(default_factory=dict)
     # resolved superstep dispatch this run executed with ("xla" | "pallas");
     # see repro.core.dispatch — bills are bit-equal across choices
@@ -410,8 +412,8 @@ def kcore_decompose(g: Graph, config: KCoreConfig = KCoreConfig(), *,
     if use_fused and config.mode != "jacobi":
         raise ValueError("fused=True requires mode='jacobi' "
                          f"(got {config.mode!r})")
-    with _trace.span("kcore.decompose", n=g.n, m=g.m, mode=config.mode,
-                     backend=config.backend, fused=bool(use_fused)) as _sp:
+    with _trace.layer("kcore.decompose", n=g.n, m=g.m, mode=config.mode,
+                      backend=config.backend, fused=bool(use_fused)) as _sp:
         res = _decompose_body(g, config, use_fused)
         _sp.set(rounds=res.rounds, messages=res.stats.total_messages,
                 converged=res.converged, recompiles=res.recompiles,
@@ -456,12 +458,14 @@ def _decompose_body(g: Graph, config: KCoreConfig,
 
         plan = _dispatch.resolve_plan(config.dispatch)
         ell = None
-        if plan.kind == "pallas":
-            from repro.graph.structs import build_ell
+        with _trace.layer("stage", h2d_bytes=0) as ell_stage:
+            if plan.kind == "pallas":
+                from repro.graph.structs import build_ell
 
-            # static fully-live adjacency + degree seed: the ELL h-index
-            # route is exact here (see dispatch._make_round_body)
-            ell = build_ell(g, widths=config.widths)
+                # static fully-live adjacency + degree seed: the ELL
+                # h-index route is exact here (see dispatch._round)
+                ell = build_ell(g, widths=config.widths)
+            arc_mask = np.ones(g.num_arcs, bool)
         # from-scratch seeding: est = degrees, frontier = every vertex —
         # round 1 of the fused loop IS round 1 of the host loop, and the
         # recv-masked rounds after it are exact for the monotone locality
@@ -470,8 +474,7 @@ def _decompose_body(g: Graph, config: KCoreConfig,
         # bills only (deg>0) receivers in round 1 — pass the accounting
         # value so flight records match the host loop bit-for-bit
         outcome = fused_converge_dense(
-            g.deg, np.ones(n, bool), g.src, g.dst,
-            np.ones(g.num_arcs, bool), g.deg,
+            g.deg, np.ones(n, bool), g.src, g.dst, arc_mask, g.deg,
             n=n, n_iters=n_iters, max_rounds=max_rounds,
             dispatch=plan.kind, ell=ell, frontier1=active[1])
         rounds, converged = outcome.rounds, outcome.converged
@@ -483,6 +486,8 @@ def _decompose_body(g: Graph, config: KCoreConfig,
         core = outcome.est
         phase_s["device-converge"] = outcome.device_s
         phase_s["host-reconstruct"] = outcome.reconstruct_s
+        phase_s["stage"] = ell_stage.seconds + outcome.stage_s
+        phase_s["device-loop"] = outcome.loop_s
 
     elif config.backend == "segment" and config.mode == "jacobi":
         plan = _dispatch.resolve_plan(config.dispatch)
@@ -506,28 +511,28 @@ def _decompose_body(g: Graph, config: KCoreConfig,
             def step(est):
                 return _round_segment(est, src, dst, amask, n, n_iters)
         rounds, converged = 0, False
-        t_conv = time.perf_counter()
-        while rounds < max_rounds:
-            t_r = time.perf_counter() if rec.active else 0.0
-            with _trace.span("kcore.round", round=rounds) as rsp:
-                new_est, changed, recv = step(est)
-                rounds += 1
-                ch_np = np.asarray(changed)
-                if not ch_np.any():
-                    converged = True
-                    break
-                msgs.append(int(deg64[ch_np].sum()))
-                changed_counts.append(int(ch_np.sum()))
-                active.append(int(np.asarray(recv).sum()))
-                rsp.set(messages=msgs[-1], changed=changed_counts[-1])
-                if rec.active:
-                    rec.record_round(
-                        active[rounds], msgs[-1], changed_counts[-1],
-                        est=np.asarray(new_est), prev_est=np.asarray(est),
-                        host_s=time.perf_counter() - t_r,
-                        dispatch=dispatch_kind)
-                est = new_est
-        phase_s["converge"] = time.perf_counter() - t_conv
+        with _trace.layer("converge") as conv:
+            while rounds < max_rounds:
+                t_r = time.perf_counter() if rec.active else 0.0
+                with _trace.span("kcore.round", round=rounds) as rsp:
+                    new_est, changed, recv = step(est)
+                    rounds += 1
+                    ch_np = np.asarray(changed)
+                    if not ch_np.any():
+                        converged = True
+                        break
+                    msgs.append(int(deg64[ch_np].sum()))
+                    changed_counts.append(int(ch_np.sum()))
+                    active.append(int(np.asarray(recv).sum()))
+                    rsp.set(messages=msgs[-1], changed=changed_counts[-1])
+                    if rec.active:
+                        rec.record_round(
+                            active[rounds], msgs[-1], changed_counts[-1],
+                            est=np.asarray(new_est), prev_est=np.asarray(est),
+                            host_s=time.perf_counter() - t_r,
+                            dispatch=dispatch_kind)
+                    est = new_est
+        phase_s["converge"] = conv.seconds
         core = np.asarray(est, np.int32)
 
     elif config.backend in ("ell", "ell_pallas") and config.mode == "jacobi":
@@ -540,30 +545,30 @@ def _decompose_body(g: Graph, config: KCoreConfig,
         est_ext = jnp.concatenate(
             [jnp.asarray(g.deg, jnp.int32), jnp.zeros(1, jnp.int32)])
         rounds, converged = 0, False
-        t_conv = time.perf_counter()
-        while rounds < max_rounds:
-            t_r = time.perf_counter() if rec.active else 0.0
-            with _trace.span("kcore.round", round=rounds):
-                new_ext, changed = round_fn(est_ext)
-                rounds += 1
-                ch_np = np.asarray(changed)
-                if not ch_np.any():
-                    converged = True
-                    break
-                msgs.append(int(deg64[ch_np].sum()))
-                changed_counts.append(int(ch_np.sum()))
-                # receivers: any vertex adjacent to a changed vertex
-                recv = _receivers_np(g, ch_np)
-                active.append(int(recv.sum()))
-                if rec.active:
-                    rec.record_round(
-                        active[rounds], msgs[-1], changed_counts[-1],
-                        est=np.asarray(new_ext)[:n],
-                        prev_est=np.asarray(est_ext)[:n],
-                        host_s=time.perf_counter() - t_r,
-                        dispatch=dispatch_kind)
-                est_ext = new_ext
-        phase_s["converge"] = time.perf_counter() - t_conv
+        with _trace.layer("converge") as conv:
+            while rounds < max_rounds:
+                t_r = time.perf_counter() if rec.active else 0.0
+                with _trace.span("kcore.round", round=rounds):
+                    new_ext, changed = round_fn(est_ext)
+                    rounds += 1
+                    ch_np = np.asarray(changed)
+                    if not ch_np.any():
+                        converged = True
+                        break
+                    msgs.append(int(deg64[ch_np].sum()))
+                    changed_counts.append(int(ch_np.sum()))
+                    # receivers: any vertex adjacent to a changed vertex
+                    recv = _receivers_np(g, ch_np)
+                    active.append(int(recv.sum()))
+                    if rec.active:
+                        rec.record_round(
+                            active[rounds], msgs[-1], changed_counts[-1],
+                            est=np.asarray(new_ext)[:n],
+                            prev_est=np.asarray(est_ext)[:n],
+                            host_s=time.perf_counter() - t_r,
+                            dispatch=dispatch_kind)
+                    est_ext = new_ext
+        phase_s["converge"] = conv.seconds
         core = np.asarray(est_ext[:n], np.int32)
 
     elif config.mode == "block_gs":
@@ -572,28 +577,28 @@ def _decompose_body(g: Graph, config: KCoreConfig,
         round_fn = _make_round_block_gs(sg, n_iters)
         est = jnp.asarray(sg.deg.reshape(-1), jnp.int32)
         rounds, converged = 0, False
-        t_conv = time.perf_counter()
-        while rounds < max_rounds:
-            t_r = time.perf_counter() if rec.active else 0.0
-            with _trace.span("kcore.round", round=rounds):
-                new_est, changed = round_fn(est)
-                rounds += 1
-                ch_real = np.asarray(changed)[: g.n]
-                if not ch_real.any():
-                    converged = True
-                    break
-                msgs.append(int(deg64[ch_real].sum()))
-                changed_counts.append(int(ch_real.sum()))
-                active.append(int(_receivers_np(g, ch_real).sum()))
-                if rec.active:
-                    rec.record_round(
-                        active[rounds], msgs[-1], changed_counts[-1],
-                        est=np.asarray(new_est)[: g.n],
-                        prev_est=np.asarray(est)[: g.n],
-                        host_s=time.perf_counter() - t_r,
-                        dispatch=dispatch_kind)
-                est = new_est
-        phase_s["converge"] = time.perf_counter() - t_conv
+        with _trace.layer("converge") as conv:
+            while rounds < max_rounds:
+                t_r = time.perf_counter() if rec.active else 0.0
+                with _trace.span("kcore.round", round=rounds):
+                    new_est, changed = round_fn(est)
+                    rounds += 1
+                    ch_real = np.asarray(changed)[: g.n]
+                    if not ch_real.any():
+                        converged = True
+                        break
+                    msgs.append(int(deg64[ch_real].sum()))
+                    changed_counts.append(int(ch_real.sum()))
+                    active.append(int(_receivers_np(g, ch_real).sum()))
+                    if rec.active:
+                        rec.record_round(
+                            active[rounds], msgs[-1], changed_counts[-1],
+                            est=np.asarray(new_est)[: g.n],
+                            prev_est=np.asarray(est)[: g.n],
+                            host_s=time.perf_counter() - t_r,
+                            dispatch=dispatch_kind)
+                    est = new_est
+        phase_s["converge"] = conv.seconds
         core = np.asarray(est)[: g.n].astype(np.int32)
 
     else:
@@ -782,8 +787,8 @@ def kcore_decompose_sharded(g: Graph, mesh: jax.sharding.Mesh,
                       n=g.n)
         rec.record_round(active[0], msgs[0], changed_counts[0], est=g.deg)
 
-    with _trace.span("kcore.decompose", n=g.n, m=g.m, mode="sharded",
-                     mesh_devices=n_dev, fused=bool(fused)) as _sp:
+    with _trace.layer("kcore.decompose", n=g.n, m=g.m, mode="sharded",
+                      mesh_devices=n_dev, fused=bool(fused)) as _sp:
         if fused:
             from repro.core.runtime import fused_converge_sharded
 
@@ -799,6 +804,8 @@ def kcore_decompose_sharded(g: Graph, mesh: jax.sharding.Mesh,
             core = outcome.est
             phase_s["device-converge"] = outcome.device_s
             phase_s["host-reconstruct"] = outcome.reconstruct_s
+            phase_s["stage"] = outcome.stage_s
+            phase_s["device-loop"] = outcome.loop_s
         else:
             superstep, _ = make_sharded_superstep(sg, mesh, axis_names, n_iters)
             superstep = jax.jit(superstep)
@@ -810,28 +817,28 @@ def kcore_decompose_sharded(g: Graph, mesh: jax.sharding.Mesh,
             deg = jnp.asarray(sg.deg)
 
             rounds, converged = 0, False
-            t_conv = time.perf_counter()
-            while rounds < cap:
-                t_r = time.perf_counter() if rec.active else 0.0
-                with _trace.span("kcore.round", round=rounds) as rsp:
-                    new_est, m, any_ch = superstep(est, src, dst, amask, deg)
-                    rounds += 1
-                    if not bool(any_ch):
-                        converged = True
-                        break
-                    ch_real = np.asarray(new_est < est).reshape(-1)[: g.n]
-                    msgs.append(int(m))
-                    changed_counts.append(int(ch_real.sum()))
-                    active.append(int(_receivers_np(g, ch_real).sum()))
-                    rsp.set(messages=msgs[-1], changed=changed_counts[-1])
-                    if rec.active:
-                        rec.record_round(
-                            active[rounds], msgs[-1], changed_counts[-1],
-                            est=np.asarray(new_est).reshape(-1)[: g.n],
-                            prev_est=np.asarray(est).reshape(-1)[: g.n],
-                            host_s=time.perf_counter() - t_r)
-                    est = new_est
-            phase_s["converge"] = time.perf_counter() - t_conv
+            with _trace.layer("converge") as conv:
+                while rounds < cap:
+                    t_r = time.perf_counter() if rec.active else 0.0
+                    with _trace.span("kcore.round", round=rounds) as rsp:
+                        new_est, m, any_ch = superstep(est, src, dst, amask, deg)
+                        rounds += 1
+                        if not bool(any_ch):
+                            converged = True
+                            break
+                        ch_real = np.asarray(new_est < est).reshape(-1)[: g.n]
+                        msgs.append(int(m))
+                        changed_counts.append(int(ch_real.sum()))
+                        active.append(int(_receivers_np(g, ch_real).sum()))
+                        rsp.set(messages=msgs[-1], changed=changed_counts[-1])
+                        if rec.active:
+                            rec.record_round(
+                                active[rounds], msgs[-1], changed_counts[-1],
+                                est=np.asarray(new_est).reshape(-1)[: g.n],
+                                prev_est=np.asarray(est).reshape(-1)[: g.n],
+                                host_s=time.perf_counter() - t_r)
+                        est = new_est
+            phase_s["converge"] = conv.seconds
             core = np.asarray(est).reshape(-1)[: g.n].astype(np.int32)
         _sp.set(rounds=rounds, converged=converged,
                 messages=int(np.asarray(msgs, np.int64).sum()))

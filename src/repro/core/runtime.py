@@ -19,29 +19,28 @@ host-loop modes would have appended round by round (BZ-verified and
 hypothesis-tested), so fusing is purely an execution-placement choice —
 never an accounting one.
 
-Every fused run is observable (repro.obs): a ``fused-converge`` span wraps
-the whole dispatch with ``device-converge`` (the while_loop itself, blocked
-to completion so the span owns the real device wall) and
-``stats-reconstruct`` (host-side MessageStats recovery) children, plus
-attributes for rounds, messages, and the compile count/seconds delta this
-run caused (repro.core.jit_telemetry — fresh XLA compiles land inside the
-``device-converge`` span as ``xla.compile`` events). The phase walls are
-also measured unconditionally into ``FusedOutcome.device_s`` /
-``reconstruct_s`` (two ``perf_counter`` pairs per BATCH — nanoseconds
-against a convergence that runs for milliseconds) so benchmark rows get
-the breakdown without tracing on.
+Every fused run is observable (repro.obs) through always-timed layer
+spans: ``fused-converge`` wraps the whole dispatch, with
+``device-converge`` (``stage``: host staging and host-to-device copies,
+``h2d_bytes`` counted; then ``device-loop``: the compiled while_loop from
+its call until ``block_until_ready`` returns) and ``stats-reconstruct``
+(the stat-buffer slices and the estimate fetch, and the compiles they
+cause) children. ``FusedOutcome.stage_s`` / ``loop_s`` / ``device_s`` /
+``reconstruct_s`` are those spans' durations, so benchmark rows get the
+breakdown without tracing on; the run's compile count/seconds delta and
+its rounds and messages are attached to ``fused-converge``.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import dispatch as _dispatch
+from repro.core.dispatch import to_device
 from repro.core.jit_telemetry import compile_count, compile_seconds
 from repro.core.kcore import (
     _fused_sharded_convergence,
@@ -67,8 +66,10 @@ class FusedOutcome:
     msgs: np.ndarray  # (k,) int64 messages per productive round
     changed: np.ndarray  # (k,) int64 senders per productive round
     recv: np.ndarray  # (k,) int64 receivers per productive round
-    # phase walls (always measured; see module docstring):
-    device_s: float = 0.0  # fused while_loop dispatch + device completion
+    # phase walls, the layer spans' durations (see module docstring):
+    device_s: float = 0.0  # staging + the while_loop to device completion
+    stage_s: float = 0.0  # ... of which host staging and H2D copies
+    loop_s: float = 0.0  # ... of which the compiled while_loop
     reconstruct_s: float = 0.0  # host-side stats/est reconstruction
     compile_delta: int = 0  # fresh XLA compiles this run caused
     compile_s: float = 0.0  # ... and the wall XLA spent on them
@@ -88,62 +89,76 @@ def _shard_devices(x) -> tuple:
 def _finish(
     span,
     raw,
-    rounds_raw,
-    t_dev,
+    dev,
     compiles0,
     csecs0,
     est_of,
     dispatch="xla",
     frontier1=None,
     seed=None,
-    devices=(),
 ):
-    """Shared tail of both fused paths: block, time phases, reconstruct."""
-    t0 = time.perf_counter()
-    r, stop, final_act, mb, cb, rb = raw
-    _k, m_r, c_r, r_r, converged = fused_round_stats(rounds_raw, stop, final_act, mb, cb, rb)
-    est = est_of()
-    reconstruct_s = time.perf_counter() - t0
-    outcome = FusedOutcome(
-        est=est,
-        rounds=int(rounds_raw),
-        converged=converged,
-        msgs=m_r,
-        changed=c_r,
-        recv=r_r,
-        device_s=t_dev,
-        reconstruct_s=reconstruct_s,
-        compile_delta=compile_count() - compiles0,
-        compile_s=compile_seconds() - csecs0,
-        dispatch=dispatch,
-        devices=devices,
-    )
-    span.set(
-        rounds=outcome.rounds,
-        messages=int(outcome.msgs.sum()),
-        converged=outcome.converged,
-        compile_delta=outcome.compile_delta,
-        compile_s=round(outcome.compile_s, 6),
-    )
-    # flight capture, reconstructed post-hoc from the while_loop stat
-    # buffers: exactly the rounds a host loop would have recorded, same
-    # accounting arrays. No-op (single attribute read) when disabled.
-    rec = flight.recorder()
-    if rec.active:
-        rec.record_fused_rounds(
-            outcome.msgs,
-            outcome.changed,
-            outcome.recv,
-            frontier1=int(frontier1) if frontier1 is not None else (
-                int(outcome.recv[0]) if len(outcome.recv) else 0
-            ),
-            device_s=t_dev,
-            compiles=outcome.compile_delta,
+    """Shared tail of both fused paths: reconstruct the per-round stats and
+    fetch the estimate inside ``stats-reconstruct``, after the
+    ``device-converge`` span ``dev`` has closed."""
+    with trace.layer("stats-reconstruct") as rec_span:
+        est_j, r, stop, final_act, mb, cb, rb = raw
+        # slices of the device buffers by the round count: a count not
+        # seen before compiles its slice programs here
+        _k, m_r, c_r, r_r, converged = fused_round_stats(r, stop, final_act, mb, cb, rb)
+        est = est_of()
+        outcome = FusedOutcome(
+            est=est,
+            rounds=int(r),
+            converged=converged,
+            msgs=m_r,
+            changed=c_r,
+            recv=r_r,
+            device_s=dev.seconds,
+            stage_s=dev.children_s.get("stage", 0.0),
+            loop_s=dev.children_s.get("device-loop", 0.0),
+            compile_delta=compile_count() - compiles0,
+            compile_s=compile_seconds() - csecs0,
             dispatch=dispatch,
-            seed=seed,
-            final=est,
+            devices=_shard_devices(est_j),
         )
+        span.set(
+            rounds=outcome.rounds,
+            messages=int(outcome.msgs.sum()),
+            converged=outcome.converged,
+            compile_delta=outcome.compile_delta,
+            compile_s=round(outcome.compile_s, 6),
+        )
+        # flight capture, reconstructed post-hoc from the while_loop stat
+        # buffers: exactly the rounds a host loop would have recorded, same
+        # accounting arrays. No-op (single attribute read) when disabled.
+        rec = flight.recorder()
+        if rec.active:
+            rec.record_fused_rounds(
+                outcome.msgs,
+                outcome.changed,
+                outcome.recv,
+                frontier1=int(frontier1) if frontier1 is not None else (
+                    int(outcome.recv[0]) if len(outcome.recv) else 0
+                ),
+                device_s=outcome.device_s,
+                compiles=outcome.compile_delta,
+                dispatch=dispatch,
+                seed=seed,
+                final=est,
+            )
+    outcome.reconstruct_s = rec_span.seconds
     return outcome
+
+
+def _device_loop(program, *args, **static):
+    """Run a compiled fused program to device completion inside a
+    ``device-loop`` layer span."""
+    with trace.layer("device-loop"):
+        out = program(*args, **static)
+        # block INSIDE the span: the async dispatch returns immediately,
+        # and without the sync the device wall would be misattributed to
+        # whichever np.asarray happens to touch a result first
+        return jax.block_until_ready(out)
 
 
 def fused_converge_dense(
@@ -177,10 +192,10 @@ def fused_converge_dense(
         if frontier1 is None:
             frontier1 = int(np.asarray(active).sum())
         seed_np = np.asarray(seed, np.int64).copy()
-    with trace.span("fused-converge", n=n, max_rounds=max_rounds, dispatch=plan.kind) as span:
-        with trace.span("device-converge"):
-            t0 = time.perf_counter()
+    with trace.layer("fused-converge", n=n, max_rounds=max_rounds, dispatch=plan.kind) as span:
+        with trace.layer("device-converge") as dev:
             if plan.kind == "pallas":
+                # the graph operands are staged in their own ``stage`` span
                 prog = _dispatch.fused_convergence_program(
                     n,
                     n_iters,
@@ -190,43 +205,36 @@ def fused_converge_dense(
                     np.asarray(dst, np.int32),
                     ell=ell,
                 )
-                est_j, r, stop, final_act, mb, cb, rb = prog(
-                    jnp.asarray(seed, jnp.int32),
-                    jnp.asarray(arc_mask),
-                    jnp.asarray(active),
-                    jnp.asarray(deg, jnp.int32),
-                )
+                with trace.layer("stage", h2d_bytes=0) as st:
+                    args = (
+                        to_device(st, seed, jnp.int32),
+                        to_device(st, arc_mask),
+                        to_device(st, active),
+                        to_device(st, deg, jnp.int32),
+                    )
+                out = _device_loop(prog, *args)
             else:
-                est_j, r, stop, final_act, mb, cb, rb = fused_convergence(
-                    jnp.asarray(seed, jnp.int32),
-                    jnp.asarray(src, jnp.int32),
-                    jnp.asarray(dst, jnp.int32),
-                    jnp.asarray(arc_mask),
-                    jnp.asarray(active),
-                    jnp.asarray(deg, jnp.int32),
-                    n=n,
-                    n_iters=n_iters,
-                    max_rounds=max_rounds,
-                )
-            # block INSIDE the span: the async dispatch returns immediately,
-            # and without the sync the device wall would be misattributed to
-            # whichever np.asarray happens to touch a result first
-            est_j = jax.block_until_ready(est_j)
-            t_dev = time.perf_counter() - t0
-        with trace.span("stats-reconstruct"):
-            return _finish(
-                span,
-                (r, stop, final_act, mb, cb, rb),
-                r,
-                t_dev,
-                compiles0,
-                csecs0,
-                lambda: np.asarray(est_j, np.int32),
-                dispatch=plan.kind,
-                frontier1=frontier1,
-                seed=seed_np,
-                devices=_shard_devices(est_j),
-            )
+                with trace.layer("stage", h2d_bytes=0) as st:
+                    args = (
+                        to_device(st, seed, jnp.int32),
+                        to_device(st, src, jnp.int32),
+                        to_device(st, dst, jnp.int32),
+                        to_device(st, arc_mask),
+                        to_device(st, active),
+                        to_device(st, deg, jnp.int32),
+                    )
+                out = _device_loop(fused_convergence, *args, n=n, n_iters=n_iters, max_rounds=max_rounds)
+        return _finish(
+            span,
+            out,
+            dev,
+            compiles0,
+            csecs0,
+            lambda: np.asarray(out[0], np.int32),
+            dispatch=plan.kind,
+            frontier1=frontier1,
+            seed=seed_np,
+        )
 
 
 def fused_converge_sharded(seed, active, sg, mesh, axis_names, *, n, n_iters, max_rounds, frontier1=None):
@@ -260,44 +268,43 @@ def fused_converge_sharded(seed, active, sg, mesh, axis_names, *, n, n_iters, ma
         seed_np = np.asarray(seed, np.int64).copy()
     multiproc = compat.is_multiprocess_mesh(mesh)
     axes = tuple(axis_names)
-    if multiproc:
-        def stage(a):
-            return compat.stage_to_mesh(np.asarray(a), mesh, P(axes))
-    else:
-        stage = jnp.asarray
-    with trace.span("fused-converge", n=n, max_rounds=max_rounds,
-                    mesh_devices=sg.n_shards, multiprocess=multiproc) as span:
+
+    def stage(st, a):
+        if multiproc:
+            a = np.asarray(a)
+            st.add("h2d_bytes", a.nbytes)
+            return compat.stage_to_mesh(a, mesh, P(axes))
+        return to_device(st, a)
+
+    with trace.layer("fused-converge", n=n, max_rounds=max_rounds,
+                     mesh_devices=sg.n_shards, multiprocess=multiproc) as span:
         prog = _fused_sharded_convergence(
             mesh, axes, sg.verts_per_shard, n_iters, max_rounds
         )
         n_dev, V = sg.n_shards, sg.verts_per_shard
-        est_p = np.zeros(sg.n_pad, np.int32)
-        est_p[:n] = seed
-        act_p = np.zeros(sg.n_pad, bool)
-        act_p[:n] = active
-        with trace.span("device-converge"):
-            t0 = time.perf_counter()
-            est_j, r, stop, final_act, mb, cb, rb = prog(
-                stage(est_p.reshape(n_dev, V)),
-                stage(sg.src),
-                stage(sg.dst),
-                stage(sg.arc_mask),
-                stage(sg.deg),
-                stage(act_p.reshape(n_dev, V)),
-            )
-            est_j = jax.block_until_ready(est_j)
-            t_dev = time.perf_counter() - t0
-        with trace.span("stats-reconstruct"):
-            return _finish(
-                span,
-                (r, stop, final_act, mb, cb, rb),
-                r,
-                t_dev,
-                compiles0,
-                csecs0,
-                lambda: compat.fetch_replicated(est_j, mesh)
-                .reshape(-1)[:n].astype(np.int32),
-                frontier1=frontier1,
-                seed=seed_np,
-                devices=_shard_devices(est_j),
-            )
+        with trace.layer("device-converge") as dev:
+            with trace.layer("stage", h2d_bytes=0) as st:
+                est_p = np.zeros(sg.n_pad, np.int32)
+                est_p[:n] = seed
+                act_p = np.zeros(sg.n_pad, bool)
+                act_p[:n] = active
+                args = (
+                    stage(st, est_p.reshape(n_dev, V)),
+                    stage(st, sg.src),
+                    stage(st, sg.dst),
+                    stage(st, sg.arc_mask),
+                    stage(st, sg.deg),
+                    stage(st, act_p.reshape(n_dev, V)),
+                )
+            out = _device_loop(prog, *args)
+        return _finish(
+            span,
+            out,
+            dev,
+            compiles0,
+            csecs0,
+            lambda: compat.fetch_replicated(out[0], mesh)
+            .reshape(-1)[:n].astype(np.int32),
+            frontier1=frontier1,
+            seed=seed_np,
+        )

@@ -57,4 +57,5 @@ def hindex_rows_pallas(nbr_est, est_u2d, *, n_iters: int, row_tile: int, interpr
         out_specs=pl.BlockSpec((row_tile, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, 1), jnp.int32),
         interpret=interpret,
+        name="kcore_hindex",
     )(nbr_est, est_u2d)

@@ -93,4 +93,5 @@ def segment_sum_pallas(vals, rows_local, block_row, n_blocks_out: int, *, R: int
         ),
         out_shape=jax.ShapeDtypeStruct((n_blocks_out, R // LANES, LANES), jnp.float32),
         interpret=interpret,
+        name="segment_sum",
     )(block_row, vals, rows_local)

@@ -5,7 +5,9 @@ Dependency-free (stdlib-only) measurement substrate for the whole repo:
   * ``trace``    — nested-span tracer (context-manager API, monotonic
     clocks, thread-safe, per-span attributes, true no-op when disabled)
     with Chrome ``trace_event`` JSON export loadable in Perfetto /
-    ``chrome://tracing``;
+    ``chrome://tracing``, plus always-timed layer spans at the layer
+    boundaries, kept in a bounded ring and mirrored into the
+    ``jax.profiler`` trace;
   * ``metrics``  — process-wide registry of counters, gauges, and
     reservoir histograms (p50/p95/p99), exportable as JSON and the
     Prometheus text format;
@@ -25,9 +27,10 @@ Dependency-free (stdlib-only) measurement substrate for the whole repo:
 
 The hot paths are instrumented permanently (host round loop, fused
 runtime, streaming batch phases, window advances, the serving loop, XLA
-compile durations via repro.core.jit_telemetry); tracing costs nothing
-until ``trace.enable()`` — surfaced as ``--trace out.json`` /
-``--metrics`` on ``repro.launch.kcore_run`` and ``kcore_serve``.
+compile durations via repro.core.jit_telemetry); detail spans cost
+nothing until ``trace.enable()``, layer spans a few microseconds each —
+surfaced as ``--trace out.json`` / ``--metrics`` on
+``repro.launch.kcore_run`` and ``kcore_serve``.
 """
 
 from repro.obs import flight, health, http, metrics, trace
@@ -36,7 +39,7 @@ from repro.obs.health import InvariantMonitor, get_monitor
 from repro.obs.http import ObsHTTPServer, start_server
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                get_registry)
-from repro.obs.trace import Span, Tracer, get_tracer
+from repro.obs.trace import LayerSpan, Span, Tracer, get_tracer
 from repro.obs.validate import (TraceValidationError, span_tree_coverage,
                                 validate_chrome_trace)
 
@@ -48,6 +51,7 @@ __all__ = [
     "http",
     "Tracer",
     "Span",
+    "LayerSpan",
     "get_tracer",
     "MetricsRegistry",
     "Counter",

@@ -207,12 +207,6 @@ class FlightRecorder:
                           "batch": self._run["batch"], "n": int(n)})
             return run_id
 
-    def annotate_run(self, **kw) -> None:
-        """Update open-run fields (e.g. dispatch resolved after start)."""
-        with self._lock:
-            if self._run is not None:
-                self._run.update(kw)
-
     def record_round(self, frontier: int, messages: int, changed: int, *,
                      round: int | None = None, est=None, prev_est=None,
                      host_s: float = 0.0, device_s: float = 0.0,

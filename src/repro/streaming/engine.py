@@ -139,9 +139,8 @@ class BatchResult:
     region_size: int          # |R| — insertion region that was re-seeded up
     seed_changed: int         # vertices that had to rebroadcast at seed time
     mode: str = "dense"       # execution mode this batch actually ran in
-    # per-phase walls, always measured (two perf_counter reads per phase —
-    # nanoseconds against phases that run for milliseconds); the same
-    # boundaries the trace spans mark, so a benchmark row gets the
+    # per-phase walls: the durations of the batch's always-timed layer
+    # spans (repro.obs.trace.layer), so a benchmark row gets the
     # patch/seed/converge/reconstruct breakdown without tracing enabled
     patch_s: float = 0.0      # host seconds spent patching the CSR in place
     seed_s: float = 0.0       # warm-start seed + initial frontier
@@ -545,18 +544,19 @@ class StreamingKCoreEngine:
         pow2 boundary would mint a fresh signature each time; the high-
         water mark (pre-seeded by ``min_arc_capacity``) only grows."""
         if self._slots_cache is None:
-            src_live, dst_live = self._live_arrays()
-            k = src_live.size
-            self._arc_pad_hwm = max(self._arc_pad_hwm,
-                                    _next_pow2(max(k, 1)))
-            arc_pad = self._arc_pad_hwm
-            src_np = np.zeros(arc_pad, np.int32)
-            src_np[:k] = src_live
-            dst_np = np.zeros(arc_pad, np.int32)
-            dst_np[:k] = dst_live
-            mask = np.zeros(arc_pad, bool)
-            mask[:k] = True
-            self._slots_cache = (src_np, dst_np, mask)
+            with _trace.layer("stage", h2d_bytes=0):
+                src_live, dst_live = self._live_arrays()
+                k = src_live.size
+                self._arc_pad_hwm = max(self._arc_pad_hwm,
+                                        _next_pow2(max(k, 1)))
+                arc_pad = self._arc_pad_hwm
+                src_np = np.zeros(arc_pad, np.int32)
+                src_np[:k] = src_live
+                dst_np = np.zeros(arc_pad, np.int32)
+                dst_np[:k] = dst_live
+                mask = np.zeros(arc_pad, bool)
+                mask[:k] = True
+                self._slots_cache = (src_np, dst_np, mask)
         return self._slots_cache
 
     # ------------------------------------------------------------------ #
@@ -774,16 +774,18 @@ class StreamingKCoreEngine:
     def apply_batch(self, batch: EdgeBatch) -> BatchResult:
         """Apply one churn batch and re-converge to exact cores.
 
-        When tracing is enabled (repro.obs.trace) each batch emits a
-        ``batch`` span with ``csr-patch`` / ``seed`` / ``converge`` /
-        ``host-reconstruct`` children (the fused modes nest the runtime's
-        ``fused-converge`` -> ``device-converge`` / ``stats-reconstruct``
-        tree under ``converge``, and fresh XLA compiles land as
-        ``xla.compile`` events wherever they happened). The same phase
-        boundaries are always measured into ``BatchResult.patch_s`` /
-        ``seed_s`` / ``converge_s`` / ``reconstruct_s``.
+        Each batch is a ``batch`` layer span (repro.obs.trace.layer) with
+        ``csr-patch`` / ``seed`` / ``converge`` / ``host-reconstruct``
+        children (the fused modes nest the runtime's ``fused-converge`` ->
+        ``device-converge`` / ``stats-reconstruct`` tree under
+        ``converge``, and padding the slot arrays is a ``stage`` span
+        wherever it first happens). Their durations are
+        ``BatchResult.patch_s`` / ``seed_s`` / ``converge_s`` /
+        ``reconstruct_s``. With tracing enabled they are exported too, and
+        fresh XLA compiles land as ``xla.compile`` events wherever they
+        happened.
         """
-        with _trace.span("batch", batch_id=self.batches_applied) as bsp:
+        with _trace.layer("batch", batch_id=self.batches_applied) as bsp:
             res = self._apply_batch_body(batch)
             bsp.set(mode=res.mode, rounds=res.rounds,
                     messages=res.stats.total_messages,
@@ -796,10 +798,8 @@ class StreamingKCoreEngine:
 
     def _apply_batch_body(self, batch: EdgeBatch) -> BatchResult:
         compiles0, csecs0 = compile_count(), compile_seconds()
-        t0 = time.perf_counter()
-        with _trace.span("csr-patch"):
+        with _trace.layer("csr-patch") as patch:
             delta = self._csr.apply_batch(batch)
-        patch_s = time.perf_counter() - t0
         self._graph_cache = None
         self._slots_cache = None
         self._live_cache = None
@@ -807,8 +807,7 @@ class StreamingKCoreEngine:
         n = csr.n
         deg64 = csr.deg.astype(np.int64)
 
-        t_seed = time.perf_counter()
-        with _trace.span("seed") as ssp:
+        with _trace.layer("seed") as ssp:
             old_core_ext = np.zeros(n, np.int64)
             old_core_ext[: self.core.shape[0]] = self.core
             seed_choice = choose_seed(delta.inserted, csr.deg, old_core_ext,
@@ -845,7 +844,6 @@ class StreamingKCoreEngine:
             ssp.set(strategy=seed_choice.strategy,
                     region=int(region.sum()),
                     frontier=int(active.sum()))
-        seed_s = time.perf_counter() - t_seed
         # active_per_round follows the static engine's convention:
         # [r] = vertices recomputing/broadcasting in round r. Round 0 is the
         # seed rebroadcast; round 1's recomputers are the initial frontier.
@@ -871,8 +869,7 @@ class StreamingKCoreEngine:
         n_iters = _round_up(_bs_iters(int(csr.deg.max()) if n else 0), 4)
         n_iters = self._n_iters_hwm = max(n_iters, self._n_iters_hwm)
 
-        t_conv = time.perf_counter()
-        with _trace.span("converge", mode=mode):
+        with _trace.layer("converge", mode=mode) as conv:
             if mode in ("fused", "fused_sharded"):
                 if active.any():
                     outcome = self._run_fused(seed, active, n, n_iters, cap,
@@ -909,10 +906,8 @@ class StreamingKCoreEngine:
                 if not active.any():
                     converged = True
                 core = np.asarray(est, np.int32)
-        converge_s = time.perf_counter() - t_conv
 
-        t_rec = time.perf_counter()
-        with _trace.span("host-reconstruct"):
+        with _trace.layer("host-reconstruct") as rec_span:
             stats = MessageStats(
                 messages_per_round=np.asarray(msgs, np.int64),
                 active_per_round=np.asarray(actives[: len(msgs)], np.int64),
@@ -925,18 +920,18 @@ class StreamingKCoreEngine:
             if rec.active:
                 rec.end_run(converged=converged,
                             messages=int(stats.total_messages))
-            reconstruct_s = time.perf_counter() - t_rec
-            return BatchResult(core=core, rounds=rounds, converged=converged,
-                               stats=stats, delta=delta,
-                               region_size=int(region.sum()),
-                               seed_changed=int(seed_changed.sum()),
-                               mode=mode, patch_s=patch_s,
-                               seed_s=seed_s, converge_s=converge_s,
-                               reconstruct_s=reconstruct_s,
-                               seed_strategy=seed_choice.strategy,
-                               seed_est_passes=seed_choice.est_passes,
-                               recompiles=compile_count() - compiles0,
-                               compile_s=compile_seconds() - csecs0,
-                               csr_compactions=int(csr.compactions),
-                               csr_dead_frac=csr.dead / cap_slots,
-                               csr_occupancy=2 * csr.m / cap_slots)
+            res = BatchResult(core=core, rounds=rounds, converged=converged,
+                              stats=stats, delta=delta,
+                              region_size=int(region.sum()),
+                              seed_changed=int(seed_changed.sum()),
+                              mode=mode, patch_s=patch.seconds,
+                              seed_s=ssp.seconds, converge_s=conv.seconds,
+                              seed_strategy=seed_choice.strategy,
+                              seed_est_passes=seed_choice.est_passes,
+                              recompiles=compile_count() - compiles0,
+                              compile_s=compile_seconds() - csecs0,
+                              csr_compactions=int(csr.compactions),
+                              csr_dead_frac=csr.dead / cap_slots,
+                              csr_occupancy=2 * csr.m / cap_slots)
+        res.reconstruct_s = rec_span.seconds
+        return res

@@ -26,7 +26,10 @@ seconds per op under ``"latency"``, raw-float cumulative walls (callers
 format; rounding here would destroy microsecond query walls), and the
 registry itself is exposed as ``server.metrics`` for JSON/Prometheus
 export. When span tracing is live each serve/update/advance also emits a
-``serve.request`` / ``server.update`` / ``window.advance`` span.
+``serve.request`` / ``server.update`` / ``window.advance`` span. Under
+``server.update``, the engine's ``batch`` layer span and its phases are
+timed whether tracing is live or not (repro.obs.trace.layer): the
+``BatchResult`` phase walls ``update`` returns are their durations.
 
 A server can be constructed over a static Graph (churn arrives as explicit
 ``update`` batches) or over a ``WindowedKCoreEngine`` (temporal mode:
