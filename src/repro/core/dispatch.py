@@ -40,7 +40,11 @@ The graph enters every program as jit ARGUMENTS (``GraphOperands``: arcs,
 the blocked segment-sum layout, the ELL tables), never as closed-over
 constants, so jax compiles one program per operand SHAPE: a graph of 10^8
 arcs does not become an HLO literal, and a stream whose slot contents churn
-at a stable padded size reuses one compiled program.
+at a stable padded size reuses one compiled program. With the blocked
+layout the arc operands are staged in its slot order, and the arc mask is
+permuted into it once per call: every mask the round counts is then built
+from vertex-sized vectors where the kernel reads it, with no arc-sized
+gather per count.
 """
 
 from __future__ import annotations
@@ -57,7 +61,7 @@ from jax import lax
 from repro import platform as _platform
 from repro.graph.structs import EllGraph
 from repro.kernels.kcore_hindex.ops import hindex_rows
-from repro.kernels.segment_sum.ops import blocked_layout, segment_sum_arrays
+from repro.kernels.segment_sum.ops import blocked_layout, segment_sum_arrays, slot_rows, to_slots
 from repro.obs import trace
 
 
@@ -95,10 +99,17 @@ def resolve_plan(mode: str | None = None) -> DispatchPlan:
 
 
 class GraphOperands(NamedTuple):
-    """Device arrays a dispatched program reads (a pytree of jit args)."""
+    """Device arrays a dispatched program reads (a pytree of jit args).
 
-    src: jax.Array  # (A,) int32 arc sources
-    dst: jax.Array  # (A,) int32 arc destinations
+    On the XLA plan ``src``/``dst`` are the arcs in arc order. With a
+    blocked layout (``seg``, the Pallas plan) they are in the layout's
+    padded slot order, so every mask the round counts is built in the order
+    the segment-sum kernel reads it: the slot's own row, and the arc's
+    destination, which is the sentinel vertex ``n`` on padding slots.
+    """
+
+    src: jax.Array  # (A,) int32 arc sources; (E_pad,) slot rows with a layout
+    dst: jax.Array  # (A,) int32 arc destinations; (E_pad,) with n on padding
     seg: tuple  # (slot_edge, rows_local, block_row) blocked layout; () on xla
     ell: tuple  # ((ids, nbrs), ...) per ELL bucket; () for the bsearch h-index
 
@@ -135,16 +146,17 @@ def _stage(plan: DispatchPlan, src, dst, n: int, ell: EllGraph | None):
     """Host graph arrays -> (GraphOperands, static layout args), inside one
     ``stage`` layer span."""
     with trace.layer("stage", h2d_bytes=0) as st:
-        src_np = np.asarray(src, np.int32)
+        src_np, dst_np = np.asarray(src, np.int32), np.asarray(dst, np.int32)
         seg, R, n_rows_pad = (), 0, 0
         if plan.kind == "pallas":
             layout = blocked_layout(src_np, n)
             seg = tuple(to_device(st, a) for a in (layout.slot_edge, layout.rows_local, layout.block_row))
             R, n_rows_pad = layout.R, layout.n_rows_pad
+            src_np, dst_np = slot_rows(layout), to_slots(dst_np, layout.slot_edge, n)
         buckets = ()
         if ell is not None and plan.kind == "pallas":
             buckets = tuple((to_device(st, b.ids), to_device(st, b.nbrs)) for b in ell.buckets)
-        ops = GraphOperands(to_device(st, src_np), to_device(st, dst, jnp.int32), seg, buckets)
+        ops = GraphOperands(to_device(st, src_np), to_device(st, dst_np), seg, buckets)
     return ops, (("R", R), ("n_rows_pad", n_rows_pad))
 
 
@@ -153,32 +165,47 @@ def _stage(plan: DispatchPlan, src, dst, n: int, ell: EllGraph | None):
 # ---------------------------------------------------------------------- #
 
 
-def _round(ops: GraphOperands, est, arc_mask, active, *, n, n_iters, R, n_rows_pad):
+def _operand_mask(ops: GraphOperands, arc_mask):
+    """``arc_mask`` in the order of ``ops.src``/``ops.dst``: as given on the
+    XLA plan; permuted into the blocked layout's slot order (False on
+    padding slots) with a layout. A program does this once per call,
+    outside its round loop."""
+    if not ops.seg:
+        return arc_mask
+    with jax.named_scope("kcore.mask"):
+        return to_slots(arc_mask, ops.seg[0], False)
+
+
+def _round(ops: GraphOperands, est, mask, active, *, n, n_iters, R, n_rows_pad):
     """Traceable masked superstep with dispatched reductions.
 
-    Same math as ``core.kcore._masked_round``. With ELL tables (static
+    Same math as ``core.kcore._masked_round``; ``mask`` is the arc mask in
+    the operands' order (``_operand_mask``). With ELL tables (static
     fully-live adjacency only — the from-scratch decomposition) the h-index
     runs through the Pallas ``hindex_rows`` kernel per degree bucket;
     otherwise it is the binary search with the hit counts routed through
-    the dispatched segment sum.
+    the dispatched segment sum. Every counted mask is built in the
+    operands' order from vertex-sized vectors, so with a blocked layout it
+    reaches the kernel with no permutation.
     """
     src, dst = ops.src, ops.dst
 
     if ops.seg:
 
-        def count(mask):
-            return segment_sum_arrays(mask, *ops.seg, R=R, n_rows_pad=n_rows_pad, n_rows=n)[:, 0]
+        def count(m):
+            return segment_sum_arrays(m, *ops.seg, R=R, n_rows_pad=n_rows_pad, n_rows=n)[:, 0]
 
     else:
 
-        def count(mask):
-            return jax.ops.segment_sum(mask.astype(jnp.int32), src, num_segments=n)
+        def count(m):
+            return jax.ops.segment_sum(m.astype(jnp.int32), src, num_segments=n)
 
+    # est_ext[n] = 0: the padding slots' sentinel destination, and the ELL
+    # tables' padded neighbor slots, never count for k >= 1
+    est_ext = jnp.concatenate([est, jnp.zeros(1, jnp.int32)])
     if ops.ell:
-        # est_ext[n] = 0: padded neighbor slots never count for k >= 1.
         # Requires est == 0 on degree-0 vertices (true from the degree
         # seed: they are in no bucket, so their estimate passes through)
-        est_ext = jnp.concatenate([est, jnp.zeros(1, jnp.int32)])
         new_ext = est_ext
         for ids, nbrs in ops.ell:
             with jax.named_scope("kcore.gather"):
@@ -189,7 +216,7 @@ def _round(ops: GraphOperands, est, arc_mask, active, *, n, n_iters, R, n_rows_p
         h = new_ext[:n]
     else:
         with jax.named_scope("kcore.gather"):
-            est_dst = jnp.where(arc_mask, est[dst], 0)
+            est_dst = jnp.where(mask, est_ext[dst], 0)
 
         def body(lohi, _):
             lo, hi = lohi
@@ -206,13 +233,15 @@ def _round(ops: GraphOperands, est, arc_mask, active, *, n, n_iters, R, n_rows_p
         new_est = jnp.where(active, h, est)
         changed = new_est < est
     with jax.named_scope("kcore.recv"):
-        recv = count(jnp.where(arc_mask, changed[dst], False)) > 0
+        changed_ext = jnp.concatenate([changed, jnp.zeros(1, jnp.bool_)])
+        recv = count(changed_ext[dst] & mask) > 0
     return new_est, changed, recv
 
 
 @functools.partial(jax.jit, static_argnames=("n", "n_iters", "R", "n_rows_pad"))
 def _masked_round_jit(ops, est, arc_mask, active, *, n, n_iters, R, n_rows_pad):
-    return _round(ops, est, arc_mask, active, n=n, n_iters=n_iters, R=R, n_rows_pad=n_rows_pad)
+    mask = _operand_mask(ops, arc_mask)
+    return _round(ops, est, mask, active, n=n, n_iters=n_iters, R=R, n_rows_pad=n_rows_pad)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "n_iters", "max_rounds", "R", "n_rows_pad"))
@@ -224,7 +253,7 @@ def _fused_jit(ops, est, arc_mask, active, deg, *, n, n_iters, max_rounds, R, n_
     def body(carry):
         est, act, r, _stop, mb, cb, rb = carry
         new_est, changed, recv = _round(
-            ops, est, arc_mask, act, n=n, n_iters=n_iters, R=R, n_rows_pad=n_rows_pad
+            ops, est, mask, act, n=n, n_iters=n_iters, R=R, n_rows_pad=n_rows_pad
         )
         with jax.named_scope("kcore.stats"):
             any_ch = changed.any()
@@ -233,6 +262,7 @@ def _fused_jit(ops, est, arc_mask, active, deg, *, n, n_iters, max_rounds, R, n_
             rb = rb.at[r].set(jnp.sum(recv, dtype=jnp.int32))
         return new_est, recv, r + 1, ~any_ch, mb, cb, rb
 
+    mask = _operand_mask(ops, arc_mask)
     zeros = jnp.zeros(max_rounds, jnp.int32)
     carry = (est, active, jnp.int32(0), jnp.bool_(False), zeros, zeros, zeros)
     est, act, r, stop, mb, cb, rb = lax.while_loop(cond, body, carry)

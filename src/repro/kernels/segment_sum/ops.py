@@ -97,14 +97,31 @@ def blocked_layout(
     )
 
 
+def slot_rows(layout: BlockedLayout) -> np.ndarray:
+    """(E_pad,) int32 segment of every slot in the layout's padded slot
+    order; a padding slot reads its block's first row, which is < n_rows."""
+    return (layout.block_row[:, None, None] * layout.R + layout.rows_local).reshape(-1)
+
+
+def to_slots(x, slot_edge, fill):
+    """Arc-order ``x`` ((E,) or (E, F)) in the layout's padded slot order:
+    (E_pad,) or (E_pad, F), ``fill`` on padding slots (slot_edge == E).
+    A numpy ``x`` stays on the host; a jax one is one gather."""
+    xp = jnp if isinstance(x, jax.Array) else np
+    pad = xp.full((1,) + x.shape[1:], fill, x.dtype)
+    return xp.concatenate([x, pad])[slot_edge.reshape(-1)]
+
+
 def segment_sum_arrays(
     vals, slot_edge, rows_local, block_row, *, R: int, n_rows_pad: int, n_rows: int
 ):
     """Traceable blocked segment sum with the layout passed as arrays.
 
-    vals: (E,) or (E, F) in ORIGINAL edge order, bool (counted to int32)
-    or floating (summed in f32, returned in ``vals.dtype``). Returns
-    (n_rows, F); each feature column is one kernel call.
+    vals: (E_pad,) or (E_pad, F) in the layout's padded slot order
+    (``to_slots``; zero on padding slots), bool (counted to int32) or
+    floating (summed in f32, returned in ``vals.dtype``). It reaches the
+    kernel with no gather. Returns (n_rows, F); each feature column is one
+    kernel call.
     """
     counting = vals.dtype == jnp.bool_
     if not counting and not jnp.issubdtype(vals.dtype, jnp.floating):
@@ -114,8 +131,7 @@ def segment_sum_arrays(
     dt = jnp.bfloat16 if counting else jnp.float32
     cols = []
     for f in range(vals.shape[1]):
-        # slot_edge == E on padding slots: read the appended zero
-        x = jnp.concatenate([vals[:, f].astype(dt), jnp.zeros(1, dt)])[slot_edge]
+        x = vals[:, f].astype(dt).reshape(slot_edge.shape)
         out = segment_sum_pallas(
             x, rows_local, block_row, n_rows_pad // R, R=R, interpret=_platform.interpret_kernels()
         )
@@ -127,7 +143,13 @@ def segment_sum_arrays(
 @functools.partial(jax.jit, static_argnames=("R", "n_rows_pad", "n_rows"))
 def _run(vals, slot_edge, rows_local, block_row, R, n_rows_pad, n_rows):
     return segment_sum_arrays(
-        vals, slot_edge, rows_local, block_row, R=R, n_rows_pad=n_rows_pad, n_rows=n_rows
+        to_slots(vals, slot_edge, 0),
+        slot_edge,
+        rows_local,
+        block_row,
+        R=R,
+        n_rows_pad=n_rows_pad,
+        n_rows=n_rows,
     )
 
 

@@ -99,6 +99,9 @@ _FAMILIES = [
     ("ba", lambda: gen.barabasi_albert(300, 3, seed=1)),
     ("er", lambda: gen.erdos_renyi(250, 700, seed=3)),
     ("star+isolated", lambda: gen.star(40)),
+    # three 1024-row blocks, the first spilling into a second, padded
+    # 2048-slot edge block: padding slots inside and between row blocks
+    ("er-3-row-blocks", lambda: gen.erdos_renyi(2100, 3000, seed=6)),
 ]
 
 
@@ -116,17 +119,25 @@ def _assert_bit_equal(rx, rp):
 def test_decompose_parity_pallas_vs_xla(name, make, fused):
     """kcore_decompose: forced Pallas dispatch (ELL h-index + blocked
     segment sum, interpret mode on CPU) is bit-equal to the XLA path and
-    the BZ oracle, in both the host round loop and the fused while_loop."""
+    the BZ oracle, in both the host round loop and the fused while_loop.
+    The Pallas route counts its masks in the blocked layout's slot order,
+    whose padding slots (E_pad > E) must count nothing."""
+    from repro.kernels.segment_sum.ops import blocked_layout
+
     g = make()
+    assert blocked_layout(g.src, g.n).slot_edge.size > g.num_arcs
     rx = kcore_decompose(g, KCoreConfig(fused=fused, dispatch="xla"))
     rp = kcore_decompose(g, KCoreConfig(fused=fused, dispatch="pallas"))
     _assert_bit_equal(rx, rp)
     assert np.array_equal(rp.core, bz_core_numbers(g))
 
 
-def test_streaming_parity_pallas_vs_xla():
+@pytest.mark.parametrize("frontier", ["dense", "fused"])
+def test_streaming_parity_pallas_vs_xla(frontier):
     """Streaming engine (dense per-round AND fused batch re-convergence):
-    REPRO_PALLAS routing gives the identical bill per churn batch."""
+    REPRO_PALLAS routing gives the identical bill per churn batch. The
+    padded slot arrays hold masked-off slots, which the Pallas route
+    permutes into slot order and must not count."""
     from repro.streaming import (StreamingConfig, StreamingKCoreEngine,
                                  random_churn_batch)
 
@@ -142,14 +153,55 @@ def test_streaming_parity_pallas_vs_xla():
                                                          rng))
                 out.append((res.stats.messages_per_round.tolist(),
                             res.stats.active_per_round.tolist(),
+                            res.stats.changed_per_round.tolist(),
                             eng.core.tolist()))
+                assert not eng._padded_slots()[2].all()
             assert np.array_equal(eng.core, bz_core_numbers(eng.graph))
             return out
         finally:
             platform.set_dispatch_mode(None)
 
-    for frontier in ("dense", "fused"):
-        assert run("xla", frontier) == run("pallas", frontier), frontier
+    assert run("xla", frontier) == run("pallas", frontier)
+
+
+@pytest.mark.parametrize("dtype", [np.bool_, np.float32])
+@pytest.mark.parametrize("F", [1, 3])
+def test_segment_sum_slot_order_matches_arc_order(dtype, F):
+    """``segment_sum_arrays`` over values in the layout's slot order (zero
+    on padding slots) equals ``segment_sum_blocked`` over the same values
+    in arc order on the same layout: bit-equal counts for bool indicators,
+    the same f32 sums for floats. ``to_slots`` makes that order, on the
+    host and on the device alike."""
+    import jax.numpy as jnp
+
+    from repro.kernels.segment_sum.ops import (blocked_layout, segment_sum_arrays,
+                                               segment_sum_blocked, slot_rows, to_slots)
+
+    r = np.random.default_rng(11)
+    n, E = 300, 1500
+    seg = r.integers(0, n, E)             # unsorted: the layout sorts it
+    vals = (r.integers(0, 2, (E, F)).astype(bool) if dtype is np.bool_
+            else r.normal(size=(E, F)).astype(np.float32))
+    lo = blocked_layout(seg, n, R=128, be=256)
+    slot_edge = lo.slot_edge.reshape(-1)
+    assert slot_edge.size > E
+    vals_slot = np.concatenate([vals, np.zeros((1, F), vals.dtype)])[slot_edge]
+    np.testing.assert_array_equal(to_slots(vals, lo.slot_edge, 0), vals_slot)
+    np.testing.assert_array_equal(
+        np.asarray(to_slots(jnp.asarray(vals), jnp.asarray(lo.slot_edge), 0)), vals_slot)
+    real = slot_edge < E
+    np.testing.assert_array_equal(slot_rows(lo)[real], seg[slot_edge[real]])
+    assert (slot_rows(lo) < n).all()
+    layout = (jnp.asarray(lo.slot_edge), jnp.asarray(lo.rows_local), jnp.asarray(lo.block_row))
+    slot = segment_sum_arrays(jnp.asarray(vals_slot), *layout,
+                              R=lo.R, n_rows_pad=lo.n_rows_pad, n_rows=n)
+    arc = segment_sum_blocked(jnp.asarray(vals), lo, n)
+    assert slot.dtype == arc.dtype and slot.shape == (n, F)
+    np.testing.assert_array_equal(np.asarray(slot), np.asarray(arc))
+    if dtype is np.bool_:
+        ref = np.zeros((n, F), np.int64)
+        np.add.at(ref, seg, vals.astype(np.int64))
+        np.testing.assert_array_equal(np.asarray(slot), ref)
 
 
 def test_fused_outcome_records_dispatch():
@@ -161,3 +213,31 @@ def test_fused_outcome_records_dispatch():
         np.ones(g.num_arcs, bool), g.deg,
         n=g.n, n_iters=8, max_rounds=g.n + 1, dispatch="pallas")
     assert out.dispatch == "pallas" and out.converged
+
+
+def test_kernel_shapes_size_the_slot_order_segment_sum():
+    """The benchmark sizes ``segsum_roofline`` from the operands the round
+    hands ``segment_sum_arrays`` (``bench.work.KernelShapes``): traced
+    Pallas-plan programs record one segment-sum shape per layout, a flat
+    bool mask of the layout's E_pad slots into n segments."""
+    import jax.numpy as jnp
+
+    from bench.work import KernelShapes
+    from repro.core.kcore import _bs_iters
+
+    plan = dmod.resolve_plan("pallas")
+    g = gen.erdos_renyi(333, 901, seed=9)   # shapes no other test traces
+    n, it = g.n, _bs_iters(g.max_deg)
+    fused = dmod.fused_convergence_program(n, it, n + 1, plan, g.src, g.dst)
+    host = dmod.masked_round_program(n, it, plan, g.src, g.dst)
+    est, amask, act = jnp.asarray(g.deg), jnp.ones(g.num_arcs, bool), jnp.ones(n, bool)
+    shapes = KernelShapes()
+    uninstall = shapes.install()
+    try:
+        fused.lower(est, amask, act, est)
+        host.lower(est, amask, act)
+    finally:
+        uninstall()
+    slot_edge = fused.operands.seg[0]
+    assert slot_edge.size > g.num_arcs
+    assert shapes.segsum == {slot_edge.shape[0]: {(slot_edge.size, 1, n)}}

@@ -8,7 +8,9 @@ or in a skip condition — and every compile runs in the test's own process
 with the persistent compilation cache off.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -98,8 +100,8 @@ def test_pallas_fused_convergence_compiles(one_chip, for_tpu):
     e_pad = arcs + n  # one half-filled 2048-edge block per 1024-row block
     S = lambda shape, dtype=jnp.int32: _spec(one_chip, shape, dtype)  # noqa: E731
     ops = dispatch.GraphOperands(
-        S((arcs,)),
-        S((arcs,)),
+        S((e_pad,)),
+        S((e_pad,)),
         (S((e_pad // 2048, 16, 128)), S((e_pad // 2048, 16, 128)), S((e_pad // 2048,))),
         tuple((S((rows,)), S((rows, w))) for w, rows in buckets),
     )
@@ -131,8 +133,8 @@ def test_kernels_and_round_scopes_keep_their_names_on_tpu(one_chip, for_tpu):
     e_pad = arcs + n
     S = lambda shape, dtype=jnp.int32: _spec(one_chip, shape, dtype)  # noqa: E731
     ops = dispatch.GraphOperands(
-        S((arcs,)),
-        S((arcs,)),
+        S((e_pad,)),
+        S((e_pad,)),
         (S((e_pad // 2048, 16, 128)), S((e_pad // 2048, 16, 128)), S((e_pad // 2048,))),
         tuple((S((rows,)), S((rows, w))) for w, rows in ((8, 2000), (128, 100))),
     )
@@ -146,3 +148,49 @@ def test_kernels_and_round_scopes_keep_their_names_on_tpu(one_chip, for_tpu):
     assert {c.split(".")[0] for c in calls} == {"%kcore_hindex", "%segment_sum"}
     for scope in ("kcore.gather", "kcore.hindex", "kcore.changed", "kcore.recv", "kcore.stats"):
         assert f"/{scope}/" in text, scope
+
+
+def _gathers(text):
+    """(op_name, element count of the gathered operand) of every gather in
+    compiled HLO text."""
+    sizes = {}
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]", ln)
+        if m:
+            sizes[m.group(1)] = math.prod(int(d) for d in m.group(2).split(",") if d)
+    out = []
+    for ln in text.splitlines():
+        m = re.search(r"= \S+ gather\((%[\w.\-]+),", ln)
+        if m:
+            out.append((re.search(r'op_name="([^"]*)"', ln).group(1), sizes[m.group(1)]))
+    return out
+
+
+def test_round_counts_read_no_arc_sized_gather_on_tpu(one_chip, for_tpu):
+    """At the churn cell's shapes (16,384 vertices, 2^19 arc slots, 540,672
+    padded layout slots, 16 bsearch steps) the masks the fused round counts
+    are built in the blocked layout's slot order: inside the while body no
+    gather under ``kcore.hindex`` or ``kcore.recv`` reads an arc-sized
+    operand, only vertex-sized ones. The arc mask is permuted once, outside
+    the loop, and the two segment-sum calls (bsearch count, receivers)
+    stay."""
+    n, arcs, e_pad = 1 << 14, 1 << 19, 540672
+    S = lambda shape, dtype=jnp.int32: _spec(one_chip, shape, dtype)  # noqa: E731
+    blocks = e_pad // 2048
+    ops = dispatch.GraphOperands(
+        S((e_pad,)), S((e_pad,)), (S((blocks, 16, 128)), S((blocks, 16, 128)), S((blocks,))), ()
+    )
+    text = dispatch._fused_jit.lower(
+        ops, S((n,)), S((arcs,), jnp.bool_), S((n,), jnp.bool_), S((n,)),
+        n=n, n_iters=16, max_rounds=n + 1, R=1024, n_rows_pad=n,
+    ).compile().as_text()
+    gathers = _gathers(text)
+    in_loop = [(name, size) for name, size in gathers if "/while/body/" in name]
+    for scope in ("kcore.hindex", "kcore.recv"):
+        assert any(f"/{scope}/" in name for name, _ in in_loop), scope
+    # n + 1: the vertex vectors with the padding slots' sentinel entry
+    assert all(size <= n + 1 for _, size in in_loop), in_loop
+    # the one arc-sized gather: the mask's permutation, before the loop
+    assert [size for _, size in gathers if size > n + 1] == [arcs + 1]
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " = " in ln]
+    assert len(calls) == 2 and all(c.strip().startswith("%segment_sum.") for c in calls)
