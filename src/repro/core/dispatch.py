@@ -145,6 +145,9 @@ def to_device(stage: trace.LayerSpan, x, dtype=None) -> jax.Array:
 def _stage(plan: DispatchPlan, src, dst, n: int, ell: EllGraph | None):
     """Host graph arrays -> (GraphOperands, static layout args), inside one
     ``stage`` layer span."""
+    # staging makes arc-sized temporaries on every call: keep them in the
+    # heap, or some calls fault every page in afresh
+    _platform.pin_host_heap()
     with trace.layer("stage", h2d_bytes=0) as st:
         src_np, dst_np = np.asarray(src, np.int32), np.asarray(dst, np.int32)
         seg, R, n_rows_pad = (), 0, 0

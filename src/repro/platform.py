@@ -31,9 +31,12 @@ entry points cover a laptop CPU, a forced-multi-device CI lane, and a TPU:
   a device without a row raises.
 * ``enable_compile_cache()`` — JAX's persistent compilation cache at one
   fixed directory, or wherever ``JAX_COMPILATION_CACHE_DIR`` says.
+* ``pin_host_heap()`` — fix glibc malloc's mmap and trim thresholds, so
+  the arc-sized temporaries of host staging are reused from the heap
+  instead of being mapped and faulted in afresh on some calls.
 
-Everything here touches only ``os.environ`` and ``jax.config`` until a
-function documents otherwise — importing this module never initializes a
+Everything here touches only ``os.environ`` and ``jax.config`` (and, in
+``pin_host_heap``, the C allocator) until a function documents otherwise — importing this module never initializes a
 jax backend, so it is always safe to import first and configure before the
 rest of the process touches a device.
 """
@@ -346,3 +349,47 @@ def enable_compile_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_enable_compilation_cache", True)
     return path
+
+
+# ---------------------------------------------------------------------- #
+# Host heap
+# ---------------------------------------------------------------------- #
+
+# glibc's mallopt parameters and the environment variables that set them
+_M_TRIM_THRESHOLD, _M_MMAP_THRESHOLD = -1, -3
+_MALLOC_ENV = ("MALLOC_MMAP_THRESHOLD_", "MALLOC_TRIM_THRESHOLD_", "GLIBC_TUNABLES")
+HEAP_MMAP_THRESHOLD = 1 << 30
+HEAP_TRIM_THRESHOLD = (1 << 31) - 1  # mallopt takes an int
+_heap_pinned: bool | None = None
+
+
+def pin_host_heap() -> bool:
+    """Serve host allocations below 1 GiB from the heap and keep up to
+    2 GiB of freed heap; returns whether the thresholds are pinned.
+
+    Host staging (``build_ell``, ``blocked_layout``, the slot arrays)
+    allocates and frees arc-sized temporaries on every call. glibc's
+    default moves its mmap threshold with the sizes it has freed, so some
+    calls reuse heap pages and others map fresh ones and fault every page
+    in: one TPU v5e host measured 29-38 ms a call pinned against 34-68 ms
+    unpinned, per process bimodal, for the same graph. Idempotent; leaves
+    the allocator alone where the environment already tunes it or the C
+    library is not glibc.
+    """
+    global _heap_pinned
+    if _heap_pinned is None:
+        _heap_pinned = False
+        if not any(k in os.environ for k in _MALLOC_ENV):
+            import ctypes
+
+            try:
+                mallopt = ctypes.CDLL(None).mallopt
+            except (OSError, AttributeError):
+                return False
+            mallopt.argtypes = (ctypes.c_int, ctypes.c_int)
+            # a threshold set explicitly also turns glibc's dynamic one off
+            _heap_pinned = bool(
+                mallopt(_M_MMAP_THRESHOLD, HEAP_MMAP_THRESHOLD)
+                and mallopt(_M_TRIM_THRESHOLD, HEAP_TRIM_THRESHOLD)
+            )
+    return _heap_pinned

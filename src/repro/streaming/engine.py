@@ -233,43 +233,55 @@ def _ub_converge(U, cap, src, dst, live, ins_u, ins_v, ins_live, n):
     Fusing it into an outer ``lax.while_loop`` makes the whole seed
     computation a single dispatch with no host round-trips; each pass is
     the identical ``_ub_pass_body``, so the resulting U is unchanged
-    (property-tested against the union-find reference)."""
-    def pass_body(state):
-        U, _ = state
-        return _ub_pass_body(U, cap, src, dst, live, ins_u, ins_v,
-                             ins_live, n)
+    (property-tested against the union-find reference).
 
-    U, _ = lax.while_loop(lambda s: s[1], pass_body, (U, jnp.bool_(True)))
-    return U
+    Returns (U, passes): ``passes`` counts every pass run, the last one
+    (which raises nothing) included — as many as the host loop of
+    ``_ub_pass`` calls would make."""
+    def pass_body(state):
+        U, _, passes = state
+        U, raised = _ub_pass_body(U, cap, src, dst, live, ins_u, ins_v,
+                                  ins_live, n)
+        return U, raised, passes + 1
+
+    U, _, passes = lax.while_loop(lambda s: s[1], pass_body,
+                                  (U, jnp.bool_(True), jnp.int32(0)))
+    return U, passes
 
 
 def _insertion_upper_bound_arrays(n: int, src, dst, live, deg,
                                   old_core_ext: np.ndarray,
-                                  inserted: np.ndarray) -> np.ndarray:
+                                  inserted: np.ndarray
+                                  ) -> tuple[np.ndarray, int]:
     """Vectorized insertion upper bound over raw (masked) arc arrays.
 
     ``src``/``dst``/``live`` may be numpy or already-device arrays (the
     engine passes its padded CSR slot arrays); shapes should be stable
     across batches (pow2-padded) so the jitted pass compiles O(log) times.
+    Returns (U, passes), ``passes`` as ``_ub_converge`` counts them (0
+    when nothing was inserted). Runs as an ``upper-bound`` layer span
+    (attribute ``passes``) whose operand copies are a ``stage`` span
+    counting ``h2d_bytes``.
     """
-    U = old_core_ext.astype(np.int64).copy()
-    if inserted.size == 0 or n == 0:
-        return U
-    ins_pad = _next_pow2(max(inserted.shape[0], 1))
-    ins_u = np.zeros(ins_pad, np.int32)
-    ins_v = np.zeros(ins_pad, np.int32)
-    ins_live = np.zeros(ins_pad, bool)
-    ins_u[: inserted.shape[0]] = inserted[:, 0]
-    ins_v[: inserted.shape[0]] = inserted[:, 1]
-    ins_live[: inserted.shape[0]] = True
+    with _trace.layer("upper-bound", passes=0) as ub:
+        U = old_core_ext.astype(np.int64).copy()
+        if inserted.size == 0 or n == 0:
+            return U, 0
+        ins_pad = _next_pow2(max(inserted.shape[0], 1))
+        ins_u = np.zeros(ins_pad, np.int32)
+        ins_v = np.zeros(ins_pad, np.int32)
+        ins_live = np.zeros(ins_pad, bool)
+        ins_u[: inserted.shape[0]] = inserted[:, 0]
+        ins_v[: inserted.shape[0]] = inserted[:, 1]
+        ins_live[: inserted.shape[0]] = True
 
-    U_j = jnp.asarray(U, jnp.int32)
-    cap_j = jnp.asarray(deg, jnp.int32)
-    src_j, dst_j = jnp.asarray(src), jnp.asarray(dst)
-    live_j = jnp.asarray(live)
-    iu, iv, il = jnp.asarray(ins_u), jnp.asarray(ins_v), jnp.asarray(ins_live)
-    U_j = _ub_converge(U_j, cap_j, src_j, dst_j, live_j, iu, iv, il, n)
-    return np.asarray(U_j).astype(np.int64)
+        with _trace.layer("stage", h2d_bytes=0) as st:
+            args = [_dispatch.to_device(st, a, dt) for a, dt in (
+                (U, jnp.int32), (deg, jnp.int32), (src, None), (dst, None),
+                (live, None), (ins_u, None), (ins_v, None), (ins_live, None))]
+        U_np, passes = jax.device_get(_ub_converge(*args, n=n))
+        ub.set(passes=int(passes))
+        return U_np.astype(np.int64), int(passes)
 
 
 def _insertion_upper_bound(new_g: Graph, old_core_ext: np.ndarray,
@@ -320,7 +332,7 @@ def _insertion_upper_bound(new_g: Graph, old_core_ext: np.ndarray,
     """
     return _insertion_upper_bound_arrays(
         new_g.n, new_g.src, new_g.dst, np.ones(new_g.num_arcs, bool),
-        new_g.deg, old_core_ext, inserted)
+        new_g.deg, old_core_ext, inserted)[0]
 
 
 def _insertion_upper_bound_unionfind(new_g: Graph, old_core_ext: np.ndarray,
@@ -776,7 +788,9 @@ class StreamingKCoreEngine:
 
         Each batch is a ``batch`` layer span (repro.obs.trace.layer) with
         ``csr-patch`` / ``seed`` / ``converge`` / ``host-reconstruct``
-        children (the fused modes nest the runtime's ``fused-converge`` ->
+        children (``seed`` nests the tight bound's ``upper-bound`` span,
+        when the batch takes it, and the initial frontier's ``frontier``;
+        the fused modes nest the runtime's ``fused-converge`` ->
         ``device-converge`` / ``stats-reconstruct`` tree under
         ``converge``, and padding the slot arrays is a ``stage`` span
         wherever it first happens). Their durations are
@@ -817,9 +831,9 @@ class StreamingKCoreEngine:
                 U = deg64.copy()
             else:
                 src_p, dst_p, live_p = self._padded_slots()
-                U = _insertion_upper_bound_arrays(n, src_p, dst_p, live_p,
-                                                  csr.deg, old_core_ext,
-                                                  delta.inserted)
+                U, _ = _insertion_upper_bound_arrays(n, src_p, dst_p, live_p,
+                                                     csr.deg, old_core_ext,
+                                                     delta.inserted)
             seed = np.minimum(U, deg64).astype(np.int32)
             region = U > old_core_ext
             old_core32 = old_core_ext.astype(np.int32)
@@ -834,13 +848,14 @@ class StreamingKCoreEngine:
             # ---- initial frontier ------------------------------------- #
             # recompute u iff its h-index inputs changed: an incident edge
             # appeared/disappeared, or a neighbor's broadcast value changed.
-            active = np.zeros(n, bool)
-            touched = delta.touched[delta.touched < n]
-            active[touched] = True
-            active |= seed_changed
-            src_live, dst_live = self._live_arrays()
-            active |= _receivers_arrays(n, src_live, dst_live, None,
-                                        seed_changed)
+            with _trace.layer("frontier"):
+                active = np.zeros(n, bool)
+                touched = delta.touched[delta.touched < n]
+                active[touched] = True
+                active |= seed_changed
+                src_live, dst_live = self._live_arrays()
+                active |= _receivers_arrays(n, src_live, dst_live, None,
+                                            seed_changed)
             ssp.set(strategy=seed_choice.strategy,
                     region=int(region.sum()),
                     frontier=int(active.sum()))

@@ -199,3 +199,57 @@ def test_configure_from_env_noop_without_vars(monkeypatch):
                 platform.ENV_X64):
         monkeypatch.delenv(var, raising=False)
     assert platform.configure_from_env() == {}
+
+
+# ----------------------------- host heap ------------------------------- #
+
+# prints, for a 16 MiB array made before and after pin_host_heap(),
+# whether it lies in the process's brk heap, and what the call returned
+_HEAP_SCRIPT = (
+    "import numpy as np\n"
+    "import repro.platform as p\n"
+    "def in_heap(a):\n"
+    "    x = a.ctypes.data\n"
+    "    for line in open('/proc/self/maps'):\n"
+    "        if line.rstrip().endswith('[heap]'):\n"
+    "            lo, hi = (int(v, 16) for v in line.split()[0].split('-'))\n"
+    "            return lo <= x < hi\n"
+    "    return False\n"
+    "before = in_heap(np.ones(16 << 20, np.uint8))\n"
+    "pinned = p.pin_host_heap(), p.pin_host_heap()\n"
+    "after = in_heap(np.ones(16 << 20, np.uint8))\n"
+    "print(before, pinned, after)\n"
+)
+
+
+def test_pin_host_heap_serves_staging_sizes_from_heap():
+    """Unpinned, a 16 MiB array is mapped afresh; pinned, it comes from
+    the heap, whose freed pages the next call reuses."""
+    proc = subprocess.run([sys.executable, "-c", _HEAP_SCRIPT], capture_output=True,
+                          text=True, env=_ENV, cwd=_REPO, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.split() == ["False", "(True,", "True)", "True"]
+
+
+@pytest.mark.parametrize("var,value", [
+    ("MALLOC_MMAP_THRESHOLD_", "131072"),
+    ("MALLOC_TRIM_THRESHOLD_", "131072"),
+    ("GLIBC_TUNABLES", "glibc.malloc.mmap_threshold=131072"),
+])
+def test_pin_host_heap_leaves_a_tuned_allocator_alone(var, value):
+    proc = subprocess.run([sys.executable, "-c", _HEAP_SCRIPT], capture_output=True,
+                          text=True, env={**_ENV, var: value}, cwd=_REPO, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.split() == ["False", "(False,", "False)", "False"]
+
+
+def test_graph_staging_pins_host_heap(monkeypatch):
+    import numpy as np
+
+    from repro.core import dispatch
+
+    calls = []
+    monkeypatch.setattr(platform, "pin_host_heap", lambda: calls.append(1) or True)
+    src, dst = np.array([0, 1, 1, 2], np.int32), np.array([1, 0, 2, 1], np.int32)
+    dispatch._stage(dispatch.DispatchPlan(kind="xla"), src, dst, 3, None)
+    assert calls == [1]

@@ -194,3 +194,21 @@ def test_round_counts_read_no_arc_sized_gather_on_tpu(one_chip, for_tpu):
     assert [size for _, size in gathers if size > n + 1] == [arcs + 1]
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " = " in ln]
     assert len(calls) == 2 and all(c.strip().startswith("%segment_sum.") for c in calls)
+
+
+def test_insertion_upper_bound_compiles_at_the_trickle_cell_shapes(one_chip, for_tpu):
+    """The streaming engine's tight insertion upper bound — every +1 pass
+    in one while_loop, with its pass count — at the trickle cell's shapes:
+    65,536 vertices, 2^21 padded arc slots, 256 padded inserted edges."""
+    from repro.streaming.engine import _ub_converge
+
+    n, slots, ins = 1 << 16, 1 << 21, 256
+    S = lambda shape, dtype=jnp.int32: _spec(one_chip, shape, dtype)  # noqa: E731
+    compiled = _ub_converge.lower(
+        S((n,)), S((n,)), S((slots,)), S((slots,)), S((slots,), jnp.bool_),
+        S((ins,)), S((ins,)), S((ins,), jnp.bool_), n=n,
+    ).compile()
+    U, passes = compiled.out_info
+    assert U.shape == (n,) and passes.shape == () and passes.dtype == jnp.int32
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
