@@ -1,9 +1,10 @@
 """Backend-aware superstep dispatch: XLA segment ops vs Pallas kernels.
 
-Two Pallas kernels target the h-index superstep — ``kernels/kcore_hindex``
+Pallas kernels target the h-index superstep — ``kernels/kcore_hindex``
 (rowwise clipped h-index over the degree-bucketed ELL layout) and
 ``kernels/segment_sum`` (blocked one-hot-matmul segment sum over sorted
-COO). This module is the routing layer between them and the generic
+COO, and the binary search's per-row hit count ``row_hits`` on the same
+layout). This module is the routing layer between them and the generic
 ``jax.ops.segment_sum`` programs of ``core.kcore``:
 
 * ``resolve_plan()`` turns the platform dispatch switch
@@ -16,11 +17,11 @@ COO). This module is the routing layer between them and the generic
   superstep programs with the SAME contract as
   ``core.kcore.masked_round_segment`` / ``core.kcore.fused_convergence``,
   but with the per-round reductions routed through the kernels: the
-  binary-search hit counts and the receiver computation go through the
-  blocked Pallas segment sum, and — when the caller provides the static
-  degree-bucketed ``EllGraph`` — the whole per-vertex h-index goes through
-  the Pallas ``hindex_rows`` kernel instead of the log2(maxdeg)
-  segment-sum binary search.
+  binary-search hit counts go through ``row_hits``, the receiver
+  computation through the blocked Pallas segment sum, and — when the
+  caller provides the static degree-bucketed ``EllGraph`` — the whole
+  per-vertex h-index goes through the Pallas ``hindex_rows`` kernel
+  instead of the log2(maxdeg) binary search.
 
 Dispatch is an execution-placement choice, never an accounting one: cores
 and per-round MessageStats are bit-equal across every (plan, mode) pair —
@@ -31,10 +32,11 @@ and sharded modes. The sharded (shard_map) paths keep the XLA segment ops.
 Inside the programs the parts of the round carry ``jax.named_scope``s —
 ``kcore.gather``, ``kcore.hindex``, ``kcore.changed``, ``kcore.recv`` and
 (in the fused loop) ``kcore.stats`` — and the kernels their
-``pallas_call`` names (``kcore_hindex``, ``segment_sum``), so a device
-trace ties each op to the part of the round it computes. Staging the
-graph operands is a ``stage`` layer span (repro.obs.trace.layer) that
-counts the bytes it copies to the device (``h2d_bytes``).
+``pallas_call`` names (``kcore_hindex``, ``segment_sum``, ``row_hits``),
+so a device trace ties each op to the part of the round it computes.
+Staging the graph operands is a ``stage`` layer span
+(repro.obs.trace.layer) that counts the bytes it copies to the device
+(``h2d_bytes``).
 
 The graph enters every program as jit ARGUMENTS (``GraphOperands``: arcs,
 the blocked segment-sum layout, the ELL tables), never as closed-over
@@ -61,7 +63,13 @@ from jax import lax
 from repro import platform as _platform
 from repro.graph.structs import EllGraph
 from repro.kernels.kcore_hindex.ops import hindex_rows
-from repro.kernels.segment_sum.ops import blocked_layout, segment_sum_arrays, slot_rows, to_slots
+from repro.kernels.segment_sum.ops import (
+    blocked_layout,
+    row_hits_arrays,
+    segment_sum_arrays,
+    slot_rows,
+    to_slots,
+)
 from repro.obs import trace
 
 
@@ -186,10 +194,12 @@ def _round(ops: GraphOperands, est, mask, active, *, n, n_iters, R, n_rows_pad):
     the operands' order (``_operand_mask``). With ELL tables (static
     fully-live adjacency only — the from-scratch decomposition) the h-index
     runs through the Pallas ``hindex_rows`` kernel per degree bucket;
-    otherwise it is the binary search with the hit counts routed through
-    the dispatched segment sum. Every counted mask is built in the
-    operands' order from vertex-sized vectors, so with a blocked layout it
-    reaches the kernel with no permutation.
+    otherwise it is the binary search, whose hit counts go through the
+    ``row_hits`` kernel with a blocked layout (each row's probe reaches its
+    slots inside the kernel) and through the XLA segment sum without one.
+    Every counted mask is built in the operands' order from vertex-sized
+    vectors, so with a blocked layout it reaches the kernel with no
+    permutation.
     """
     src, dst = ops.src, ops.dst
 
@@ -198,10 +208,17 @@ def _round(ops: GraphOperands, est, mask, active, *, n, n_iters, R, n_rows_pad):
         def count(m):
             return segment_sum_arrays(m, *ops.seg, R=R, n_rows_pad=n_rows_pad, n_rows=n)[:, 0]
 
+        def hits(est_dst, mid):
+            # each row's probe reaches its slots inside the kernel
+            return row_hits_arrays(est_dst, mid, *ops.seg[1:], R=R, n_rows_pad=n_rows_pad, n_rows=n)
+
     else:
 
         def count(m):
             return jax.ops.segment_sum(m.astype(jnp.int32), src, num_segments=n)
+
+        def hits(est_dst, mid):
+            return count((est_dst >= mid[src]) & (mid[src] > 0))
 
     # est_ext[n] = 0: the padding slots' sentinel destination, and the ELL
     # tables' padded neighbor slots, never count for k >= 1
@@ -224,8 +241,7 @@ def _round(ops: GraphOperands, est, mask, active, *, n, n_iters, R, n_rows_pad):
         def body(lohi, _):
             lo, hi = lohi
             mid = (lo + hi + 1) // 2
-            cnt = count((est_dst >= mid[src]) & (mid[src] > 0))
-            ok = cnt >= mid
+            ok = hits(est_dst, mid) >= mid
             return (jnp.where(ok, mid, lo), jnp.where(ok, hi, mid - 1)), None
 
         # lax.scan (not fori_loop) like core.kcore._hindex_by_bsearch:
@@ -239,6 +255,13 @@ def _round(ops: GraphOperands, est, mask, active, *, n, n_iters, R, n_rows_pad):
         changed_ext = jnp.concatenate([changed, jnp.zeros(1, jnp.bool_)])
         recv = count(changed_ext[dst] & mask) > 0
     return new_est, changed, recv
+
+
+def row_hit_steps(ops: GraphOperands, n_iters: int) -> int:
+    """``row_hits`` kernel calls one ``_round`` on ``ops`` makes: one per
+    binary-search step on the slot route, none with ELL tables or on the
+    XLA plan."""
+    return n_iters if ops.seg and not ops.ell else 0
 
 
 @functools.partial(jax.jit, static_argnames=("n", "n_iters", "R", "n_rows_pad"))

@@ -27,8 +27,10 @@ its call until ``block_until_ready`` returns) and ``stats-reconstruct``
 (the stat-buffer slices and the estimate fetch, and the compiles they
 cause) children. ``FusedOutcome.stage_s`` / ``loop_s`` / ``device_s`` /
 ``reconstruct_s`` are those spans' durations, so benchmark rows get the
-breakdown without tracing on; the run's compile count/seconds delta and
-its rounds and messages are attached to ``fused-converge``.
+breakdown without tracing on; the run's compile count/seconds delta, its
+rounds and messages, and its ``row_hits`` kernel calls (``row_hit_calls``:
+rounds × binary-search steps on the slot route, else 0) are attached to
+``fused-converge``.
 """
 
 from __future__ import annotations
@@ -96,10 +98,12 @@ def _finish(
     dispatch="xla",
     frontier1=None,
     seed=None,
+    row_hit_steps=0,
 ):
     """Shared tail of both fused paths: reconstruct the per-round stats and
     fetch the estimate inside ``stats-reconstruct``, after the
-    ``device-converge`` span ``dev`` has closed."""
+    ``device-converge`` span ``dev`` has closed. ``row_hit_steps`` is the
+    ``row_hits`` kernel calls a round makes (``dispatch.row_hit_steps``)."""
     with trace.layer("stats-reconstruct") as rec_span:
         est_j, r, stop, final_act, mb, cb, rb = raw
         # slices of the device buffers by the round count: a count not
@@ -127,6 +131,7 @@ def _finish(
             converged=outcome.converged,
             compile_delta=outcome.compile_delta,
             compile_s=round(outcome.compile_s, 6),
+            row_hit_calls=outcome.rounds * row_hit_steps,
         )
         # flight capture, reconstructed post-hoc from the while_loop stat
         # buffers: exactly the rounds a host loop would have recorded, same
@@ -205,6 +210,7 @@ def fused_converge_dense(
                     np.asarray(dst, np.int32),
                     ell=ell,
                 )
+                row_hit_steps = _dispatch.row_hit_steps(prog.operands, n_iters)
                 with trace.layer("stage", h2d_bytes=0) as st:
                     args = (
                         to_device(st, seed, jnp.int32),
@@ -214,6 +220,7 @@ def fused_converge_dense(
                     )
                 out = _device_loop(prog, *args)
             else:
+                row_hit_steps = 0
                 with trace.layer("stage", h2d_bytes=0) as st:
                     args = (
                         to_device(st, seed, jnp.int32),
@@ -234,6 +241,7 @@ def fused_converge_dense(
             dispatch=plan.kind,
             frontier1=frontier1,
             seed=seed_np,
+            row_hit_steps=row_hit_steps,
         )
 
 

@@ -29,6 +29,14 @@ and PADDED so each edge block touches exactly one output row block;
 names that row block. Sorted edges mean each out block is visited by
 consecutive grid steps, so the accumulate-in-VMEM pattern is safe on TPU's
 sequential grid.
+
+``row_hits`` is the binary-search h-index's hit count on the same layout:
+it takes each row's probe as an (R/128, 128) tile of its row block, moves
+it to the row's edges with the transpose of the same two one-hots (a
+matmul that picks column r % 128, a select of sublane r // 128), tests
+``est >= probe > 0`` per edge and counts the hits per row as above. An
+int32 probe crosses the MXU as its four bytes, each an integer below 256
+and so exact in bf16; the shifts put them back together.
 """
 
 from __future__ import annotations
@@ -95,3 +103,80 @@ def segment_sum_pallas(vals, rows_local, block_row, n_blocks_out: int, *, R: int
         interpret=interpret,
         name="segment_sum",
     )(block_row, vals, rows_local)
+
+
+# bytes of an int32 probe, each broadcast exactly as one bf16 integer < 256
+PROBE_BYTES = 4
+
+
+def _row_hits_kernel(block_row_ref, est_ref, rows_ref, probe_ref, out_ref):
+    i = pl.program_id(0)
+    first = jnp.logical_or(i == 0, block_row_ref[jnp.maximum(i - 1, 0)] != block_row_ref[i])
+
+    @pl.when(first)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    est = est_ref[...]  # (be/128, 128) int32 neighbour estimate, edges on lanes
+    rows = rows_ref[...]  # (be/128, 128) int32 local row in [0, R)
+    probe = probe_ref[...]  # (R/128, 128) int32: row r's probe at (r // 128, r % 128)
+    n_hi = probe.shape[0]
+    # the probe's bytes, stacked on sublanes: every one is an integer below
+    # 256, exact in bf16, so one bf16 pass broadcasts the whole int32
+    # (byte b = 3 keeps the sign bits; the shifts below wrap them back)
+    pieces = jnp.concatenate(
+        [((probe >> (8 * b)) & 0xFF).astype(jnp.float32) for b in range(PROBE_BYTES)], axis=0
+    ).astype(jnp.bfloat16)  # (PROBE_BYTES * R/128, 128 rows)
+    hi_iota = jax.lax.broadcasted_iota(jnp.int32, (n_hi, LANES), 0)
+    lo_iota = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    acc = jnp.zeros(out_ref.shape, jnp.float32)
+    for s in range(est.shape[0]):
+        r = rows[s : s + 1, :]
+        hi = hi_iota == r // LANES  # (R/128, 128 edges)
+        lo = (lo_iota == r % LANES).astype(jnp.float32)  # (128 rows, 128 edges)
+        # every edge's row probe, byte by byte: pieces[:, r % 128]
+        sel = jax.lax.dot_general(
+            pieces,
+            lo.astype(jnp.bfloat16),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ).astype(jnp.int32)  # (PROBE_BYTES * R/128, 128 edges)
+        by_hi = sel[:n_hi]
+        for b in range(1, PROBE_BYTES):
+            by_hi = by_hi + (sel[b * n_hi : (b + 1) * n_hi] << (8 * b))
+        mid = jnp.sum(jnp.where(hi, by_hi, 0), axis=0, keepdims=True)  # (1, 128 edges)
+        hit = jnp.logical_and(est[s : s + 1, :] >= mid, mid > 0).astype(jnp.float32)
+        acc += jax.lax.dot_general(
+            hi.astype(jnp.float32).astype(jnp.bfloat16),
+            (lo * hit).astype(jnp.bfloat16),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+    out_ref[...] += acc.astype(jnp.int32)
+
+
+def row_hits_pallas(est, rows_local, block_row, probe, *, R: int, interpret: bool):
+    """est and rows_local: (n_edge_blocks, be/128, 128) int32 in padded slot
+    order (a slot's neighbour estimate, and its local row); block_row:
+    (n_edge_blocks,) int32 out-block id per edge block; probe:
+    (n_blocks_out, R/128, 128) int32, row r of out block b at [b, r // 128,
+    r % 128]. Returns the same shape in int32: per row, the slots with
+    ``est >= probe > 0``."""
+    sub = est.shape[1]
+    tile = pl.BlockSpec((None, R // LANES, LANES), lambda i, br: (br[i], 0, 0))
+    return pl.pallas_call(
+        _row_hits_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(block_row.shape[0],),
+            in_specs=[
+                pl.BlockSpec((None, sub, LANES), lambda i, br: (i, 0, 0)),
+                pl.BlockSpec((None, sub, LANES), lambda i, br: (i, 0, 0)),
+                tile,
+            ],
+            out_specs=tile,
+        ),
+        out_shape=jax.ShapeDtypeStruct(probe.shape, jnp.int32),
+        interpret=interpret,
+        name="row_hits",
+    )(block_row, est, rows_local, probe)

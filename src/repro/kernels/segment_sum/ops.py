@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import platform as _platform
-from repro.kernels.segment_sum.kernel import LANES, segment_sum_pallas
+from repro.kernels.segment_sum.kernel import LANES, row_hits_pallas, segment_sum_pallas
 
 # every integer below 2**24 is exact in float32
 EXACT_COUNT_LIMIT = 1 << 24
@@ -138,6 +138,28 @@ def segment_sum_arrays(
         cols.append(out.reshape(-1)[:n_rows])
     out = jnp.stack(cols, axis=1)
     return out.astype(jnp.int32) if counting else out.astype(vals.dtype)
+
+
+def row_hits_arrays(est, probe, rows_local, block_row, *, R: int, n_rows_pad: int, n_rows: int):
+    """Traceable per-row hit count of the binary-search h-index.
+
+    est: (E_pad,) int32 in the layout's padded slot order, each slot's
+    neighbour estimate (zero on padding slots); probe: (n_rows,) int32, one
+    per row. Returns (n_rows,) int32: per row r, the slots of r with
+    ``est >= probe[r] > 0``. The kernel broadcasts each row's probe to its
+    slots in VMEM, exactly for every int32, so no (E_pad,) array of probes
+    is gathered or written.
+    """
+    probe = jnp.pad(probe, (0, n_rows_pad - n_rows)).reshape(n_rows_pad // R, R // LANES, LANES)
+    out = row_hits_pallas(
+        est.reshape(rows_local.shape),
+        rows_local,
+        block_row,
+        probe,
+        R=R,
+        interpret=_platform.interpret_kernels(),
+    )
+    return out.reshape(-1)[:n_rows]
 
 
 @functools.partial(jax.jit, static_argnames=("R", "n_rows_pad", "n_rows"))

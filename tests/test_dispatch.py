@@ -132,28 +132,69 @@ def test_decompose_parity_pallas_vs_xla(name, make, fused):
     assert np.array_equal(rp.core, bz_core_numbers(g))
 
 
+def _hub_graph():
+    """A hub of degree 616 (600 leaves, and a 17-clique it belongs to) and
+    13 isolated vertices: the hub's degree seed makes binary-search probes
+    of 256 and above, which bf16 cannot hold exactly."""
+    from repro.graph.structs import Graph
+
+    clique = np.array([0, *range(601, 617)])
+    iu = np.triu_indices(clique.size, k=1)
+    edges = [[0, v] for v in range(1, 601)] + np.stack([clique[iu[0]], clique[iu[1]]], 1).tolist()
+    return Graph.from_edges(np.asarray(edges), n=630)
+
+
+def _hub_batches(eng, rng):
+    """Delete ten hub arcs and a clique edge; put them back with ten more
+    (twenty inserts at the hub take the degree seed); then random churn."""
+    from repro.streaming import random_churn_batch
+    from repro.streaming.delta import EdgeBatch
+
+    spokes = [[0, v] for v in range(1, 11)]
+    yield EdgeBatch.make(insert=np.asarray([[1, 2], [3, 4], [617, 618]]),
+                         delete=np.asarray(spokes + [[601, 602]]))
+    yield EdgeBatch.make(insert=np.asarray(spokes + [[0, v] for v in range(617, 627)]),
+                         delete=np.asarray([[1, 2]]))
+    yield random_churn_batch(eng.graph, 10, 10, rng)
+
+
+def _random_batches(eng, rng):
+    from repro.streaming import random_churn_batch
+
+    for _ in range(3):
+        yield random_churn_batch(eng.graph, 10, 10, rng)
+
+
+_STREAMS = [
+    ("ba", lambda: gen.barabasi_albert(200, 3, seed=2), _random_batches),
+    ("hub-616", _hub_graph, _hub_batches),
+]
+
+
+@pytest.mark.parametrize("name,make,batches", _STREAMS, ids=[s[0] for s in _STREAMS])
 @pytest.mark.parametrize("frontier", ["dense", "fused"])
-def test_streaming_parity_pallas_vs_xla(frontier):
+def test_streaming_parity_pallas_vs_xla(frontier, name, make, batches):
     """Streaming engine (dense per-round AND fused batch re-convergence):
     REPRO_PALLAS routing gives the identical bill per churn batch. The
     padded slot arrays hold masked-off slots, which the Pallas route
-    permutes into slot order and must not count."""
-    from repro.streaming import (StreamingConfig, StreamingKCoreEngine,
-                                 random_churn_batch)
+    permutes into slot order and must not count. On the hub graph the
+    binary search probes the hub at 256 and above, which the ``row_hits``
+    kernel must move to the hub's slots exactly."""
+    from repro.streaming import StreamingConfig, StreamingKCoreEngine
 
     def run(mode, frontier):
         platform.set_dispatch_mode(mode)
         try:
-            g = gen.barabasi_albert(200, 3, seed=2)
+            g = make()
             eng = StreamingKCoreEngine(g, StreamingConfig(frontier=frontier))
             rng = np.random.default_rng(7)
             out = []
-            for _ in range(3):
-                res = eng.apply_batch(random_churn_batch(eng.graph, 10, 10,
-                                                         rng))
+            for batch in batches(eng, rng):
+                res = eng.apply_batch(batch)
                 out.append((res.stats.messages_per_round.tolist(),
                             res.stats.active_per_round.tolist(),
                             res.stats.changed_per_round.tolist(),
+                            res.seed_strategy,
                             eng.core.tolist()))
                 assert not eng._padded_slots()[2].all()
             assert np.array_equal(eng.core, bz_core_numbers(eng.graph))
@@ -161,7 +202,12 @@ def test_streaming_parity_pallas_vs_xla(frontier):
         finally:
             platform.set_dispatch_mode(None)
 
-    assert run("xla", frontier) == run("pallas", frontier)
+    xla = run("xla", frontier)
+    assert xla == run("pallas", frontier)
+    if name == "hub-616":
+        # the hub (degree 626) re-converges from its degree seed: its first
+        # probe is 313; the clique that lost an edge holds core 15
+        assert xla[1][3] == "degree" and xla[1][4][0] == 15
 
 
 @pytest.mark.parametrize("dtype", [np.bool_, np.float32])

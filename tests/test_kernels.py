@@ -153,6 +153,58 @@ def test_segment_sum_refuses_inexact_inputs(monkeypatch):
         blocked_layout(seg, 2, R=128, be=128)
 
 
+# probes at the bf16 rounding edges and past f32's exact integers
+_PROBES = np.array([0, 1, 255, 256, 257, 9_699, 65_536, (1 << 23) - 1, (1 << 24) + 1,
+                    (1 << 31) - 1], np.int64)
+
+
+def _hub_rows(n, hubs, E, r):
+    """Arc sources (rows): ``hubs`` rows own most arcs, the rest uniform."""
+    return np.concatenate([r.choice(hubs, E // 2), r.integers(0, n, E - E // 2)])
+
+
+@pytest.mark.parametrize("name,n,E,R,be", [
+    # five 128-slot edge blocks for one 128-row block
+    ("several-edge-blocks-per-row-block", 300, 1500, 128, 128),
+    # rows 128..383 have no arcs: two row blocks of padding slots only
+    ("empty-row-blocks", 600, 700, 128, 128),
+    # the round's defaults: a 2048-slot edge block spilling into a second
+    ("default-tiles", 2100, 5000, 1024, 2048),
+])
+def test_row_hits_counts_each_rows_probe_hits(name, n, E, R, be):
+    """``row_hits_arrays`` (interpret mode) against numpy over arcs:
+    counts[r] = #{arcs of row r : est >= probe[r] > 0}. Estimates sit at,
+    just below and just above each row's probe, so a probe that crosses the
+    MXU rounded (257 as bf16 is 256) or in f32 (2^24 + 1) miscounts."""
+    from repro.kernels.segment_sum.ops import row_hits_arrays, to_slots
+
+    r = np.random.default_rng(len(name))
+    if name == "empty-row-blocks":
+        seg = np.concatenate([r.integers(0, 128, E // 2), r.integers(384, n, E - E // 2)])
+    else:
+        seg = _hub_rows(n, np.array([3, 5, 77]), E, r)
+    probe = np.where(r.random(n) < 0.7, r.choice(_PROBES, n), r.integers(0, 1 << 31, n))
+    est = probe[seg] + r.integers(-1, 2, E)
+    est = np.where(r.random(E) < 0.1, r.integers(0, 1 << 31, E), est)
+    probe, est = probe.astype(np.int32), np.clip(est, 0, (1 << 31) - 1).astype(np.int32)
+    lo = blocked_layout(seg, n, R=R, be=be)
+    assert lo.slot_edge.size > E                               # padding slots
+    if name == "several-edge-blocks-per-row-block":
+        assert np.bincount(lo.block_row).max() >= 3
+    if name == "empty-row-blocks":
+        assert np.isin([1, 2], lo.block_row).all()
+        assert not np.isin(np.arange(128, 384), seg).any()
+    out = row_hits_arrays(
+        jnp.asarray(to_slots(est, lo.slot_edge, 0)), jnp.asarray(probe),
+        jnp.asarray(lo.rows_local), jnp.asarray(lo.block_row),
+        R=lo.R, n_rows_pad=lo.n_rows_pad, n_rows=n)
+    hit = (est >= probe[seg]) & (probe[seg] > 0)
+    ref = np.bincount(seg, weights=hit, minlength=n).astype(np.int64)
+    assert out.dtype == jnp.int32 and out.shape == (n,)
+    np.testing.assert_array_equal(np.asarray(out), ref)
+    assert 0 < ref.sum() < E
+
+
 # ------------------------- flash attention --------------------------- #
 
 @pytest.mark.parametrize("B,Sq,Sk,Hq,Hkv,D", [

@@ -121,6 +121,31 @@ def test_server_update_builds_the_batch_layer_tree(ring):
     assert res.reconstruct_s == _only(spans, "host-reconstruct").seconds
 
 
+@pytest.mark.parametrize("mode", ["pallas", "xla"])
+def test_fused_converge_counts_its_row_hits_calls(ring, mode):
+    """``row_hit_calls`` on ``fused-converge``: one ``row_hits`` kernel call
+    per binary-search step of every round on the Pallas plan's slot route,
+    none on the XLA plan."""
+    from repro import platform
+
+    g = gen.erdos_renyi(300, 900, seed=4)
+    platform.set_dispatch_mode(mode)
+    try:
+        srv = KCoreServer(g, StreamingConfig(frontier="fused"))
+        there, _back = _batches(g)
+        trace.reset()
+        res = srv.update(there)
+    finally:
+        platform.set_dispatch_mode(None)
+    assert res.mode == "fused"
+    fc = _only(trace.recent_layers(), "fused-converge")
+    assert fc.attrs["dispatch"] == mode and fc.attrs["rounds"] == res.rounds >= 1
+    n_iters = srv.engine._n_iters_hwm
+    assert n_iters >= 4
+    want = res.rounds * n_iters if mode == "pallas" else 0
+    assert fc.attrs["row_hit_calls"] == want
+
+
 def test_fused_decompose_builds_the_root_layer_tree(ring):
     g = gen.erdos_renyi(3000, 12000, seed=3)
     args = kcore_run.parse_args(["--fused"])

@@ -22,7 +22,7 @@ from repro.core import dispatch
 from repro.core.kcore import _bs_iters
 from repro.kernels.kcore_hindex.kernel import hindex_rows_pallas
 from repro.kernels.kcore_hindex.ops import _pick_row_tile
-from repro.kernels.segment_sum.kernel import segment_sum_pallas
+from repro.kernels.segment_sum.kernel import row_hits_pallas, segment_sum_pallas
 
 
 @pytest.fixture(scope="module")
@@ -83,6 +83,24 @@ def test_segment_sum_kernel_compiles(one_chip, for_tpu):
             _spec(one_chip, (blocks, be // 128, 128), jnp.float32),
             _spec(one_chip, (blocks, be // 128, 128)),
             _spec(one_chip, (blocks,)),
+        )
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("R,be", [(1024, 2048), (128, 128)])
+def test_row_hits_kernel_compiles(one_chip, for_tpu, R, be):
+    """The round's tiles (R=1024, be=2048), and the smallest: one sublane
+    of probes per row block."""
+    blocks, out_blocks = 8, 4
+    compiled = (
+        jax.jit(lambda e, r, b, p: row_hits_pallas(e, r, b, p, R=R, interpret=False))
+        .lower(
+            _spec(one_chip, (blocks, be // 128, 128)),
+            _spec(one_chip, (blocks, be // 128, 128)),
+            _spec(one_chip, (blocks,)),
+            _spec(one_chip, (out_blocks, R // 128, 128)),
         )
         .compile()
     )
@@ -151,8 +169,8 @@ def test_kernels_and_round_scopes_keep_their_names_on_tpu(one_chip, for_tpu):
 
 
 def _gathers(text):
-    """(op_name, element count of the gathered operand) of every gather in
-    compiled HLO text."""
+    """(op_name, element count of the gathered operand, element count of
+    the result) of every gather in compiled HLO text."""
     sizes = {}
     for ln in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]", ln)
@@ -160,21 +178,27 @@ def _gathers(text):
             sizes[m.group(1)] = math.prod(int(d) for d in m.group(2).split(",") if d)
     out = []
     for ln in text.splitlines():
-        m = re.search(r"= \S+ gather\((%[\w.\-]+),", ln)
+        m = re.search(r"(%[\w.\-]+) = \S+ gather\((%[\w.\-]+),", ln)
         if m:
-            out.append((re.search(r'op_name="([^"]*)"', ln).group(1), sizes[m.group(1)]))
+            name = re.search(r'op_name="([^"]*)"', ln).group(1)
+            out.append((name, sizes[m.group(2)], sizes[m.group(1)]))
     return out
 
 
-def test_round_counts_read_no_arc_sized_gather_on_tpu(one_chip, for_tpu):
-    """At the churn cell's shapes (16,384 vertices, 2^19 arc slots, 540,672
-    padded layout slots, 16 bsearch steps) the masks the fused round counts
+@pytest.mark.parametrize("n,arcs,e_pad", [
+    (1 << 14, 1 << 19, 540_672),        # graph500-s14.churn
+    (1 << 16, 1 << 21, 2_158_592),      # graph500-s16.trickle
+], ids=["churn", "trickle"])
+def test_round_counts_read_no_arc_sized_gather_on_tpu(one_chip, for_tpu, n, arcs, e_pad):
+    """At the churn and trickle cells' shapes (vertices, padded arc slots,
+    padded layout slots; 16 bsearch steps) the masks the fused round counts
     are built in the blocked layout's slot order: inside the while body no
     gather under ``kcore.hindex`` or ``kcore.recv`` reads an arc-sized
-    operand, only vertex-sized ones. The arc mask is permuted once, outside
-    the loop, and the two segment-sum calls (bsearch count, receivers)
-    stay."""
-    n, arcs, e_pad = 1 << 14, 1 << 19, 540672
+    operand, only vertex-sized ones, and none under ``kcore.hindex`` makes
+    an arc-sized result: the binary search's probes reach their slots
+    inside the ``row_hits`` kernel. The arc mask is permuted once, outside
+    the loop; the round makes one ``row_hits`` call (the bsearch step, in
+    the scan) and one ``segment_sum`` (receivers)."""
     S = lambda shape, dtype=jnp.int32: _spec(one_chip, shape, dtype)  # noqa: E731
     blocks = e_pad // 2048
     ops = dispatch.GraphOperands(
@@ -185,15 +209,19 @@ def test_round_counts_read_no_arc_sized_gather_on_tpu(one_chip, for_tpu):
         n=n, n_iters=16, max_rounds=n + 1, R=1024, n_rows_pad=n,
     ).compile().as_text()
     gathers = _gathers(text)
-    in_loop = [(name, size) for name, size in gathers if "/while/body/" in name]
-    for scope in ("kcore.hindex", "kcore.recv"):
-        assert any(f"/{scope}/" in name for name, _ in in_loop), scope
+    in_loop = [g for g in gathers if "/while/body/" in g[0]]
+    assert any("/kcore.recv/" in name for name, _, _ in in_loop)
     # n + 1: the vertex vectors with the padding slots' sentinel entry
-    assert all(size <= n + 1 for _, size in in_loop), in_loop
-    # the one arc-sized gather: the mask's permutation, before the loop
-    assert [size for _, size in gathers if size > n + 1] == [arcs + 1]
+    assert all(size <= n + 1 for _, size, _ in in_loop), in_loop
+    hindex = [g for g in in_loop if "/kcore.hindex/" in g[0]]
+    assert all(out <= n + 1 for _, _, out in hindex), hindex
+    # the one arc-sized gathered operand: the mask's permutation, before the loop
+    assert [size for _, size, _ in gathers if size > n + 1] == [arcs + 1]
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " = " in ln]
-    assert len(calls) == 2 and all(c.strip().startswith("%segment_sum.") for c in calls)
+    heads = sorted(c.strip().split(".", 1)[0] for c in calls)
+    assert heads == ["%row_hits", "%segment_sum"], heads
+    (row_hits,) = [c for c in calls if c.strip().startswith("%row_hits.")]
+    assert "/while/body/" in row_hits and "/kcore.hindex/" in row_hits
 
 
 def test_insertion_upper_bound_compiles_at_the_trickle_cell_shapes(one_chip, for_tpu):
