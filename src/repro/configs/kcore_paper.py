@@ -4,6 +4,6 @@
 from repro.core.kcore import KCoreConfig
 from repro.graph.generators import SNAP_TABLE
 
-CONFIG = KCoreConfig(mode="jacobi", backend="segment")
-CONFIG_BEYOND = KCoreConfig(mode="block_gs", backend="segment", n_blocks=16)
+CONFIG = KCoreConfig(mode="jacobi")
+CONFIG_BEYOND = KCoreConfig(mode="block_gs", n_blocks=16)
 GRAPHS = tuple(e.abbrev for e in SNAP_TABLE)

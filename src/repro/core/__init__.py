@@ -8,12 +8,10 @@ from repro.core.jit_telemetry import compile_count, compile_seconds
 from repro.core.kcore import (
     KCoreConfig,
     KCoreResult,
-    fused_convergence,
     fused_round_stats,
     kcore_decompose,
     kcore_decompose_sharded,
     make_sharded_superstep,
-    masked_round_segment,
 )
 from repro.core.cost_model import SeedCostModel, choose_seed, estimate_ub_passes
 from repro.core.messages import MessageStats, heartbeat_overhead, work_bound
@@ -43,12 +41,10 @@ __all__ = [
     "compile_seconds",
     "KCoreConfig",
     "KCoreResult",
-    "fused_convergence",
     "fused_round_stats",
     "kcore_decompose",
     "kcore_decompose_sharded",
     "make_sharded_superstep",
-    "masked_round_segment",
     "MessageStats",
     "heartbeat_overhead",
     "work_bound",

@@ -17,12 +17,15 @@ Execution modes
                     converges in fewer rounds / messages (mimics the Go
                     version's asynchrony).
 
-Backends
-  * ``segment``     — sorted-COO + jax.ops.segment_sum; the general, shardable
-                      path. The per-round h-index is a vectorized binary
-                      search (log2(maxdeg) segment_sums per round).
-  * ``ell``         — degree-bucketed dense tiles, pure-jnp rowwise h-index.
-  * ``ell_pallas``  — same layout, Pallas kernel (kernels/kcore_hindex).
+Execution paths
+  * host loop   — one jitted superstep per Python-loop round;
+  * fused       — the whole round loop as one device-resident while_loop
+                  (the shared runtime, core/runtime.py); jacobi only.
+  Both run the one single-device round body, ``core.dispatch._round``; the
+  plan (``KCoreConfig.dispatch``, from the platform by default) picks its
+  reductions: XLA segment sums, or the Pallas kernels (on a from-scratch
+  decomposition the ELL ``kcore_hindex`` kernel does the whole h-index).
+  ``block_gs`` sweeps its blocks with the XLA segment sums.
 
 Distribution: `make_sharded_superstep` builds a shard_map superstep over a
 device mesh — vertex state sharded by contiguous range, arcs co-located with
@@ -49,7 +52,7 @@ from repro.obs import flight as _flight
 from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
 from repro.graph.partition import ShardedGraph
-from repro.graph.structs import EllGraph, Graph
+from repro.graph.structs import Graph, build_ell
 
 
 # ---------------------------------------------------------------------- #
@@ -59,7 +62,6 @@ from repro.graph.structs import EllGraph, Graph
 @dataclasses.dataclass(frozen=True)
 class KCoreConfig:
     mode: str = "jacobi"            # "jacobi" | "block_gs"
-    backend: str = "segment"        # "segment" | "ell" | "ell_pallas"
     n_blocks: int = 8               # block_gs sweep granularity
     max_rounds: int | None = None   # None → n (the worst-case depth)
     widths: tuple[int, ...] = (8, 32, 128, 512, 2048)
@@ -69,8 +71,8 @@ class KCoreConfig:
     fused: bool = False
     # superstep kernel dispatch (repro.core.dispatch): "auto" consults the
     # platform layer (REPRO_PALLAS env; Pallas only where it compiles
-    # natively), "pallas"/"xla" force it. Segment-backend jacobi paths
-    # (host loop and fused) only; execution placement, never accounting.
+    # natively), "pallas"/"xla" force it. Jacobi paths (host loop and
+    # fused) only; execution placement, never accounting.
     dispatch: str = "auto"
 
 
@@ -110,120 +112,8 @@ def _bs_iters(max_deg: int) -> int:
 
 
 # ---------------------------------------------------------------------- #
-# Single-host rounds — segment backend
+# Fused convergence — per-round accounting from the device stat buffers
 # ---------------------------------------------------------------------- #
-
-def _hindex_by_bsearch(est, est_dst_masked, src, n, n_iters):
-    """Vectorized per-vertex h-index via binary search.
-
-    For every vertex u, finds max k in [0, est_u] with
-    |{arcs (u,v): est_v >= k}| >= k. est_dst_masked must be 0 on padding arcs
-    (so they never count for k >= 1).
-    """
-    lo = jnp.zeros_like(est)
-    hi = est
-
-    def body(lohi, _):
-        lo, hi = lohi
-        mid = (lo + hi + 1) // 2
-        hit = (est_dst_masked >= mid[src]) & (mid[src] > 0)
-        cnt = jax.ops.segment_sum(hit.astype(jnp.int32), src, num_segments=n)
-        ok = cnt >= mid
-        return (jnp.where(ok, mid, lo), jnp.where(ok, hi, mid - 1)), None
-
-    # lax.scan (not fori_loop): scan records the trip count in the jaxpr,
-    # which the roofline's jaxpr-walk cost analysis needs to be exact.
-    (lo, hi), _ = lax.scan(body, (lo, hi), None, length=n_iters)
-    return lo
-
-
-def _masked_round(est, src, dst, arc_mask, active, n, n_iters):
-    """Traceable body of the masked Jacobi superstep (shared by the jitted
-    per-round entry point and the fused while_loop)."""
-    est_dst = jnp.where(arc_mask, est[dst], 0)
-    h = _hindex_by_bsearch(est, est_dst, src, n, n_iters)
-    new_est = jnp.where(active, h, est)
-    changed = new_est < est
-    # who receives a message next round: u s.t. some neighbor v changed
-    recv = jax.ops.segment_sum(
-        (jnp.where(arc_mask, changed[dst], False)).astype(jnp.int32),
-        src, num_segments=n) > 0
-    return new_est, changed, recv
-
-
-@functools.partial(jax.jit, static_argnames=("n", "n_iters"))
-def masked_round_segment(est, src, dst, arc_mask, active, n, n_iters):
-    """One frontier-masked Jacobi superstep. Returns (new_est, changed, recv).
-
-    Only vertices with ``active`` True recompute their h-index; everyone else
-    keeps their estimate. With ``active`` all-True this is the paper's plain
-    synchronous superstep. The masked form is the primitive the streaming
-    engine (repro.streaming.engine) iterates: after an edge-churn batch only
-    the frontier — vertices whose estimate may still drop — recomputes, which
-    is exact for the monotone locality operator (an inactive vertex's inputs
-    are unchanged, so recomputing it would be a no-op).
-    """
-    return _masked_round(est, src, dst, arc_mask, active, n, n_iters)
-
-
-def _round_segment(est, src, dst, arc_mask, n, n_iters):
-    """One (unmasked) Jacobi superstep. Returns (new_est, changed, received)."""
-    active = jnp.ones(est.shape, bool)
-    return masked_round_segment(est, src, dst, arc_mask, active, n, n_iters)
-
-
-# ---------------------------------------------------------------------- #
-# Fused convergence — one device-resident while_loop per batch
-# ---------------------------------------------------------------------- #
-
-@functools.partial(jax.jit, static_argnames=("n", "n_iters", "max_rounds"))
-def fused_convergence(est, src, dst, arc_mask, active, deg,
-                      n, n_iters, max_rounds):
-    """Run masked Jacobi supersteps to the fixpoint in ONE ``lax.while_loop``.
-
-    The host round loop (kcore_decompose / the streaming engine's per-round
-    ``step``) pays a device round-trip of est/changed/recv per superstep —
-    at streaming batch sizes that host traffic, not the h-index math,
-    dominates wall-clock. Montresor et al. bound the number of rounds, so a
-    whole batch re-convergence is a bounded iteration that can live on
-    device: carry = (est, active, round_idx, stop, per-round stat buffers),
-    body = the same ``_masked_round`` superstep the host loop runs, cond =
-    frontier non-empty (and round cap not hit, and last round productive).
-
-    Per executed round r the body fills three ``(max_rounds,)`` int32
-    buffers — messages (Σ deg over changed vertices; < 2m < 2^31 per round
-    for every graph we target, accumulated to int64 on host), changed
-    count, and receiver count — from which the host reconstructs per-round
-    ``MessageStats`` EXACTLY equal to the host-loop modes' accounting
-    (see ``fused_round_stats``).
-
-    Returns ``(est', rounds, stopped, final_active, msgs_buf, changed_buf,
-    recv_buf)``: ``rounds`` counts every executed superstep including a
-    final unproductive one (host-loop convention), ``stopped`` is True iff
-    the loop exited on an unproductive round, ``final_active`` is the exit
-    frontier size (0 and/or ``stopped`` ⇒ converged).
-    """
-    def cond(carry):
-        _est, act, r, stop = carry[:4]
-        return (~stop) & (r < max_rounds) & act.any()
-
-    def body(carry):
-        est, act, r, _stop, mb, cb, rb = carry
-        new_est, changed, recv = _masked_round(est, src, dst, arc_mask,
-                                               act, n, n_iters)
-        any_ch = changed.any()
-        mb = mb.at[r].set(jnp.sum(jnp.where(changed, deg, 0),
-                                  dtype=jnp.int32))
-        cb = cb.at[r].set(jnp.sum(changed, dtype=jnp.int32))
-        rb = rb.at[r].set(jnp.sum(recv, dtype=jnp.int32))
-        return new_est, recv, r + 1, ~any_ch, mb, cb, rb
-
-    zeros = jnp.zeros(max_rounds, jnp.int32)
-    carry = (est, active, jnp.int32(0), jnp.bool_(False),
-             zeros, zeros, zeros)
-    est, act, r, stop, mb, cb, rb = lax.while_loop(cond, body, carry)
-    return est, r, stop, jnp.sum(act, dtype=jnp.int32), mb, cb, rb
-
 
 def fused_round_stats(rounds, stopped, final_active,
                       msgs_buf, changed_buf, recv_buf):
@@ -248,14 +138,15 @@ def _fused_sharded_convergence(mesh: jax.sharding.Mesh, axes: tuple,
                                V: int, n_iters: int, max_rounds: int):
     """Cached jitted fused convergence over a device mesh (streaming path).
 
-    The masked shard_map superstep of ``_masked_sharded_superstep`` nested
-    INSIDE the while_loop: the whole batch re-convergence is one shard_map
+    The masked shard_map superstep (``_sharded_round``) iterated by the
+    single-device loop, ``dispatch.converge_loop``, with its counts summed
+    over the mesh: the whole batch re-convergence is one shard_map
     program, with per-round cross-device traffic only (one est all_gather,
-    one 1-bit changed all_gather, three scalar psums) — the host sees the
-    final estimate plus the filled stat buffers, same contract and same
-    exact accounting as ``fused_convergence``. Keyed on (mesh, axes, V,
-    n_iters, max_rounds) like its per-round sibling so stable shard shapes
-    reuse one compiled program across batches.
+    one 1-bit changed all_gather, scalar psums) — the host sees the final
+    estimate plus the filled stat buffers, same contract and same exact
+    accounting as ``dispatch.fused_convergence_program``. Keyed on (mesh,
+    axes, V, n_iters, max_rounds) like its per-round sibling so stable
+    shard shapes reuse one compiled program across batches.
 
     Returns ``prog(est, src, dst, arc_mask, deg, active) -> (est', rounds,
     stopped, final_active, msgs_buf, changed_buf, recv_buf)`` with est'
@@ -267,95 +158,22 @@ def _fused_sharded_convergence(mesh: jax.sharding.Mesh, axes: tuple,
 
     def prog(est, src, dst, arc_mask, deg, active):
         # shapes inside shard_map (per device): est (1, V), src (1, A), ...
-        src_l, dst_l, am_l, deg_l = src[0], dst[0], arc_mask[0], deg[0]
+        src_l, dst_l, am_l = src[0], dst[0], arc_mask[0]
 
-        def cond(carry):
-            _est, act, r, stop = carry[:4]
-            return ((~stop) & (r < max_rounds)
-                    & (lax.psum(jnp.sum(act, dtype=jnp.int32), axes) > 0))
+        def step(est_c, act_c):
+            new_l, changed_l, recv_l = _sharded_round(
+                est_c, act_c, src_l, dst_l, am_l, axes, V, n_iters)
+            return new_l[None], changed_l, recv_l[None]
 
-        def body(carry):
-            est_c, act_c, r, _stop, mb, cb, rb = carry
-            est_glob = lax.all_gather(est_c, axes, axis=0,
-                                      tiled=True).reshape(-1)
-            est_dst = jnp.where(am_l, est_glob[dst_l], 0)
-            h = _hindex_by_bsearch(est_c[0], est_dst, src_l, V, n_iters)
-            new_l = jnp.where(act_c[0], h, est_c[0])
-            changed_l = new_l < est_c[0]
-            msgs = lax.psum(jnp.sum(jnp.where(changed_l, deg_l, 0),
-                                    dtype=jnp.int32), axes)
-            ch_cnt = lax.psum(jnp.sum(changed_l, dtype=jnp.int32), axes)
-            ch_glob = lax.all_gather(changed_l[None], axes, axis=0,
-                                     tiled=True).reshape(-1)
-            recv_l = jax.ops.segment_sum(
-                jnp.where(am_l, ch_glob[dst_l], False).astype(jnp.int32),
-                src_l, num_segments=V) > 0
-            rb = rb.at[r].set(lax.psum(jnp.sum(recv_l, dtype=jnp.int32),
-                                       axes))
-            return (new_l[None], recv_l[None], r + 1, ch_cnt == 0,
-                    mb.at[r].set(msgs), cb.at[r].set(ch_cnt), rb)
-
-        zeros = jnp.zeros(max_rounds, jnp.int32)
-        carry = (est, active, jnp.int32(0), jnp.bool_(False),
-                 zeros, zeros, zeros)
-        est, act, r, stop, mb, cb, rb = lax.while_loop(cond, body, carry)
-        final = lax.psum(jnp.sum(act, dtype=jnp.int32), axes)
-        return est, r, stop, final, mb, cb, rb
+        return _dispatch.converge_loop(
+            step, est, active, deg[0], max_rounds=max_rounds,
+            any_=lambda x: lax.psum(jnp.sum(x, dtype=jnp.int32), axes) > 0,
+            total=lambda x: lax.psum(x, axes))
 
     spec_state = P(axes)
     sharded = shard_map(prog, mesh=mesh, in_specs=(spec_state,) * 6,
                         out_specs=(spec_state,) + (P(),) * 6)
     return jax.jit(sharded)
-
-
-# ---------------------------------------------------------------------- #
-# Single-host rounds — ELL backend
-# ---------------------------------------------------------------------- #
-
-def hindex_rows_ref(nbr_est, est_u, n_iters):
-    """Rowwise h-index of clip(nbr_est, 0, est_u) — jnp reference.
-
-    nbr_est: (rows, w) int32 (sentinel slots hold 0), est_u: (rows,) int32.
-    """
-    vals = jnp.minimum(nbr_est, est_u[:, None])
-    lo = jnp.zeros_like(est_u)
-    hi = est_u
-
-    def body(_, lohi):
-        lo, hi = lohi
-        mid = (lo + hi + 1) // 2
-        cnt = jnp.sum(vals >= jnp.maximum(mid[:, None], 1), axis=1)
-        ok = cnt >= mid
-        return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid - 1)
-
-    lo, hi = lax.fori_loop(0, n_iters, body, (lo, hi))
-    return lo
-
-
-def _make_round_ell(ell: EllGraph, n_iters: int, use_pallas: bool):
-    if use_pallas:
-        from repro.kernels.kcore_hindex.ops import hindex_rows as _hindex
-    else:
-        _hindex = hindex_rows_ref
-
-    bucket_ids = [jnp.asarray(b.ids) for b in ell.buckets]
-    bucket_nbrs = [jnp.asarray(b.nbrs) for b in ell.buckets]
-    n = ell.n
-
-    @jax.jit
-    def round_ell(est_ext):
-        """est_ext: (n+1,) int32, est_ext[n] == 0 (sentinel)."""
-        new_ext = est_ext
-        for ids, nbrs in zip(bucket_ids, bucket_nbrs):
-            nbr_est = est_ext[nbrs]
-            est_u = est_ext[ids]
-            h = _hindex(nbr_est, est_u, n_iters)
-            new_ext = new_ext.at[ids].set(h)
-        new_ext = new_ext.at[n].set(0)          # keep sentinel pinned
-        changed = new_ext[:n] < est_ext[:n]
-        return new_ext, changed
-
-    return round_ell
 
 
 # ---------------------------------------------------------------------- #
@@ -376,7 +194,8 @@ def _make_round_block_gs(sg: ShardedGraph, n_iters: int):
             est, changed = carry
             est_dst = jnp.where(amask[b], est[dst[b]], 0)
             est_u = lax.dynamic_slice(est, (b * V,), (V,))
-            new_u = _hindex_by_bsearch(est_u, est_dst, src[b], V, n_iters)
+            new_u = _dispatch.hindex_by_bsearch(
+                est_u, _dispatch.arc_hits(est_dst, src[b], V), n_iters)
             ch_u = new_u < est_u
             est = lax.dynamic_update_slice(est, new_u, (b * V,))
             changed = lax.dynamic_update_slice(changed, ch_u, (b * V,))
@@ -404,16 +223,14 @@ def kcore_decompose(g: Graph, config: KCoreConfig = KCoreConfig(), *,
     ``lax.while_loop`` through the shared fused runtime (core/runtime.py) —
     no per-round host round-trips — and the per-round stats are
     reconstructed from device buffers, bit-equal to the host loop
-    (hypothesis-tested, BZ-verified). Fused is jacobi-only; the backend is
-    ignored there (every backend computes the identical h-index, and the
-    fused program always stages the segment arrays).
+    (hypothesis-tested, BZ-verified). Fused is jacobi-only.
     """
     use_fused = config.fused if fused is None else fused
     if use_fused and config.mode != "jacobi":
         raise ValueError("fused=True requires mode='jacobi' "
                          f"(got {config.mode!r})")
     with _trace.layer("kcore.decompose", n=g.n, m=g.m, mode=config.mode,
-                      backend=config.backend, fused=bool(use_fused)) as _sp:
+                      fused=bool(use_fused)) as _sp:
         res = _decompose_body(g, config, use_fused)
         _sp.set(rounds=res.rounds, messages=res.stats.total_messages,
                 converged=res.converged, recompiles=res.recompiles,
@@ -425,13 +242,17 @@ def _decompose_body(g: Graph, config: KCoreConfig,
                     use_fused: bool) -> KCoreResult:
     compiles0, csecs0 = compile_count(), compile_seconds()
     phase_s: dict = {}
-    dispatch_kind = "xla"
     devices: tuple = ()
     n = g.n
     if n == 0:
         return KCoreResult(core=np.zeros(0, np.int32), rounds=0,
                            converged=True,
                            stats=MessageStats(*(np.zeros(0, np.int64),) * 3))
+    if config.mode not in ("jacobi", "block_gs"):
+        raise ValueError(f"unsupported mode {config.mode!r}")
+    # block_gs sweeps with the XLA segment sums whatever the plan
+    plan = _dispatch.resolve_plan(
+        config.dispatch if config.mode == "jacobi" else "xla")
     n_iters = _bs_iters(g.max_deg)
     max_rounds = config.max_rounds if config.max_rounds is not None else n + 1
     deg64 = g.deg.astype(np.int64)
@@ -449,23 +270,22 @@ def _decompose_body(g: Graph, config: KCoreConfig,
     if rec.active:
         rec.start_run(
             "static",
-            "fused" if use_fused else f"{config.mode}/{config.backend}",
+            "fused" if use_fused else f"{config.mode}/{plan.kind}",
             n=n)
         rec.record_round(active[0], msgs[0], changed_counts[0], est=g.deg)
 
-    if use_fused:
-        from repro.core.runtime import fused_converge_dense
-
-        plan = _dispatch.resolve_plan(config.dispatch)
-        ell = None
+    ell = None
+    if config.mode == "jacobi":
         with _trace.layer("stage", h2d_bytes=0) as ell_stage:
             if plan.kind == "pallas":
-                from repro.graph.structs import build_ell
-
                 # static fully-live adjacency + degree seed: the ELL
                 # h-index route is exact here (see dispatch._round)
                 ell = build_ell(g, widths=config.widths)
             arc_mask = np.ones(g.num_arcs, bool)
+
+    if use_fused:
+        from repro.core.runtime import fused_converge_dense
+
         # from-scratch seeding: est = degrees, frontier = every vertex —
         # round 1 of the fused loop IS round 1 of the host loop, and the
         # recv-masked rounds after it are exact for the monotone locality
@@ -478,7 +298,6 @@ def _decompose_body(g: Graph, config: KCoreConfig,
             n=n, n_iters=n_iters, max_rounds=max_rounds,
             dispatch=plan.kind, ell=ell, frontier1=active[1])
         rounds, converged = outcome.rounds, outcome.converged
-        dispatch_kind = outcome.dispatch
         devices = outcome.devices
         msgs.extend(outcome.msgs.tolist())
         changed_counts.extend(outcome.changed.tolist())
@@ -488,122 +307,54 @@ def _decompose_body(g: Graph, config: KCoreConfig,
         phase_s["host-reconstruct"] = outcome.reconstruct_s
         phase_s["stage"] = ell_stage.seconds + outcome.stage_s
         phase_s["device-loop"] = outcome.loop_s
-
-    elif config.backend == "segment" and config.mode == "jacobi":
-        plan = _dispatch.resolve_plan(config.dispatch)
-        dispatch_kind = plan.kind
-        est = jnp.asarray(g.deg, jnp.int32)
-        src = jnp.asarray(g.src, jnp.int32)
-        dst = jnp.asarray(g.dst, jnp.int32)
-        amask = jnp.ones(g.num_arcs, bool)
-        if plan.kind == "pallas":
-            from repro.graph.structs import build_ell
-
-            ell = build_ell(g, widths=config.widths)
+    else:
+        # step(est) -> (new_est, changed (n,) on the host, receiver count)
+        if config.mode == "jacobi":
             prog = _dispatch.masked_round_program(
                 n, n_iters, plan, g.src, g.dst, ell=ell)
-            ones = jnp.ones(n, bool)
+            amask, everyone = jnp.asarray(arc_mask), jnp.ones(n, bool)
 
             def step(est):
-                return prog(est, amask, ones)
+                new_est, changed, recv = prog(est, amask, everyone)
+                return new_est, np.asarray(changed), int(np.asarray(recv).sum())
+
+            est = jnp.asarray(g.deg, jnp.int32)
         else:
+            from repro.graph.partition import shard_graph
+
+            sg = shard_graph(g, max(1, config.n_blocks))
+            round_gs = _make_round_block_gs(sg, n_iters)
 
             def step(est):
-                return _round_segment(est, src, dst, amask, n, n_iters)
+                new_est, changed = round_gs(est)
+                ch = np.asarray(changed)[:n]
+                return new_est, ch, int(_receivers_np(g, ch).sum())
+
+            est = jnp.asarray(sg.deg.reshape(-1), jnp.int32)
         rounds, converged = 0, False
         with _trace.layer("converge") as conv:
             while rounds < max_rounds:
                 t_r = time.perf_counter() if rec.active else 0.0
                 with _trace.span("kcore.round", round=rounds) as rsp:
-                    new_est, changed, recv = step(est)
+                    new_est, ch_np, n_recv = step(est)
                     rounds += 1
-                    ch_np = np.asarray(changed)
                     if not ch_np.any():
                         converged = True
                         break
                     msgs.append(int(deg64[ch_np].sum()))
                     changed_counts.append(int(ch_np.sum()))
-                    active.append(int(np.asarray(recv).sum()))
+                    active.append(n_recv)
                     rsp.set(messages=msgs[-1], changed=changed_counts[-1])
                     if rec.active:
                         rec.record_round(
                             active[rounds], msgs[-1], changed_counts[-1],
-                            est=np.asarray(new_est), prev_est=np.asarray(est),
+                            est=np.asarray(new_est)[:n],
+                            prev_est=np.asarray(est)[:n],
                             host_s=time.perf_counter() - t_r,
-                            dispatch=dispatch_kind)
+                            dispatch=plan.kind)
                     est = new_est
         phase_s["converge"] = conv.seconds
-        core = np.asarray(est, np.int32)
-
-    elif config.backend in ("ell", "ell_pallas") and config.mode == "jacobi":
-        from repro.graph.structs import build_ell
-        if config.backend == "ell_pallas":
-            dispatch_kind = "pallas"
-        ell = build_ell(g, widths=config.widths)
-        round_fn = _make_round_ell(ell, n_iters,
-                                   use_pallas=config.backend == "ell_pallas")
-        est_ext = jnp.concatenate(
-            [jnp.asarray(g.deg, jnp.int32), jnp.zeros(1, jnp.int32)])
-        rounds, converged = 0, False
-        with _trace.layer("converge") as conv:
-            while rounds < max_rounds:
-                t_r = time.perf_counter() if rec.active else 0.0
-                with _trace.span("kcore.round", round=rounds):
-                    new_ext, changed = round_fn(est_ext)
-                    rounds += 1
-                    ch_np = np.asarray(changed)
-                    if not ch_np.any():
-                        converged = True
-                        break
-                    msgs.append(int(deg64[ch_np].sum()))
-                    changed_counts.append(int(ch_np.sum()))
-                    # receivers: any vertex adjacent to a changed vertex
-                    recv = _receivers_np(g, ch_np)
-                    active.append(int(recv.sum()))
-                    if rec.active:
-                        rec.record_round(
-                            active[rounds], msgs[-1], changed_counts[-1],
-                            est=np.asarray(new_ext)[:n],
-                            prev_est=np.asarray(est_ext)[:n],
-                            host_s=time.perf_counter() - t_r,
-                            dispatch=dispatch_kind)
-                    est_ext = new_ext
-        phase_s["converge"] = conv.seconds
-        core = np.asarray(est_ext[:n], np.int32)
-
-    elif config.mode == "block_gs":
-        from repro.graph.partition import shard_graph
-        sg = shard_graph(g, max(1, config.n_blocks))
-        round_fn = _make_round_block_gs(sg, n_iters)
-        est = jnp.asarray(sg.deg.reshape(-1), jnp.int32)
-        rounds, converged = 0, False
-        with _trace.layer("converge") as conv:
-            while rounds < max_rounds:
-                t_r = time.perf_counter() if rec.active else 0.0
-                with _trace.span("kcore.round", round=rounds):
-                    new_est, changed = round_fn(est)
-                    rounds += 1
-                    ch_real = np.asarray(changed)[: g.n]
-                    if not ch_real.any():
-                        converged = True
-                        break
-                    msgs.append(int(deg64[ch_real].sum()))
-                    changed_counts.append(int(ch_real.sum()))
-                    active.append(int(_receivers_np(g, ch_real).sum()))
-                    if rec.active:
-                        rec.record_round(
-                            active[rounds], msgs[-1], changed_counts[-1],
-                            est=np.asarray(new_est)[: g.n],
-                            prev_est=np.asarray(est)[: g.n],
-                            host_s=time.perf_counter() - t_r,
-                            dispatch=dispatch_kind)
-                    est = new_est
-        phase_s["converge"] = conv.seconds
-        core = np.asarray(est)[: g.n].astype(np.int32)
-
-    else:
-        raise ValueError(f"unsupported combo mode={config.mode} "
-                         f"backend={config.backend}")
+        core = np.asarray(est)[:n].astype(np.int32)
 
     stats = MessageStats(
         messages_per_round=np.asarray(msgs, np.int64),
@@ -616,7 +367,7 @@ def _decompose_body(g: Graph, config: KCoreConfig,
                        stats=stats,
                        recompiles=compile_count() - compiles0,
                        compile_s=compile_seconds() - csecs0,
-                       phase_s=phase_s, dispatch=dispatch_kind,
+                       phase_s=phase_s, dispatch=plan.kind,
                        devices=devices)
 
 
@@ -643,6 +394,28 @@ def _receivers_np(g: Graph, changed: np.ndarray) -> np.ndarray:
 # Sharded superstep (shard_map) — the multi-pod path
 # ---------------------------------------------------------------------- #
 
+def _sharded_round(est, active, src, dst, arc_mask, axes, V, n_iters):
+    """One frontier-masked superstep inside shard_map, on one device's
+    block: ``est``/``active`` (1, V), ``src`` (local ids), ``dst`` (global
+    ids) and ``arc_mask`` its arcs. All-gathers the estimates, runs the
+    binary-search h-index with the local segment sum, then all-gathers the
+    1-bit changed mask to find next round's receivers locally. Returns
+    ``(new, changed, recv)``, each (V,)."""
+    est_l = est[0]
+    est_glob = lax.all_gather(est, axes, axis=0, tiled=True).reshape(-1)
+    est_dst = jnp.where(arc_mask, est_glob[dst], 0)
+    h = _dispatch.hindex_by_bsearch(
+        est_l, _dispatch.arc_hits(est_dst, src, V), n_iters)
+    new_l = jnp.where(active[0], h, est_l)
+    changed_l = new_l < est_l
+    ch_glob = lax.all_gather(changed_l[None], axes, axis=0,
+                             tiled=True).reshape(-1)
+    recv_l = jax.ops.segment_sum(
+        jnp.where(arc_mask, ch_glob[dst], False).astype(jnp.int32),
+        src, num_segments=V) > 0
+    return new_l, changed_l, recv_l
+
+
 @functools.lru_cache(maxsize=128)
 def _masked_sharded_superstep(mesh: jax.sharding.Mesh,
                               axes: tuple, V: int, n_iters: int):
@@ -652,7 +425,8 @@ def _masked_sharded_superstep(mesh: jax.sharding.Mesh,
     shard shapes are stable (the engine pads them to powers of two) reuses
     one compiled program across batches. Same layout contract as
     ``make_sharded_superstep``; on top of the est all_gather a second 1-bit
-    all_gather of the changed mask computes next round's receivers locally.
+    all_gather of the changed mask computes next round's receivers locally
+    (``_sharded_round``).
 
     Returns ``superstep(est, src, dst, arc_mask, deg, active) ->
     (est', changed, recv, msgs)`` with est'/changed/recv sharded like the
@@ -663,18 +437,9 @@ def _masked_sharded_superstep(mesh: jax.sharding.Mesh,
     from repro.distribution.compat import shard_map
 
     def superstep(est, src, dst, arc_mask, deg, active):
-        est_l, act_l = est[0], active[0]
-        est_glob = lax.all_gather(est, axes, axis=0, tiled=True).reshape(-1)
-        est_dst = jnp.where(arc_mask[0], est_glob[dst[0]], 0)
-        h = _hindex_by_bsearch(est_l, est_dst, src[0], V, n_iters)
-        new_l = jnp.where(act_l, h, est_l)
-        changed_l = new_l < est_l
+        new_l, changed_l, recv_l = _sharded_round(
+            est, active, src[0], dst[0], arc_mask[0], axes, V, n_iters)
         msgs = lax.psum(jnp.sum(jnp.where(changed_l, deg[0], 0)), axes)
-        ch_glob = lax.all_gather(changed_l[None], axes, axis=0,
-                                 tiled=True).reshape(-1)
-        recv_l = jax.ops.segment_sum(
-            jnp.where(arc_mask[0], ch_glob[dst[0]], False).astype(jnp.int32),
-            src[0], num_segments=V) > 0
         return new_l[None], changed_l[None], recv_l[None], msgs
 
     spec_state = P(axes)
@@ -720,7 +485,8 @@ def make_sharded_superstep(sg: ShardedGraph, mesh: jax.sharding.Mesh,
         est_l = est[0]
         est_glob = lax.all_gather(est, axes, axis=0, tiled=True).reshape(-1)
         est_dst = jnp.where(arc_mask[0], est_glob[dst[0]], 0)
-        new_l = _hindex_by_bsearch(est_l, est_dst, src[0], V, n_iters)
+        new_l = _dispatch.hindex_by_bsearch(
+            est_l, _dispatch.arc_hits(est_dst, src[0], V), n_iters)
         changed = new_l < est_l
         # int32 is safe per round: messages/round <= 2m < 2^31 for all graphs
         # we target; host-side totals accumulate in int64.

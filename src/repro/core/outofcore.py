@@ -10,7 +10,7 @@ as Gao et al. cycle disk blocks through a small compute tier (PAPERS.md):
     host — O(n) int32/bool, two orders of magnitude below the arc arrays;
   * per round, each block with ≥1 active vertex is materialized (through a
     byte-budgeted LRU ``BlockCache``) and runs ONE masked Jacobi superstep
-    on device: the same ``_hindex_by_bsearch`` program as every other mode,
+    on device: the same ``hindex_by_bsearch`` as every other mode,
     over the block's (V,) vertices and (A,) arcs only;
   * the *halo buffer* is the per-block gather ``est_prev[dst]`` — the
     neighbor estimates a block needs, shipped as one (A,) vector instead of
@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.dispatch import hindex_by_bsearch
 from repro.core.jit_telemetry import compile_count, compile_seconds
 from repro.core.kcore import KCoreResult, _bs_iters
 from repro.core.messages import MessageStats
@@ -101,37 +102,37 @@ class OutOfCoreResult(KCoreResult):
     block_stats: OutOfCoreStats | None = None
 
 
+def row_offset_hits(est_dst, src_local, row_off):
+    """``count_hits`` (``dispatch.hindex_by_bsearch``) over a block's
+    src-sorted arcs: a cumsum of the hits, differenced at the rows'
+    offsets — an exact integer rewrite of the segment sum that sidesteps
+    XLA's serialized scatter-add on CPU (~8x per superstep)."""
+
+    def count_hits(mid):
+        hit = (est_dst >= mid[src_local]) & (mid[src_local] > 0)
+        c = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                             jnp.cumsum(hit.astype(jnp.int32))])
+        return c[row_off[1:]] - c[row_off[:-1]]
+
+    return count_hits
+
+
 @functools.partial(jax.jit, static_argnames=("n_iters",))
 def _block_superstep(est_u, est_dst_masked, src_local, row_off, active,
                      n_iters):
     """One masked Jacobi superstep over a single resident block.
 
-    Identical math to ``kcore._masked_round`` restricted to the block: the
+    The in-memory round (``dispatch._round``) restricted to the block: the
     caller pre-gathers the halo ``est_dst_masked = where(mask, est_prev[dst],
     0)`` on the host, so the device only ever sees (V,) vertex state and
-    (A,) arc state. Because a block's arcs are src-sorted the per-vertex
-    hit counts inside the h-index binary search come from a cumsum +
-    row-offset difference instead of ``segment_sum`` — an exact integer
-    rewrite that sidesteps XLA's serialized scatter-add on CPU (~8x per
-    superstep). Arc inputs arrive sliced to the block's pow2 LENGTH BUCKET
-    (not the store-wide max A), so the straggler block no longer inflates
-    every other block's arc slots; the bucket count bounds the number of
-    compiled shapes at ~log2(A).
+    (A,) arc state, and the binary search counts hits by
+    ``row_offset_hits``. Arc inputs arrive sliced to the block's pow2
+    LENGTH BUCKET (not the store-wide max A), so the straggler block no
+    longer inflates every other block's arc slots; the bucket count bounds
+    the number of compiled shapes at ~log2(A).
     """
-    lo = jnp.zeros_like(est_u)
-    hi = est_u
-
-    def body(lohi, _):
-        lo, hi = lohi
-        mid = (lo + hi + 1) // 2
-        hit = (est_dst_masked >= mid[src_local]) & (mid[src_local] > 0)
-        c = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                             jnp.cumsum(hit.astype(jnp.int32))])
-        cnt = c[row_off[1:]] - c[row_off[:-1]]
-        ok = cnt >= mid
-        return (jnp.where(ok, mid, lo), jnp.where(ok, hi, mid - 1)), None
-
-    (h, _), _ = jax.lax.scan(body, (lo, hi), None, length=n_iters)
+    h = hindex_by_bsearch(
+        est_u, row_offset_hits(est_dst_masked, src_local, row_off), n_iters)
     new = jnp.where(active, h, est_u)
     return new, new < est_u
 

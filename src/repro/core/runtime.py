@@ -1,11 +1,12 @@
 """Shared fused convergence runtime — one layer, both engines.
 
-The device-resident ``lax.while_loop`` programs (``core.kcore.fused_convergence``
-and its nested-shard_map sibling) were born in the streaming engine (ISSUE 4);
-this module lifts their host-side orchestration — staging/padding inputs,
-dispatching the right fused program, reconstructing exact per-round
-``MessageStats`` arrays from the device stat buffers — into a runtime that
-BOTH engines call:
+The device-resident ``lax.while_loop`` programs
+(``core.dispatch.fused_convergence_program`` and its nested-shard_map
+sibling, both iterating ``dispatch.converge_loop``) were born in the
+streaming engine; this module holds their host-side orchestration —
+staging/padding inputs, dispatching the right fused program,
+reconstructing exact per-round ``MessageStats`` arrays from the device
+stat buffers — in a runtime that BOTH engines call:
 
 * ``kcore_decompose(..., fused=True)`` / ``kcore_decompose_sharded(...,
   fused=True)`` run the paper's from-scratch decomposition as one jitted
@@ -44,11 +45,7 @@ import numpy as np
 from repro.core import dispatch as _dispatch
 from repro.core.dispatch import to_device
 from repro.core.jit_telemetry import compile_count, compile_seconds
-from repro.core.kcore import (
-    _fused_sharded_convergence,
-    fused_convergence,
-    fused_round_stats,
-)
+from repro.core.kcore import _fused_sharded_convergence, fused_round_stats
 from repro.obs import flight, trace
 
 
@@ -175,14 +172,15 @@ def fused_converge_dense(
     streaming engine passes its pow2 high-water padded CSR slots, the static
     engine the plain sorted-COO arrays (every arc live).
 
-    ``dispatch`` picks the superstep implementation inside the while_loop
+    ``dispatch`` picks the plan of the round body inside the while_loop
     (``repro.core.dispatch``): None/"auto" consults the platform layer
     (``REPRO_PALLAS``), "pallas"/"xla" force it. With the Pallas plan the
     per-round reductions run through the kernels package — and through the
     ``kcore_hindex`` ELL kernel when the caller passes the static
     degree-bucketed ``ell`` layout (from-scratch decompositions only; the
     streaming engine's masked slot arrays stay on the segment-sum route).
-    Accounting is bit-equal across every dispatch choice.
+    Both plans go through ``dispatch.fused_convergence_program``;
+    accounting is bit-equal across them.
     """
     compiles0, csecs0 = compile_count(), compile_seconds()
     plan = _dispatch.resolve_plan(dispatch)
@@ -199,38 +197,16 @@ def fused_converge_dense(
         seed_np = np.asarray(seed, np.int64).copy()
     with trace.layer("fused-converge", n=n, max_rounds=max_rounds, dispatch=plan.kind) as span:
         with trace.layer("device-converge") as dev:
-            if plan.kind == "pallas":
-                # the graph operands are staged in their own ``stage`` span
-                prog = _dispatch.fused_convergence_program(
-                    n,
-                    n_iters,
-                    max_rounds,
-                    plan,
-                    np.asarray(src, np.int32),
-                    np.asarray(dst, np.int32),
-                    ell=ell,
+            # the graph operands are staged in their own ``stage`` span
+            prog = _dispatch.fused_convergence_program(n, n_iters, max_rounds, plan, src, dst, ell=ell)
+            with trace.layer("stage", h2d_bytes=0) as st:
+                args = (
+                    to_device(st, seed, jnp.int32),
+                    to_device(st, arc_mask),
+                    to_device(st, active),
+                    to_device(st, deg, jnp.int32),
                 )
-                row_hit_steps = _dispatch.row_hit_steps(prog.operands, n_iters)
-                with trace.layer("stage", h2d_bytes=0) as st:
-                    args = (
-                        to_device(st, seed, jnp.int32),
-                        to_device(st, arc_mask),
-                        to_device(st, active),
-                        to_device(st, deg, jnp.int32),
-                    )
-                out = _device_loop(prog, *args)
-            else:
-                row_hit_steps = 0
-                with trace.layer("stage", h2d_bytes=0) as st:
-                    args = (
-                        to_device(st, seed, jnp.int32),
-                        to_device(st, src, jnp.int32),
-                        to_device(st, dst, jnp.int32),
-                        to_device(st, arc_mask),
-                        to_device(st, active),
-                        to_device(st, deg, jnp.int32),
-                    )
-                out = _device_loop(fused_convergence, *args, n=n, n_iters=n_iters, max_rounds=max_rounds)
+            out = _device_loop(prog, *args)
         return _finish(
             span,
             out,
@@ -241,7 +217,7 @@ def fused_converge_dense(
             dispatch=plan.kind,
             frontier1=frontier1,
             seed=seed_np,
-            row_hit_steps=row_hit_steps,
+            row_hit_steps=_dispatch.row_hit_steps(prog.operands, n_iters),
         )
 
 

@@ -27,7 +27,7 @@ def _pick_row_tile(width: int) -> int:
 def hindex_rows(nbr_est, est_u, n_iters: int):
     """Rowwise clipped h-index. nbr_est (R, W) int32, est_u (R,) int32 → (R,).
 
-    Drop-in replacement for core.kcore.hindex_rows_ref.
+    Checked against the sort-based oracle ``ref.hindex_rows_ref``.
     """
     rows, width = nbr_est.shape
     tile = _pick_row_tile(width)

@@ -40,7 +40,6 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--n", type=int, default=1000)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--mode", default="jacobi", choices=["jacobi", "block_gs"])
-    ap.add_argument("--backend", default="segment", choices=["segment", "ell", "ell_pallas"])
     ap.add_argument(
         "--fused",
         action="store_true",
@@ -141,13 +140,12 @@ def parse_args(argv=None) -> argparse.Namespace:
     args = ap.parse_args(argv)
     if args.metrics_out:
         args.metrics = True
-    if args.mesh and (args.mode != "jacobi" or args.backend != "segment"):
-        # the sharded engine is jacobi/segment only; refuse rather than
-        # silently running (and reporting) a different mode than asked
-        ap.error("--mesh supports --mode jacobi --backend segment only")
-    if args.out_of_core and (args.mesh or args.fused or args.mode != "jacobi"
-                             or args.backend != "segment"):
-        ap.error("--out-of-core is its own engine: jacobi/segment only, "
+    if args.mesh and args.mode != "jacobi":
+        # the sharded engine is jacobi only; refuse rather than silently
+        # running (and reporting) a different mode than asked
+        ap.error("--mesh supports --mode jacobi only")
+    if args.out_of_core and (args.mesh or args.fused or args.mode != "jacobi"):
+        ap.error("--out-of-core is its own engine: jacobi only, "
                  "no --mesh/--fused")
     if (args.mem_budget or args.blocks) and not args.out_of_core:
         ap.error("--mem-budget/--blocks require --out-of-core")
@@ -179,7 +177,7 @@ def decompose(args, g):
         mesh = make_mesh((args.mesh,), ("data",))
         res = kcore_decompose_sharded(g, mesh, ("data",), fused=args.fused)
     else:
-        config = KCoreConfig(mode=args.mode, backend=args.backend)
+        config = KCoreConfig(mode=args.mode)
         res = kcore_decompose(g, config, fused=args.fused)
     return res, time.perf_counter() - t0
 
@@ -202,7 +200,6 @@ def report(args, g, res, wall: float, oracle) -> dict:
         "max_deg": g.max_deg,
         "max_core": int(res.core.max()) if g.n else 0,
         "mode": args.mode,
-        "backend": args.backend,
         "fused": args.fused,
         "dispatch": res.dispatch,
         "mesh": args.mesh or 1,

@@ -12,10 +12,10 @@ unbounded growth.
 
 Capture points (all guarded by ``recorder().active`` — see below):
 
-* the static host round loops (``core/kcore.py``: segment / ell / block_gs
-  backends and the sharded superstep loop) record ONLINE, one record per
-  productive round, with an exact per-round estimate-decrease histogram
-  computed from the host estimate vectors;
+* the static host round loops (``core/kcore.py``: jacobi on either
+  dispatch plan, block_gs, and the sharded superstep loop) record ONLINE,
+  one record per productive round, with an exact per-round
+  estimate-decrease histogram computed from the host estimate vectors;
 * the fused while_loop modes record POST-HOC from the device stat buffers
   (``core/runtime.py`` — the single layer every fused path flows through):
   per-round messages/changed/frontier are bit-equal to the host loops by
@@ -91,7 +91,7 @@ class FlightRecord:
     seq: int                # monotone over the recorder's lifetime
     run: int                # run id (one run = one convergence / batch)
     engine: str             # "static" | "streaming" | "temporal" | ...
-    mode: str               # execution mode ("jacobi/segment", "fused", ...)
+    mode: str               # execution mode ("jacobi/xla", "fused", ...)
     batch: int | None       # batch / window-step id, None for static runs
     round: int              # accounting round index (0 = seed broadcast)
     frontier: int           # accounting active count this round

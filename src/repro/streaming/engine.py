@@ -40,8 +40,9 @@ the from-scratch total the paper reports.
 
 Four frontier execution modes (plus ``auto``, which picks per batch):
 
-  * ``dense``   — full-width jitted masked superstep (core.masked_round_segment):
-    one XLA program for the whole stream, frontier as a boolean mask;
+  * ``dense``   — full-width jitted masked superstep
+    (core.dispatch.masked_round_program): one program for the whole
+    stream, frontier as a boolean mask;
   * ``compact`` — per-round extraction of the active subgraph, padded to
     powers of two so jit recompiles only O(log n) distinct shapes; work per
     round is proportional to the frontier, not the graph;
@@ -51,11 +52,11 @@ Four frontier execution modes (plus ``auto``, which picks per batch):
     all_gather per round. The in-place CSR's slot arrays are already
     src-sorted, so sharding a churned graph needs no sort.
   * ``fused``   — the ENTIRE batch re-convergence runs as one device-resident
-    ``lax.while_loop`` (core.fused_convergence): no per-round host
-    round-trips; the host gets back only the final estimate plus per-round
-    stat buffers from which exact MessageStats are reconstructed. With a
-    mesh attached the while_loop nests the masked shard_map superstep
-    (``fused_sharded``). All fused-program shapes are high-water-marked
+    ``lax.while_loop`` (core.dispatch.fused_convergence_program): no
+    per-round host round-trips; the host gets back only the final
+    estimate plus per-round stat buffers from which exact MessageStats
+    are reconstructed. With a mesh attached the while_loop nests the
+    masked shard_map superstep (``fused_sharded``). All fused-program shapes are high-water-marked
     (CSR capacity, shard arc blocks, h-index search depth) so a whole
     windowed replay compiles O(log) distinct jit signatures — measured,
     not asserted, via repro.core.jit_telemetry (``BatchResult.recompiles``).
@@ -78,10 +79,9 @@ from jax import lax
 from repro.core import dispatch as _dispatch
 from repro.core.cost_model import SeedCostModel, choose_seed
 from repro.core.jit_telemetry import compile_count, compile_seconds
-from repro.core.kcore import (KCoreConfig, _bs_iters, _hindex_by_bsearch,
-                              _receivers_arrays, kcore_decompose,
-                              kcore_decompose_sharded,
-                              make_sharded_superstep, masked_round_segment)
+from repro.core.kcore import (KCoreConfig, _bs_iters, _receivers_arrays,
+                              kcore_decompose, kcore_decompose_sharded,
+                              make_sharded_superstep)
 from repro.core.messages import MessageStats
 from repro.core.runtime import fused_converge_dense, fused_converge_sharded
 from repro.graph.padding import next_pow2 as _next_pow2
@@ -453,7 +453,8 @@ def warm_start_seed(new_g: Graph, old_core: np.ndarray,
 @functools.partial(jax.jit, static_argnames=("n", "n_iters"))
 def _compact_kernel(est_u, est_dst_masked, src, n, n_iters):
     """h-index over a pre-gathered compact frontier subproblem."""
-    new = _hindex_by_bsearch(est_u, est_dst_masked, src, n, n_iters)
+    new = _dispatch.hindex_by_bsearch(
+        est_u, _dispatch.arc_hits(est_dst_masked, src, n), n_iters)
     return new, new < est_u
 
 
@@ -661,32 +662,18 @@ class StreamingKCoreEngine:
 
         if mode == "dense":
             src_p, dst_p, amask_p = self._padded_slots()
-            plan = _dispatch.resolve_plan()
-            if plan.kind == "pallas":
-                # segment-sum route only (ell=None): the slot arrays are
-                # masked/mutable, not a static fully-live adjacency. Arc
-                # contents are baked into the program — a churning stream
-                # re-stages per batch (the documented REPRO_PALLAS=on cost).
-                prog = _dispatch.masked_round_program(
-                    n, n_iters, plan,
-                    np.asarray(src_p, np.int32), np.asarray(dst_p, np.int32))
-                amask_j = jnp.asarray(amask_p)
-
-                def step(est, active):
-                    new_j, ch_j, recv_j = prog(
-                        jnp.asarray(est), amask_j, jnp.asarray(active))
-                    return new_j, np.asarray(ch_j), np.asarray(recv_j)
-
-                return step
-            src_j, dst_j, amask_j = (jnp.asarray(a) for a in
-                                     (src_p, dst_p, amask_p))
+            # segment-sum route only (ell=None): the slot arrays are
+            # masked/mutable, not a static fully-live adjacency; the
+            # operands are re-staged per batch
+            prog = _dispatch.masked_round_program(
+                n, n_iters, _dispatch.resolve_plan(), src_p, dst_p)
+            amask_j = jnp.asarray(amask_p)
 
             def step(est, active):
                 # est stays device-resident across rounds (the loop treats
                 # it opaquely); only the small bool masks come back to host
-                new_j, ch_j, recv_j = masked_round_segment(
-                    jnp.asarray(est), src_j, dst_j, amask_j,
-                    jnp.asarray(active), n, n_iters)
+                new_j, ch_j, recv_j = prog(
+                    jnp.asarray(est), amask_j, jnp.asarray(active))
                 return new_j, np.asarray(ch_j), np.asarray(recv_j)
 
             return step

@@ -32,14 +32,17 @@ def test_engine_matches_bz(family, kw, seed):
     assert (res.core == bz_core_numbers(g)).all()
 
 
-@pytest.mark.parametrize("mode,backend", [
-    ("jacobi", "segment"), ("jacobi", "ell"), ("jacobi", "ell_pallas"),
-    ("block_gs", "segment"),
-])
-def test_all_backends_agree(mode, backend):
+@pytest.mark.parametrize("mode,dispatch,fused", [
+    ("jacobi", "xla", False), ("jacobi", "pallas", False),
+    ("jacobi", "xla", True), ("block_gs", "xla", False),
+], ids=["jacobi/xla/host", "jacobi/pallas/host", "jacobi/xla/fused",
+        "block_gs/xla/host"])
+def test_all_backends_agree(mode, dispatch, fused):
     g = gen.barabasi_albert(300, 4, seed=3)
-    res = kcore_decompose(g, KCoreConfig(mode=mode, backend=backend))
-    assert (res.core == bz_core_numbers(g)).all(), (mode, backend)
+    res = kcore_decompose(g, KCoreConfig(mode=mode, dispatch=dispatch),
+                          fused=fused)
+    assert res.dispatch == dispatch
+    assert (res.core == bz_core_numbers(g)).all(), (mode, dispatch, fused)
 
 
 def test_structured_graphs():

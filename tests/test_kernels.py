@@ -72,7 +72,8 @@ def _ell_round_all(g, est, n_iters, hindex_fn):
 def test_ell_hindex_pallas_vs_ref_vs_segment(n, e, seed):
     """Pallas ELL kernel == sort-identity oracle == XLA segment-op binary
     search, on random ragged graphs with arbitrary (deg-0-zeroed) ests."""
-    from repro.core.kcore import _bs_iters, _hindex_by_bsearch
+    from repro.core.dispatch import arc_hits, hindex_by_bsearch
+    from repro.core.kcore import _bs_iters
     from repro.graph.structs import Graph
 
     r = np.random.default_rng(seed)
@@ -89,8 +90,9 @@ def test_ell_hindex_pallas_vs_ref_vs_segment(n, e, seed):
     got_ref = _ell_round_all(
         g, est, n_iters, lambda nbr, eu, it: hindex_rows_ref(nbr, eu, it))
     est_j = jnp.asarray(est)
-    seg = np.asarray(_hindex_by_bsearch(
-        est_j, est_j[jnp.asarray(g.dst)], jnp.asarray(g.src), g.n, n_iters))
+    seg = np.asarray(hindex_by_bsearch(
+        est_j, arc_hits(est_j[jnp.asarray(g.dst)], jnp.asarray(g.src), g.n),
+        n_iters))
     np.testing.assert_array_equal(got_pallas, got_ref)
     np.testing.assert_array_equal(got_pallas, seg)
 
@@ -99,7 +101,8 @@ def test_ell_hindex_pow2_boundary_and_empty_rows():
     """Deterministic edge cases: a star whose hub degree sits exactly ON a
     pow2 bucket width (8), leaf count NOT a row_multiple multiple (so the
     leaf bucket carries sentinel-padded rows), plus isolated vertices."""
-    from repro.core.kcore import _bs_iters, _hindex_by_bsearch
+    from repro.core.dispatch import arc_hits, hindex_by_bsearch
+    from repro.core.kcore import _bs_iters
     from repro.graph.structs import Graph, build_ell
 
     # hub 0 -- leaves 1..8 (deg 8 == bucket width), 9..11 isolated
@@ -115,10 +118,93 @@ def test_ell_hindex_pow2_boundary_and_empty_rows():
         g, est, n_iters,
         lambda nbr, eu, it: hindex_rows(nbr, eu, n_iters=it))
     est_j = jnp.asarray(est)
-    seg = np.asarray(_hindex_by_bsearch(
-        est_j, est_j[jnp.asarray(g.dst)], jnp.asarray(g.src), g.n, n_iters))
+    seg = np.asarray(hindex_by_bsearch(
+        est_j, arc_hits(est_j[jnp.asarray(g.dst)], jnp.asarray(g.src), g.n),
+        n_iters))
     np.testing.assert_array_equal(got, seg)
     assert (got[9:] == 0).all()          # isolated vertices stay 0
+
+
+# ------------- one binary search, every hit counter ------------------ #
+#
+# ``dispatch.hindex_by_bsearch`` is the package's one binary-search
+# h-index; its callers differ only in how they count a probe's hits. Each
+# counter, fed to it, must give the sort-identity oracle's h-index.
+
+def _ragged_graphs():
+    """Random graphs with a hub, isolated vertices and rows spread over
+    several 128-row blocks."""
+    from repro.graph.structs import Graph
+
+    r = np.random.default_rng(5)
+    for n, e in ((40, 90), (300, 900), (260, 400)):
+        hub = r.integers(1, n, n // 2)
+        edges = np.concatenate([r.integers(0, n, (e, 2)),
+                                np.stack([np.zeros_like(hub), hub], 1)])
+        yield Graph.from_edges(edges, n=n + 5)
+
+
+def _bsearch_with(counter, g, est, n_iters):
+    """The h-index of every vertex of g from ``est`` by the one binary
+    search, its hits counted the way ``counter`` names."""
+    from repro.core.dispatch import arc_hits, hindex_by_bsearch
+    from repro.core.kcore import make_sharded_superstep
+    from repro.core.outofcore import row_offset_hits
+    from repro.distribution.compat import make_mesh
+    from repro.graph.partition import shard_graph
+    from repro.kernels.segment_sum.ops import row_hits_arrays, to_slots
+
+    n, est_dst = g.n, est[g.dst]
+    if counter == "shard_local":
+        # the masked shard_map superstep, everyone active, on a 1-device mesh
+        sg = shard_graph(g, 1)
+        step, _ = make_sharded_superstep(sg, make_mesh((1,), ("data",)),
+                                         ("data",), n_iters, masked=True)
+        est_p = np.zeros((1, sg.verts_per_shard), np.int32)
+        est_p[0, :n] = est
+        new, _, _, _ = step(jnp.asarray(est_p), jnp.asarray(sg.src),
+                            jnp.asarray(sg.dst), jnp.asarray(sg.arc_mask),
+                            jnp.asarray(sg.deg), jnp.ones_like(est_p, bool))
+        return np.asarray(new)[0, :n]
+    if counter == "segment_sum":
+        # arcs in any order
+        perm = np.random.default_rng(1).permutation(g.num_arcs)
+        hits = arc_hits(jnp.asarray(est_dst[perm]), jnp.asarray(g.src[perm]), n)
+    elif counter == "row_hits":
+        lo = blocked_layout(g.src, n, R=128, be=128)
+        slots = jnp.asarray(to_slots(est_dst, lo.slot_edge, 0))
+
+        def hits(mid):
+            return row_hits_arrays(slots, mid, jnp.asarray(lo.rows_local),
+                                   jnp.asarray(lo.block_row), R=lo.R,
+                                   n_rows_pad=lo.n_rows_pad, n_rows=n)
+    else:
+        hits = row_offset_hits(jnp.asarray(est_dst), jnp.asarray(g.src),
+                               jnp.asarray(g.offsets))
+    return np.asarray(hindex_by_bsearch(jnp.asarray(est), hits, n_iters))
+
+
+@pytest.mark.parametrize("counter", ["segment_sum", "row_hits",
+                                     "row_offsets", "shard_local"])
+def test_every_hit_counter_gives_the_oracles_hindex(counter):
+    """The XLA segment sum (arcs in any order), the ``row_hits`` kernel on
+    a blocked layout (interpret mode), a cumsum differenced at the CSR row
+    offsets (the out-of-core block), and the shard-local segment sum on a
+    1-device mesh: each counter fed to ``hindex_by_bsearch`` gives every
+    vertex the sort-identity h-index of min(est[v], est[u]) over its
+    neighbours, estimates above and below the degrees alike."""
+    from repro.core.kcore import _bs_iters
+
+    r = np.random.default_rng(11)
+    for g in _ragged_graphs():
+        est = r.integers(0, 2 * g.max_deg + 2, g.n).astype(np.int32)
+        nbr = np.zeros((g.n, g.max_deg), np.int32)   # sentinel slots hold 0
+        for u in range(g.n):
+            nbr[u, :g.deg[u]] = est[g.dst[g.offsets[u]:g.offsets[u + 1]]]
+        want = np.asarray(hindex_rows_ref(jnp.asarray(nbr), jnp.asarray(est)))
+        got = _bsearch_with(counter, g, est, _bs_iters(int(est.max())))
+        np.testing.assert_array_equal(got, want)
+        assert (want[g.deg == 0] == 0).all() and want.max() > 1
 
 
 @settings(max_examples=20, deadline=None)

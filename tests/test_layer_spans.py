@@ -175,15 +175,18 @@ def test_stage_counts_the_bytes_it_copies_to_the_device(ring):
     active, mask = np.ones(n, bool), np.ones(A, bool)
     kw = dict(n=n, n_iters=_bs_iters(g.max_deg), max_rounds=n + 1, dispatch="xla")
     fused_converge_dense(seed, active, g.src, g.dst, mask, g.deg, **kw)
-    (st,) = [s for s in trace.recent_layers() if s.name == "stage"]
-    # seed, deg (int32, n); src, dst (int32, A); mask (bool, A); active (bool, n)
-    assert st.attrs["h2d_bytes"] == 4 * n * 2 + 4 * A * 2 + A + n
+    # the program's graph operands, then the call's arguments
+    ops_st, args_st = [s for s in trace.recent_layers() if s.name == "stage"]
+    assert ops_st.attrs["h2d_bytes"] == 4 * A * 2                 # src, dst (int32, A)
+    # seed, deg (int32, n); mask (bool, A); active (bool, n)
+    assert args_st.attrs["h2d_bytes"] == 4 * n * 2 + A + n
     # arrays already on the device cost nothing
     trace.reset()
     on_dev = [jnp.asarray(a) for a in (seed, g.src, g.dst, mask)]
     fused_converge_dense(on_dev[0], active, on_dev[1], on_dev[2], on_dev[3], g.deg, **kw)
-    (st,) = [s for s in trace.recent_layers() if s.name == "stage"]
-    assert st.attrs["h2d_bytes"] == n + 4 * n                     # active, deg
+    ops_st, args_st = [s for s in trace.recent_layers() if s.name == "stage"]
+    assert ops_st.attrs["h2d_bytes"] == 0
+    assert args_st.attrs["h2d_bytes"] == n + 4 * n                # active, deg
     # the graph operands of a dispatched program: the sum over their leaves
     trace.reset()
     ops, _layout = dispatch._stage(dispatch.DispatchPlan("pallas"), g.src, g.dst, n, None)

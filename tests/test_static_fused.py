@@ -2,7 +2,7 @@
 the paper's from-scratch decomposition as one device-resident while_loop
 through the shared runtime (core/runtime.py) — must be EXACT-equal to the
 host round loop in cores AND per-round accounting (messages / active /
-changed per round, round count, convergence flag), on every backend config,
+changed per round, round count, convergence flag), on both dispatch plans,
 with a max_rounds cap, and through the sharded variant."""
 
 import numpy as np
@@ -57,12 +57,14 @@ def test_static_fused_via_config_flag():
 
 
 def test_static_fused_backend_configs_identical():
-    """The fused runtime is backend-independent (it always stages the
-    segment arrays); every backend's host loop must match it bit-exactly."""
+    """The host loop on either dispatch plan (the XLA segment sums, or the
+    Pallas ELL h-index and blocked segment sum) matches the fused loop
+    bit-exactly."""
     g = gen.barabasi_albert(150, 3, seed=2)
     fus = kcore_decompose(g, fused=True)
-    for backend in ("segment", "ell"):
-        host = kcore_decompose(g, KCoreConfig(backend=backend))
+    for plan in ("xla", "pallas"):
+        host = kcore_decompose(g, KCoreConfig(dispatch=plan))
+        assert host.dispatch == plan
         assert_result_equal(host, fus)
 
 

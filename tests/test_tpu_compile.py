@@ -142,11 +142,17 @@ def test_pallas_fused_convergence_compiles(one_chip, for_tpu):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
-def test_kernels_and_round_scopes_keep_their_names_on_tpu(one_chip, for_tpu):
+@pytest.mark.parametrize("route,buckets,kernels", [
+    ("ell", ((8, 2000), (128, 100)), {"%kcore_hindex", "%segment_sum"}),
+    ("slots", (), {"%row_hits", "%segment_sum"}),
+], ids=["ell", "slots"])
+def test_kernels_and_round_scopes_keep_their_names_on_tpu(one_chip, for_tpu, route, buckets, kernels):
     """The trace names each kernel call after its ``pallas_call`` and each
-    part of the round after its ``jax.named_scope``: the h-index kernel is
-    ``kcore_hindex.N``, the segment sum ``segment_sum.N``, and every scope
-    of the round body is in the ops' metadata."""
+    part of the round after its ``jax.named_scope``: on the ELL route (a
+    decomposition) the h-index kernel is ``kcore_hindex.N``, on the slot
+    route (a churn batch) the binary search's hit count is ``row_hits.N``,
+    the receivers' count is ``segment_sum.N`` on both, and every scope of
+    the fused program is in the ops' metadata."""
     n, arcs = 1 << 12, 1 << 15
     e_pad = arcs + n
     S = lambda shape, dtype=jnp.int32: _spec(one_chip, shape, dtype)  # noqa: E731
@@ -154,7 +160,7 @@ def test_kernels_and_round_scopes_keep_their_names_on_tpu(one_chip, for_tpu):
         S((e_pad,)),
         S((e_pad,)),
         (S((e_pad // 2048, 16, 128)), S((e_pad // 2048, 16, 128)), S((e_pad // 2048,))),
-        tuple((S((rows,)), S((rows, w))) for w, rows in ((8, 2000), (128, 100))),
+        tuple((S((rows,)), S((rows, w))) for w, rows in buckets),
     )
     text = dispatch._fused_jit.lower(
         ops, S((n,)), S((arcs,), jnp.bool_), S((n,), jnp.bool_), S((n,)),
@@ -162,9 +168,9 @@ def test_kernels_and_round_scopes_keep_their_names_on_tpu(one_chip, for_tpu):
     ).compile().as_text()
     calls = [ln.strip().split(" = ", 1)[0] for ln in text.splitlines()
              if "tpu_custom_call" in ln and " = " in ln]
-    assert calls and all(c.startswith(("%kcore_hindex.", "%segment_sum.")) for c in calls)
-    assert {c.split(".")[0] for c in calls} == {"%kcore_hindex", "%segment_sum"}
-    for scope in ("kcore.gather", "kcore.hindex", "kcore.changed", "kcore.recv", "kcore.stats"):
+    assert {c.split(".")[0] for c in calls} == kernels, calls
+    for scope in ("kcore.mask", "kcore.gather", "kcore.hindex", "kcore.changed", "kcore.recv",
+                  "kcore.stats"):
         assert f"/{scope}/" in text, scope
 
 
