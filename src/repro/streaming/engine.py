@@ -176,7 +176,8 @@ def _ub_pass_body(U, cap, src, dst, live, ins_u, ins_v, ins_live, n):
     """One vectorized +1 pass of the insertion upper bound (see below).
 
     All device-side segment ops; dead/padding arc slots carry live=False.
-    Returns (U', raised_any).
+    Returns (U', raised_any, prop_sweeps, peel_sweeps), the last two the
+    sweeps of the pass's two inner loops.
 
       1. bottleneck propagation: T(x) = max over paths from x to an
          inserted-edge endpoint of min(k_e, min U over the path) — the
@@ -190,31 +191,43 @@ def _ub_pass_body(U, cap, src, dst, live, ins_u, ins_v, ins_live, n):
          or sit strictly above it. (Peeling order never changes the
          greatest fixpoint, so the parallel peel equals the sequential
          stack peel of the reference implementation.)
+
+    Each sweep (scopes ``ub.prop``, ``ub.peel``) gathers one vertex-sized
+    int32 table over the arc slots: the propagation min(U, T)[dst], the
+    peel K[dst] with K = 2U + c, against lim = 2U[src] made once a pass
+    (INT32_MAX on dead slots, which so never qualify). That is exact:
+    K(y) > 2U(x) iff U(y) > U(x), or U(y) == U(x) and c(y). U never
+    exceeds the largest degree (< 2^30), so 2U + 1 fits in int32.
     """
     k_ins = jnp.where(ins_live, jnp.minimum(U[ins_u], U[ins_v]),
                       jnp.int32(-1))
     A = jnp.full(n, -1, jnp.int32).at[ins_u].max(k_ins).at[ins_v].max(k_ins)
 
     def prop_body(state):
-        T, _ = state
-        val = jnp.where(live, jnp.minimum(U[dst], T[dst]), jnp.int32(-1))
-        T2 = jnp.maximum(T, jax.ops.segment_max(val, src, num_segments=n))
-        return T2, (T2 > T).any()
+        T, _, sweeps = state
+        with jax.named_scope("ub.prop"):
+            val = jnp.where(live, jnp.minimum(U, T)[dst], jnp.int32(-1))
+            T2 = jnp.maximum(T, jax.ops.segment_max(val, src, num_segments=n))
+        return T2, (T2 > T).any(), sweeps + 1
 
-    T, _ = lax.while_loop(lambda s: s[1], prop_body, (A, jnp.bool_(True)))
+    T, _, prop_sweeps = lax.while_loop(
+        lambda s: s[1], prop_body, (A, jnp.bool_(True), jnp.int32(0)))
 
     cand0 = (T >= U) & (cap > U)
+    lim = jnp.where(live, 2 * U[src], jnp.iinfo(jnp.int32).max)
 
     def peel_body(state):
-        c, _ = state
-        qual = live & ((U[dst] > U[src]) | (c[dst] & (U[dst] == U[src])))
-        s = jax.ops.segment_sum(qual.astype(jnp.int32), src, num_segments=n)
+        c, _, sweeps = state
+        with jax.named_scope("ub.peel"):
+            qual = (2 * U + c.astype(jnp.int32))[dst] > lim
+            s = jax.ops.segment_sum(qual.astype(jnp.int32), src,
+                                    num_segments=n)
         c2 = c & (s > U)
-        return c2, (c2 != c).any()
+        return c2, (c2 != c).any(), sweeps + 1
 
-    cand, _ = lax.while_loop(lambda s: s[1], peel_body,
-                             (cand0, jnp.bool_(True)))
-    return jnp.where(cand, U + 1, U), cand.any()
+    cand, _, peel_sweeps = lax.while_loop(
+        lambda s: s[1], peel_body, (cand0, jnp.bool_(True), jnp.int32(0)))
+    return jnp.where(cand, U + 1, U), cand.any(), prop_sweeps, peel_sweeps
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
@@ -235,18 +248,20 @@ def _ub_converge(U, cap, src, dst, live, ins_u, ins_v, ins_live, n):
     the identical ``_ub_pass_body``, so the resulting U is unchanged
     (property-tested against the union-find reference).
 
-    Returns (U, passes): ``passes`` counts every pass run, the last one
-    (which raises nothing) included — as many as the host loop of
-    ``_ub_pass`` calls would make."""
+    Returns (U, passes, prop_sweeps, peel_sweeps): ``passes`` counts every
+    pass run, the last one (which raises nothing) included — as many as
+    the host loop of ``_ub_pass`` calls would make — and the sweeps are
+    summed over them."""
     def pass_body(state):
-        U, _, passes = state
-        U, raised = _ub_pass_body(U, cap, src, dst, live, ins_u, ins_v,
-                                  ins_live, n)
-        return U, raised, passes + 1
+        U, _, passes, prop, peel = state
+        U, raised, p, q = _ub_pass_body(U, cap, src, dst, live, ins_u, ins_v,
+                                        ins_live, n)
+        return U, raised, passes + 1, prop + p, peel + q
 
-    U, _, passes = lax.while_loop(lambda s: s[1], pass_body,
-                                  (U, jnp.bool_(True), jnp.int32(0)))
-    return U, passes
+    zero = jnp.int32(0)
+    U, _, passes, prop, peel = lax.while_loop(
+        lambda s: s[1], pass_body, (U, jnp.bool_(True), zero, zero, zero))
+    return U, passes, prop, peel
 
 
 def _insertion_upper_bound_arrays(n: int, src, dst, live, deg,
@@ -260,10 +275,11 @@ def _insertion_upper_bound_arrays(n: int, src, dst, live, deg,
     across batches (pow2-padded) so the jitted pass compiles O(log) times.
     Returns (U, passes), ``passes`` as ``_ub_converge`` counts them (0
     when nothing was inserted). Runs as an ``upper-bound`` layer span
-    (attribute ``passes``) whose operand copies are a ``stage`` span
-    counting ``h2d_bytes``.
+    (attributes ``passes``, ``prop_sweeps``, ``peel_sweeps``) whose
+    operand copies are a ``stage`` span counting ``h2d_bytes``.
     """
-    with _trace.layer("upper-bound", passes=0) as ub:
+    with _trace.layer("upper-bound", passes=0, prop_sweeps=0,
+                      peel_sweeps=0) as ub:
         U = old_core_ext.astype(np.int64).copy()
         if inserted.size == 0 or n == 0:
             return U, 0
@@ -279,8 +295,9 @@ def _insertion_upper_bound_arrays(n: int, src, dst, live, deg,
             args = [_dispatch.to_device(st, a, dt) for a, dt in (
                 (U, jnp.int32), (deg, jnp.int32), (src, None), (dst, None),
                 (live, None), (ins_u, None), (ins_v, None), (ins_live, None))]
-        U_np, passes = jax.device_get(_ub_converge(*args, n=n))
-        ub.set(passes=int(passes))
+        U_np, passes, prop, peel = jax.device_get(_ub_converge(*args, n=n))
+        ub.set(passes=int(passes), prop_sweeps=int(prop),
+               peel_sweeps=int(peel))
         return U_np.astype(np.int64), int(passes)
 
 

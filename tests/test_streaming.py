@@ -121,9 +121,28 @@ def test_patched_csr_row_overflow_compacts():
 # Warm-start seeding
 # ---------------------------------------------------------------------- #
 
-def test_vectorized_insertion_bound_matches_unionfind_reference():
+def _bound_on_slots(graph, oce, inserted, rng):
+    """The vectorized bound over the graph's arcs scattered among 5% dead
+    slots whose ``src`` and ``dst`` are arbitrary vertices."""
+    from repro.streaming.engine import _insertion_upper_bound_arrays
+    m = graph.num_arcs
+    slots = m + m // 20 + 3
+    keep = np.sort(rng.choice(slots, m, replace=False))
+    src = rng.integers(0, graph.n, slots).astype(np.int32)
+    dst = rng.integers(0, graph.n, slots).astype(np.int32)
+    live = np.zeros(slots, bool)
+    src[keep], dst[keep], live[keep] = graph.src, graph.dst, True
+    return _insertion_upper_bound_arrays(graph.n, src, dst, live, graph.deg,
+                                         oce, inserted)[0]
+
+
+@pytest.mark.parametrize("case", ["churn", "dead_slots", "ties", "at_cap"])
+def test_vectorized_insertion_bound_matches_unionfind_reference(case):
     """The jitted segment-op insertion upper bound must equal the host-side
-    union-find reference exactly (same passes, same peel fixpoints)."""
+    union-find reference exactly (same passes, same peel fixpoints): from
+    the old cores (``churn``), over slot arrays with dead slots, from one
+    level shared by every vertex (``ties``), and with U equal to the
+    degree cap at half the vertices (``at_cap``)."""
     from repro.streaming.engine import (_insertion_upper_bound,
                                         _insertion_upper_bound_unionfind)
     rng = np.random.default_rng(7)
@@ -135,11 +154,20 @@ def test_vectorized_insertion_bound_matches_unionfind_reference():
             d = apply_batch(g, batch)
             oce = np.zeros(d.graph.n, np.int64)
             oce[: g.n] = core
-            U_vec = _insertion_upper_bound(d.graph, oce, d.inserted)
+            if case == "ties":
+                oce[:] = int(np.median(core))
+            elif case == "at_cap":
+                half = rng.random(d.graph.n) < 0.5
+                oce[half] = d.graph.deg[half]
+            if case == "dead_slots":
+                U_vec = _bound_on_slots(d.graph, oce, d.inserted, rng)
+            else:
+                U_vec = _insertion_upper_bound(d.graph, oce, d.inserted)
             U_ref = _insertion_upper_bound_unionfind(d.graph, oce,
                                                      d.inserted)
             assert (U_vec == U_ref).all()
             g, core = d.graph, bz_core_numbers(d.graph).astype(np.int64)
+
 
 def test_seed_is_upper_bound_on_new_cores():
     """The locality theorem needs seed >= exact new cores pointwise; check

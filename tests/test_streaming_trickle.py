@@ -2,7 +2,8 @@
 ``frontier="auto"``, by the core-maintenance protocol (uniformly chosen
 existing edges deleted, the previous batch's deletions inserted back).
 Every batch takes the tight insertion upper bound; the bound is sound,
-its pass count is the host loop's, and its spans nest under ``seed``."""
+its pass and sweep counts are the host loop's, and its spans nest under
+``seed``."""
 
 import numpy as np
 import pytest
@@ -62,20 +63,25 @@ def test_trickle_upper_bound_is_sound_and_counts_the_host_loop_passes(replay):
         live = np.ones(g.num_arcs, bool)
         U, passes = _insertion_upper_bound_arrays(g.n, g.src, g.dst, live, g.deg, old, ins)
         assert (U >= bz_core_numbers(g)).all()
-        calls, U_host = 0, old.astype(np.int32)
+        calls, prop, peel, U_host = 0, 0, 0, old.astype(np.int32)
         if ins.size:
             k = ins.shape[0]
             raised = True
             while raised:
-                U_host, raised = _ub_pass(U_host, g.deg.astype(np.int32), g.src, g.dst, live,
-                                          ins[:, 0].astype(np.int32), ins[:, 1].astype(np.int32),
-                                          np.ones(k, bool), n=g.n)
-                calls += 1
+                U_host, raised, p, q = _ub_pass(
+                    U_host, g.deg.astype(np.int32), g.src, g.dst, live,
+                    ins[:, 0].astype(np.int32), ins[:, 1].astype(np.int32),
+                    np.ones(k, bool), n=g.n)
+                calls, prop, peel = calls + 1, prop + int(p), peel + int(q)
         assert passes == calls
         assert (np.asarray(U_host) == U).all()
-        # the engine ran the same passes over its padded slot arrays
+        # the engine ran the same passes over its padded slot arrays, and
+        # the span counts their sweeps: each pass sweeps each loop once
+        # or more
         (ub,) = [s for s in b["spans"] if s.name == "upper-bound"]
         assert ub.attrs["passes"] == passes
+        assert (ub.attrs["prop_sweeps"], ub.attrs["peel_sweeps"]) == (prop, peel)
+        assert prop >= passes and peel >= passes
         assert passes >= 1 or not ins.size
 
 

@@ -232,8 +232,11 @@ def test_round_counts_read_no_arc_sized_gather_on_tpu(one_chip, for_tpu, n, arcs
 
 def test_insertion_upper_bound_compiles_at_the_trickle_cell_shapes(one_chip, for_tpu):
     """The streaming engine's tight insertion upper bound — every +1 pass
-    in one while_loop, with its pass count — at the trickle cell's shapes:
-    65,536 vertices, 2^21 padded arc slots, 256 padded inserted edges."""
+    in one while_loop, with its pass and sweep counts — at the trickle
+    cell's shapes: 65,536 vertices, 2^21 padded arc slots, 256 padded
+    inserted edges. Each sweep of the propagation (``ub.prop``) and of the
+    peel (``ub.peel``) gathers one vertex-sized table into one slot-sized
+    result, and no gather reads a bool table."""
     from repro.streaming.engine import _ub_converge
 
     n, slots, ins = 1 << 16, 1 << 21, 256
@@ -242,7 +245,16 @@ def test_insertion_upper_bound_compiles_at_the_trickle_cell_shapes(one_chip, for
         S((n,)), S((n,)), S((slots,)), S((slots,)), S((slots,), jnp.bool_),
         S((ins,)), S((ins,)), S((ins,), jnp.bool_), n=n,
     ).compile()
-    U, passes = compiled.out_info
-    assert U.shape == (n,) and passes.shape == () and passes.dtype == jnp.int32
+    U, *counts = compiled.out_info
+    assert U.shape == (n,) and len(counts) == 3
+    assert all(c.shape == () and c.dtype == jnp.int32 for c in counts)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    text = compiled.as_text()
+    gathers = _gathers(text)
+    for scope in ("/while/body/while/body/ub.prop/", "/while/body/while/body/ub.peel/"):
+        swept = [(size, out) for name, size, out in gathers if scope in name]
+        assert swept == [(n, slots)], (scope, gathers)
+    # the peel's 2U[src] is gathered once a pass, outside its loop
+    assert sum(out == slots for _, _, out in gathers) == 3, gathers
+    assert not re.search(rf"= pred\[{slots}\][^ ]* gather\(", text)
